@@ -34,6 +34,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir, "src"))
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs.base import reduce_config                    # noqa: E402
 from repro.configs.registry import get_config                   # noqa: E402
 from repro.runtime.engine import Engine, SlotBufferEngine       # noqa: E402
@@ -210,6 +211,7 @@ def run(csv):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small shapes + regression assertions (CI)")

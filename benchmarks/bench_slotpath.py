@@ -27,6 +27,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir, "src"))
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs.base import reduce_config            # noqa: E402
 from repro.configs.registry import get_config           # noqa: E402
 from repro.models import Model                          # noqa: E402
@@ -126,6 +127,7 @@ def run(csv) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny config + regression assertions (CI fast lane)")

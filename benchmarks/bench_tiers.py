@@ -39,6 +39,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir, "src"))
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs.base import reduce_config                    # noqa: E402
 from repro.configs.registry import get_config                   # noqa: E402
 from repro.core.expert_tiers import (TieredExpertStore,         # noqa: E402
@@ -46,7 +47,7 @@ from repro.core.expert_tiers import (TieredExpertStore,         # noqa: E402
 from repro.core.faults import FaultPlan                         # noqa: E402
 from repro.data.workloads import make_workload, prompt_tokens   # noqa: E402
 from repro.runtime.engine import (Engine, SlotBufferEngine,     # noqa: E402
-                                  build_host_store)
+                                  split_params)
 from repro.runtime.request import Request                       # noqa: E402
 from repro.runtime.serving import (EngineServingConfig,         # noqa: E402
                                    ServingEngine)
@@ -105,7 +106,8 @@ def _max_seq(p):
 
 def _make_store(eng, p, sdir, prefetch=True):
     if not os.path.exists(os.path.join(sdir, "manifest.json")):
-        export_expert_shards(build_host_store(eng.model, eng.params), sdir)
+        export_expert_shards(split_params(eng.model, eng.params).experts,
+                             sdir)
     probe = TieredExpertStore(sdir)
     return TieredExpertStore(
         sdir,
@@ -351,6 +353,7 @@ def run(csv):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small shapes + regression assertions (CI)")

@@ -11,9 +11,11 @@ import sys
 import time
 
 from benchmarks.common import Csv
+from repro.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     only = sys.argv[1] if len(sys.argv) > 1 else None
     from benchmarks import (bench_cache_aware, bench_decode, bench_faults,
                             bench_integrity, bench_prefill,

@@ -190,14 +190,18 @@ def run_integrity_lane() -> None:
 
 
 if __name__ == "__main__":
+    import traceback
     archs = sys.argv[1:] or ARCH_IDS
+    failed = []
     for a in archs:
         try:
             run(a)
         except Exception as e:
             print(f"{a:24s} FAILED: {type(e).__name__}: {e}")
-            import traceback
             traceback.print_exc()
+            failed.append(a)
     run_fault_lane()
     run_tiers_lane()
     run_integrity_lane()
+    if failed:
+        sys.exit(f"{len(failed)} architecture(s) failed: {', '.join(failed)}")

@@ -27,8 +27,8 @@ module adds the third tier beneath the slot buffer:
   independently of, and clamped above, the device horizon S.
 
 - **`TieredExpertStore`** — drop-in superset of
-  `core.expert_buffer.HostExpertStore`: same ``gather``/``gather_many``
-  contract (stacked contiguous host arrays), so ``swap_in_many`` and the
+  `core.expert_buffer.HostExpertStore`: same ``experts`` contract
+  (per-expert host arrays), so ``swap_in_many`` and the
   device prefetch window are untouched. Residency in the host tier must
   be guaranteed first via ``demand_host`` (blocking, records a stall just
   like a device miss) or the speculative ``request_host`` path.
@@ -722,10 +722,9 @@ class HostTierModel:
 class TieredExpertStore:
     """Disk-backed drop-in superset of `HostExpertStore`.
 
-    ``gather``/``gather_many`` keep the `HostExpertStore` contract
-    (stacked contiguous host arrays, keys grouped per layer) but may only
-    be called for host-resident experts — residency is the engine's job
-    via ``demand_host``/``request_host``, exactly as device-slot residency
+    ``experts`` keeps the `HostExpertStore` contract (per-expert host
+    arrays) but may only be called for host-resident experts — residency
+    is the engine's job via ``demand_host``/``request_host``, exactly as device-slot residency
     is guaranteed by ``ensure_resident`` before each FFN dispatch."""
 
     def __init__(self, store_dir: str, *,
@@ -899,15 +898,8 @@ class TieredExpertStore:
             raise RuntimeError(
                 f"expert {key} is not staged in the host tier — "
                 "demand_host/request_host must guarantee residency before "
-                "gather (this is a scheduling bug, not a data error)")
+                "reading it (this is a scheduling bug, not a data error)")
         return w
 
-    def gather(self, layer: int, experts) -> Tuple[np.ndarray, ...]:
-        idx = np.asarray(experts, dtype=np.int32)
-        ws = [self._expert((layer, int(e))) for e in idx]
-        return tuple(np.stack([w[t] for w in ws]) for t in range(3))
-
-    def gather_many(self, keys: List[Key]) -> Tuple[np.ndarray, ...]:
-        assert keys, "gather_many needs at least one key"
-        ws = [self._expert((li, int(e))) for li, e in keys]
-        return tuple(np.stack([w[t] for w in ws]) for t in range(3))
+    def experts(self, keys: List[Key]) -> List[Tuple[np.ndarray, ...]]:
+        return [self._expert((li, int(e))) for li, e in keys]

@@ -74,15 +74,13 @@ def bubble_fraction(n_stages: int, n_microbatches: int) -> float:
 def make_pipelined_forward(mesh: Mesh, stage_fn, n_stages: int,
                            n_microbatches: int):
     """shard_map wrapper: params sharded by stage on the pod axis."""
-    from jax.experimental.shard_map import shard_map
-
     pipelined = pipeline_stages(stage_fn, n_stages, n_microbatches, "pod")
 
     def fwd(stage_params, x_mb):
         return pipelined(stage_params, x_mb)
 
-    return shard_map(
+    return jax.shard_map(
         fwd, mesh=mesh,
         in_specs=(P("pod"), P(None, "data")),
         out_specs=P(None, "data"),
-        check_rep=False)
+        check_vma=False)

@@ -19,9 +19,12 @@ overhead (not FLOPs) dominates the reduced bench configs:
   masked full-window softmax of `models.attention.decode_attention` /
   `mla_decode` with a single launch per layer.
 
-Both run interpret-mode on CPU (the `kernels/ops.py::_default_interpret`
-pattern) and compile to Mosaic on TPU; `kernels/ref.py` and the einsum paths
-stay the numerics oracles.
+All three compile to Mosaic on TPU and run in interpret mode on CPU
+(`kernels/ops.py`); `kernels/ref.py` and the einsum paths stay the numerics
+oracles. The expert FFN is tiled over d_ff and attention over KV blocks of
+the cache, so no block holds a whole expert or a whole cache row: at
+olmoe-1b-7b widths (12.6 MB per expert, 4 MB of K per row at 1024
+positions) either would overrun the chip's scoped VMEM.
 """
 from __future__ import annotations
 
@@ -31,10 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific grid spec (works in interpret mode on CPU too)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.slot_gather import _fit_block
 
@@ -46,66 +46,73 @@ NEG_INF = -2.0 ** 30        # attention masking (matches models/attention.py)
 # Fused MoE entry: route + top-k + slot lookup + expert FFN, one launch
 # ---------------------------------------------------------------------------
 
-def _fused_moe_kernel(slot_ref, x_ref, rw_ref, bias_ref, wg_ref, wu_ref,
-                      wd_ref, y_ref, gates_ref, ids_ref, *, k: int,
+def _fused_moe_kernel(slot_ref, x_ref, rw_ref, bias_ref, raw_ref, wg_ref,
+                      wu_ref, wd_ref, y_ref, gates_ref, ids_ref, *, k: int,
                       norm: bool):
-    """Grid step e computes expert e's gate-weighted contribution for every
-    token; the router/top-k recompute per step is negligible next to the
-    launch it saves (T is a decode batch, E <= 256)."""
-    e = pl.program_id(0)
-    x = x_ref[...]                                    # (T, d)
-    xf = x.astype(jnp.float32)
-    logits = jnp.dot(xf, rw_ref[...].astype(jnp.float32)) \
-        + bias_ref[0].astype(jnp.float32)
-    T, E = logits.shape
-    m = jnp.max(logits, axis=-1, keepdims=True)
-    p = jnp.exp(logits - m)
-    probs = p / jnp.sum(p, axis=-1, keepdims=True)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (T, E), 1)
-    total = jnp.zeros((T, 1), jnp.float32)
-    work = probs
-    vals, idxs = [], []
-    for _ in range(k):                 # same first-max rule as _topk_kernel
-        v = jnp.max(work, axis=-1, keepdims=True)
-        idx = jnp.min(jnp.where(work == v, iota, E), axis=-1, keepdims=True)
-        work = jnp.where(iota == idx, NEG, work)
-        vals.append(v)
-        idxs.append(idx)
-        total = total + v
-    gates = jnp.concatenate(vals, axis=-1)            # (T, k)
-    if norm:
-        gates = gates / jnp.maximum(total, 1e-9)
-    ids = jnp.concatenate(idxs, axis=-1).astype(jnp.int32)
-    # dead-sentinel rule: a non-resident expert (raw slot < 0) contributes
-    # nothing — its assignments' gates zero exactly as in moe_slotbuf. The
-    # one-hot contraction avoids a gather from the scalar ref.
-    res = (slot_ref[1] >= 0).astype(jnp.float32)                  # (E,)
-    iota_k = jax.lax.broadcasted_iota(jnp.int32, (T, k, E), 2)
-    resk = jnp.sum(jnp.where(iota_k == ids[:, :, None],
-                             res[None, None, :], 0.0), axis=-1)
-    gates = gates * resk
+    """Grid step (e, f) adds d_ff tile f of expert e's gate-weighted FFN for
+    every token. Routing runs once, at the first step, into the gates/ids
+    outputs, which stay in VMEM for the whole grid."""
+    e, ft = pl.program_id(0), pl.program_id(1)
 
-    @pl.when(e == 0)
-    def _init():
+    @pl.when((e == 0) & (ft == 0))
+    def _route():
+        xf = x_ref[...].astype(jnp.float32)           # (T, d)
+        logits = jnp.dot(xf, rw_ref[...].astype(jnp.float32)) \
+            + bias_ref[...].astype(jnp.float32)
+        T, E = logits.shape
+        m = jnp.max(logits, axis=-1, keepdims=True)
+        p = jnp.exp(logits - m)
+        probs = p / jnp.sum(p, axis=-1, keepdims=True)
+        iota = jax.lax.broadcasted_iota(jnp.int32, (T, E), 1)
+        # dead-sentinel rule: a non-resident expert (raw slot < 0)
+        # contributes nothing — its assignments' gates zero exactly as in
+        # moe_slotbuf
+        res = (raw_ref[...] >= 0).astype(jnp.float32)             # (1, E)
+        total = jnp.zeros((T, 1), jnp.float32)
+        work = probs
+        vals, idxs, resk = [], [], []
+        for _ in range(k):             # same first-max rule as _topk_kernel
+            v = jnp.max(work, axis=-1, keepdims=True)
+            idx = jnp.min(jnp.where(work == v, iota, E), axis=-1,
+                          keepdims=True)
+            resk.append(jnp.sum(jnp.where(iota == idx, res, 0.0), axis=-1,
+                                keepdims=True))
+            work = jnp.where(iota == idx, NEG, work)
+            vals.append(v)
+            idxs.append(idx)
+            total = total + v
+        gates = jnp.concatenate(vals, axis=-1)        # (T, k)
+        if norm:
+            gates = gates / jnp.maximum(total, 1e-9)
+        gates_ref[...] = gates * jnp.concatenate(resk, axis=-1)
+        ids_ref[...] = jnp.concatenate(idxs, axis=-1).astype(jnp.int32)
         y_ref[...] = jnp.zeros_like(y_ref)
-        gates_ref[...] = gates
-        ids_ref[...] = ids
 
-    ge = jnp.sum(jnp.where(ids == e, gates, 0.0), axis=-1)        # (T,)
-    g = jnp.dot(x, wg_ref[0])                         # bf16, like the einsum
-    u = jnp.dot(x, wu_ref[0])
-    h = (jax.nn.silu(g) * u).astype(x.dtype)
-    part = jnp.dot(h, wd_ref[0])
-    y_ref[...] += ge[:, None] * part.astype(jnp.float32)
+    x = x_ref[...]
+    ge = jnp.sum(jnp.where(ids_ref[...] == e, gates_ref[...], 0.0),
+                 axis=-1, keepdims=True)                          # (T, 1)
+    # every op's result rounded to x.dtype, as the einsum path rounds it;
+    # the arithmetic itself runs in f32 (the v5e VPU has no bf16)
+    dt, f32 = x.dtype, jnp.float32
+
+    def rnd(v):
+        return v.astype(dt).astype(f32)
+
+    g = rnd(jnp.dot(x, wg_ref[0], preferred_element_type=f32))
+    u = rnd(jnp.dot(x, wu_ref[0], preferred_element_type=f32))
+    h = rnd(rnd(g * rnd(jax.nn.sigmoid(g))) * u).astype(dt)
+    part = rnd(jnp.dot(h, wd_ref[0], preferred_element_type=f32))
+    y_ref[...] += ge * part
 
 
 @functools.partial(jax.jit, static_argnames=("top_k", "norm_topk",
-                                             "interpret"))
+                                             "block_f", "interpret"))
 def fused_moe_entry(x: jnp.ndarray, router_w: jnp.ndarray,
                     logit_bias: jnp.ndarray, slot_of_expert: jnp.ndarray,
                     s_gate: jnp.ndarray, s_up: jnp.ndarray,
                     s_down: jnp.ndarray, *, top_k: int,
-                    norm_topk: bool = True, interpret: bool = False):
+                    norm_topk: bool = True, block_f: int = 128,
+                    interpret: bool = False):
     """x: (T, d) tokens; router_w: (d, E); logit_bias: (E,) additive fp32
     (zeros when cache-aware routing is off — bit-exact); slot_of_expert:
     (E,) int32, -1 = non-resident; slot buffers (S, d, f)/(S, f, d).
@@ -117,25 +124,27 @@ def fused_moe_entry(x: jnp.ndarray, router_w: jnp.ndarray,
     T, d = x.shape
     E = router_w.shape[1]
     f = s_gate.shape[-1]
+    bf = _fit_block(f, block_f)
     raw = slot_of_expert.astype(jnp.int32)
-    # stacked scalar-prefetch rows: [0] clamped (valid BlockSpec index even
-    # for misses — their output is gate-zeroed), [1] raw (sentinel rule)
-    slots2 = jnp.stack([jnp.maximum(raw, 0), raw])
+    # scalar prefetch: the clamped slot row (a valid BlockSpec index even
+    # for misses, whose output is gate-zeroed); the raw row rides in VMEM
+    # for the sentinel rule (Mosaic loads only scalars from SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(E,),
+        grid=(E, f // bf),
         in_specs=[
-            pl.BlockSpec((T, d), lambda e, s: (0, 0)),
-            pl.BlockSpec((d, E), lambda e, s: (0, 0)),
-            pl.BlockSpec((1, E), lambda e, s: (0, 0)),
-            pl.BlockSpec((1, d, f), lambda e, s: (s[0, e], 0, 0)),
-            pl.BlockSpec((1, d, f), lambda e, s: (s[0, e], 0, 0)),
-            pl.BlockSpec((1, f, d), lambda e, s: (s[0, e], 0, 0)),
+            pl.BlockSpec((T, d), lambda e, j, s: (0, 0)),
+            pl.BlockSpec((d, E), lambda e, j, s: (0, 0)),
+            pl.BlockSpec((1, E), lambda e, j, s: (0, 0)),
+            pl.BlockSpec((1, E), lambda e, j, s: (0, 0)),
+            pl.BlockSpec((1, d, bf), lambda e, j, s: (s[e], 0, j)),
+            pl.BlockSpec((1, d, bf), lambda e, j, s: (s[e], 0, j)),
+            pl.BlockSpec((1, bf, d), lambda e, j, s: (s[e], j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((T, d), lambda e, s: (0, 0)),
-            pl.BlockSpec((T, top_k), lambda e, s: (0, 0)),
-            pl.BlockSpec((T, top_k), lambda e, s: (0, 0)),
+            pl.BlockSpec((T, d), lambda e, j, s: (0, 0)),
+            pl.BlockSpec((T, top_k), lambda e, j, s: (0, 0)),
+            pl.BlockSpec((T, top_k), lambda e, j, s: (0, 0)),
         ],
     )
     return pl.pallas_call(
@@ -145,81 +154,63 @@ def fused_moe_entry(x: jnp.ndarray, router_w: jnp.ndarray,
                    jax.ShapeDtypeStruct((T, top_k), jnp.float32),
                    jax.ShapeDtypeStruct((T, top_k), jnp.int32)],
         interpret=interpret,
-    )(slots2, x, router_w, logit_bias.reshape(1, E), s_gate, s_up, s_down)
+    )(jnp.maximum(raw, 0), x, router_w, logit_bias.reshape(1, E),
+      raw.reshape(1, E), s_gate, s_up, s_down)
 
 
 # ---------------------------------------------------------------------------
 # Fused single-token attention: ring insert + online softmax, one launch
 # ---------------------------------------------------------------------------
 
-def _online_softmax(scores_fn, values_fn, valid, n_chunks: int,
-                    block_s: int, acc_shape, m_shape):
-    """Chunked online softmax driven by a traced `valid` length: chunks the
-    per-row cache_len never reaches are skipped via lax.cond, so compute
-    tracks the filled prefix, not the ring capacity."""
-    def body(c, carry):
-        acc, m, l = carry
-        start = c * block_s
-
-        def compute(carry):
-            acc, m, l = carry
-            s_blk, kpos = scores_fn(start)
-            s_blk = jnp.where(kpos < valid, s_blk, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s_blk, axis=-1))
-            p = jnp.exp(s_blk - m_new[..., None])
-            corr = jnp.exp(m - m_new)
-            l_new = l * corr + jnp.sum(p, axis=-1)
-            acc_new = acc * corr[..., None] + values_fn(p, start)
-            return acc_new, m_new, l_new
-
-        return jax.lax.cond(start < valid, compute, lambda cr: cr, carry)
-
-    acc0 = jnp.zeros(acc_shape, jnp.float32)
-    m0 = jnp.full(m_shape, NEG_INF, jnp.float32)
-    l0 = jnp.zeros(m_shape, jnp.float32)
-    acc, _, l = jax.lax.fori_loop(0, n_chunks, body, (acc0, m0, l0))
-    return acc / jnp.maximum(l[..., None], 1e-20)
-
-
 def _gqa_decode_kernel(clen_ref, q_ref, kn_ref, vn_ref, kc_ref, vc_ref,
-                       o_ref, ko_ref, vo_ref, *, scale: float,
-                       logit_softcap: float, block_s: int):
-    b = pl.program_id(0)
+                       o_ref, ko_ref, vo_ref, m_sc, l_sc, acc_sc, *,
+                       scale: float, logit_softcap: float, block_s: int):
+    """Grid step (b, s): KV block s of row b. The block is copied through
+    with the new K/V row inserted, and — if it holds any of the row's
+    valid positions — folded into the online softmax kept in scratch.
+    Scores are per-head lane reductions (no MXU): one query per head."""
+    b, s = pl.program_id(0), pl.program_id(1)
+    n_blocks = pl.num_programs(1)
     clen = clen_ref[b]
-    kc = kc_ref[0]                                    # (S, Hkv, D)
-    vc = vc_ref[0]
-    S, Hkv, D = kc.shape
+    S = block_s * n_blocks
+    start = s * block_s
     # ring insert: slot(pos) = pos % size (layer_decode's discipline; for
     # unwrapped caches clen < S makes this a plain positional insert)
-    ins = jax.lax.broadcasted_iota(jnp.int32, (S, 1, 1), 0) \
-        == jax.lax.rem(clen, S)
-    kc = jnp.where(ins, kn_ref[0], kc)
-    vc = jnp.where(ins, vn_ref[0], vc)
-    ko_ref[0] = kc
-    vo_ref[0] = vc
+    pos = start + jax.lax.broadcasted_iota(jnp.int32, (block_s, 1, 1), 0)
+    ins = pos == jax.lax.rem(clen, S)
+    kb = jnp.where(ins, kn_ref[0], kc_ref[0])         # (bs, Hkv, D)
+    vb = jnp.where(ins, vn_ref[0], vc_ref[0])
+    ko_ref[0] = kb
+    vo_ref[0] = vb
     valid = jnp.minimum(clen + 1, S)
 
-    q = q_ref[0, 0]                                   # (Hq, D)
-    G = q.shape[0] // Hkv
-    qf = q.reshape(Hkv, G, D).astype(jnp.float32) * scale
-    kcf = kc.astype(jnp.float32)
-    vcf = vc.astype(jnp.float32)
+    @pl.when(s == 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    def scores(start):
-        kb = jax.lax.dynamic_slice_in_dim(kcf, start, block_s, axis=0)
-        s_blk = jnp.einsum("hgd,khd->hgk", qf, kb)
-        if logit_softcap > 0.0:
-            s_blk = logit_softcap * jnp.tanh(s_blk / logit_softcap)
-        kpos = start + jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_s), 2)
-        return s_blk, kpos
+    @pl.when(start < valid)
+    def _attend():
+        kf = kb.astype(jnp.float32)
+        vf = vb.astype(jnp.float32)
+        for g in range(q_ref.shape[1]):
+            qg = q_ref[0, g].astype(jnp.float32) * scale          # (Hkv, D)
+            sc = jnp.sum(kf * qg[None], axis=-1, keepdims=True)   # (bs,Hkv,1)
+            if logit_softcap > 0.0:
+                sc = logit_softcap * jnp.tanh(sc / logit_softcap)
+            sc = jnp.where(pos < valid, sc, NEG_INF)
+            m_prev = m_sc[g]                                       # (Hkv, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0))
+            p = jnp.exp(sc - m_new[None])
+            corr = jnp.exp(m_prev - m_new)
+            l_sc[g] = l_sc[g] * corr + jnp.sum(p, axis=0)
+            acc_sc[g] = acc_sc[g] * corr + jnp.sum(p * vf, axis=0)
+            m_sc[g] = m_new
 
-    def values(p, start):
-        vb = jax.lax.dynamic_slice_in_dim(vcf, start, block_s, axis=0)
-        return jnp.einsum("hgk,khd->hgd", p, vb)
-
-    out = _online_softmax(scores, values, valid, S // block_s, block_s,
-                          (Hkv, G, D), (Hkv, G))
-    o_ref[0, 0] = out.reshape(Hkv * G, D).astype(o_ref.dtype)
+    @pl.when(s == n_blocks - 1)
+    def _finish():
+        o_ref[0] = acc_sc[...] / jnp.maximum(l_sc[...], 1e-20)
 
 
 @functools.partial(jax.jit, static_argnames=("logit_softcap", "scale",
@@ -235,75 +226,91 @@ def fused_decode_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     `min(cache_len + 1, S)`). Returns (out (B, 1, Hq, D), k_cache', v_cache').
     """
     B, _, Hq, D = q.shape
-    S = k_cache.shape[1]
-    Hkv = k_cache.shape[2]
+    S, Hkv = k_cache.shape[1], k_cache.shape[2]
+    G = Hq // Hkv
     if scale is None:
         scale = D ** -0.5
-    block_s = _fit_block(S, block_s)
+    bs = _fit_block(S, block_s)
+    # query head h * G + g -> [g, h]: every kernel array is (.., Hkv, D)
+    qg = q[:, 0].reshape(B, Hkv, G, D).transpose(0, 2, 1, 3)
+    row = lambda b, s, c: (b, 0, 0, 0)                      # noqa: E731
+    blk = lambda b, s, c: (b, s, 0, 0)                      # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B,),
+        grid=(B, S // bs),
         in_specs=[
-            pl.BlockSpec((1, 1, Hq, D), lambda b, s: (b, 0, 0, 0)),
-            pl.BlockSpec((1, 1, Hkv, D), lambda b, s: (b, 0, 0, 0)),
-            pl.BlockSpec((1, 1, Hkv, D), lambda b, s: (b, 0, 0, 0)),
-            pl.BlockSpec((1, S, Hkv, D), lambda b, s: (b, 0, 0, 0)),
-            pl.BlockSpec((1, S, Hkv, D), lambda b, s: (b, 0, 0, 0)),
+            pl.BlockSpec((1, G, Hkv, D), row),
+            pl.BlockSpec((1, 1, Hkv, D), row),
+            pl.BlockSpec((1, 1, Hkv, D), row),
+            pl.BlockSpec((1, bs, Hkv, D), blk),
+            pl.BlockSpec((1, bs, Hkv, D), blk),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, Hq, D), lambda b, s: (b, 0, 0, 0)),
-            pl.BlockSpec((1, S, Hkv, D), lambda b, s: (b, 0, 0, 0)),
-            pl.BlockSpec((1, S, Hkv, D), lambda b, s: (b, 0, 0, 0)),
+            pl.BlockSpec((1, G, Hkv, D), row),
+            pl.BlockSpec((1, bs, Hkv, D), blk),
+            pl.BlockSpec((1, bs, Hkv, D), blk),
         ],
+        scratch_shapes=[pltpu.VMEM((G, Hkv, 1), jnp.float32),
+                        pltpu.VMEM((G, Hkv, 1), jnp.float32),
+                        pltpu.VMEM((G, Hkv, D), jnp.float32)],
     )
-    return pl.pallas_call(
+    out, kc, vc = pl.pallas_call(
         functools.partial(_gqa_decode_kernel, scale=float(scale),
-                          logit_softcap=float(logit_softcap),
-                          block_s=block_s),
+                          logit_softcap=float(logit_softcap), block_s=bs),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, 1, Hq, D), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B, G, Hkv, D), jnp.float32),
                    jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
                    jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)],
+        input_output_aliases={4: 1, 5: 2},
         interpret=interpret,
-    )(cache_len.astype(jnp.int32), q, k_new, v_new, k_cache, v_cache)
+    )(cache_len.astype(jnp.int32), qg, k_new, v_new, k_cache, v_cache)
+    out = out.transpose(0, 2, 1, 3).reshape(B, 1, Hq, D).astype(q.dtype)
+    return out, kc, vc
 
 
 def _mla_decode_kernel(clen_ref, qa_ref, qp_ref, cn_ref, pn_ref, lat_ref,
-                       pe_ref, ctx_ref, lat_o_ref, pe_o_ref, *, scale: float,
-                       block_s: int):
-    b = pl.program_id(0)
+                       pe_ref, ctx_ref, lat_o_ref, pe_o_ref, m_sc, l_sc,
+                       acc_sc, *, scale: float, block_s: int):
+    """Grid step (b, s): latent block s of row b, copied through with the
+    new row inserted and folded into the online softmax in scratch."""
+    b, s = pl.program_id(0), pl.program_id(1)
     clen = clen_ref[b]
-    lat = lat_ref[0]                                  # (S, R)
-    pe = pe_ref[0]                                    # (S, P)
-    S = lat.shape[0]
+    start = s * block_s
     # MLA latent cache is positional (no ring): insert at clen
-    ins = jax.lax.broadcasted_iota(jnp.int32, (S, 1), 0) == clen
-    lat = jnp.where(ins, cn_ref[0], lat)
-    pe = jnp.where(ins, pn_ref[0], pe)
-    lat_o_ref[0] = lat
-    pe_o_ref[0] = pe
+    pos = start + jax.lax.broadcasted_iota(jnp.int32, (block_s, 1), 0)
+    ins = pos == clen
+    lb = jnp.where(ins, cn_ref[0], lat_ref[0])        # (bs, R)
+    pb = jnp.where(ins, pn_ref[0], pe_ref[0])         # (bs, P)
+    lat_o_ref[0] = lb
+    pe_o_ref[0] = pb
     valid = clen + 1
 
-    qa = qa_ref[0].astype(jnp.float32)                # (H, R)
-    qp = qp_ref[0].astype(jnp.float32)                # (H, P)
-    latf = lat.astype(jnp.float32)
-    pef = pe.astype(jnp.float32)
-    H, R = qa.shape
+    @pl.when(s == 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    def scores(start):
-        lb = jax.lax.dynamic_slice_in_dim(latf, start, block_s, axis=0)
-        pb = jax.lax.dynamic_slice_in_dim(pef, start, block_s, axis=0)
-        s_blk = (jnp.einsum("hr,kr->hk", qa, lb)
-                 + jnp.einsum("hp,kp->hk", qp, pb)) * scale
+    @pl.when(start < valid)
+    def _attend():
+        lf = lb.astype(jnp.float32)
+        nt = (((1,), (1,)), ((), ()))                 # contract last dims
+        sc = (jax.lax.dot_general(qa_ref[0], lf, nt)
+              + jax.lax.dot_general(qp_ref[0], pb.astype(jnp.float32),
+                                    nt)) * scale      # (H, bs)
         kpos = start + jax.lax.broadcasted_iota(jnp.int32, (1, block_s), 1)
-        return s_blk, kpos
+        sc = jnp.where(kpos < valid, sc, NEG_INF)
+        m_prev = m_sc[...]                            # (H, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_sc[...] = l_sc[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * corr + jnp.dot(p, lf)
+        m_sc[...] = m_new
 
-    def values(p, start):
-        lb = jax.lax.dynamic_slice_in_dim(latf, start, block_s, axis=0)
-        return jnp.einsum("hk,kr->hr", p, lb)
-
-    ctx_ref[0] = _online_softmax(scores, values, valid, S // block_s,
-                                 block_s, (H, R), (H,))
+    @pl.when(s == pl.num_programs(1) - 1)
+    def _finish():
+        ctx_ref[0] = acc_sc[...] / jnp.maximum(l_sc[...], 1e-20)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "block_s", "interpret"))
@@ -323,30 +330,38 @@ def fused_mla_decode_attention(q_abs: jnp.ndarray, q_pe: jnp.ndarray,
     B, H, R = q_abs.shape
     P = q_pe.shape[-1]
     S = latent.shape[1]
-    block_s = _fit_block(S, block_s)
+    bs = _fit_block(S, block_s)
+    row = lambda b, s, c: (b, 0, 0)                         # noqa: E731
+    blk = lambda b, s, c: (b, s, 0)                         # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B,),
+        grid=(B, S // bs),
         in_specs=[
-            pl.BlockSpec((1, H, R), lambda b, s: (b, 0, 0)),
-            pl.BlockSpec((1, H, P), lambda b, s: (b, 0, 0)),
-            pl.BlockSpec((1, R), lambda b, s: (b, 0)),
-            pl.BlockSpec((1, P), lambda b, s: (b, 0)),
-            pl.BlockSpec((1, S, R), lambda b, s: (b, 0, 0)),
-            pl.BlockSpec((1, S, P), lambda b, s: (b, 0, 0)),
+            pl.BlockSpec((1, H, R), row),
+            pl.BlockSpec((1, H, P), row),
+            pl.BlockSpec((1, 1, R), row),
+            pl.BlockSpec((1, 1, P), row),
+            pl.BlockSpec((1, bs, R), blk),
+            pl.BlockSpec((1, bs, P), blk),
         ],
         out_specs=[
-            pl.BlockSpec((1, H, R), lambda b, s: (b, 0, 0)),
-            pl.BlockSpec((1, S, R), lambda b, s: (b, 0, 0)),
-            pl.BlockSpec((1, S, P), lambda b, s: (b, 0, 0)),
+            pl.BlockSpec((1, H, R), row),
+            pl.BlockSpec((1, bs, R), blk),
+            pl.BlockSpec((1, bs, P), blk),
         ],
+        scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, R), jnp.float32)],
     )
     return pl.pallas_call(
         functools.partial(_mla_decode_kernel, scale=float(scale),
-                          block_s=block_s),
+                          block_s=bs),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, H, R), jnp.float32),
                    jax.ShapeDtypeStruct(latent.shape, latent.dtype),
                    jax.ShapeDtypeStruct(pe.shape, pe.dtype)],
+        input_output_aliases={5: 1, 6: 2},
         interpret=interpret,
-    )(cache_len.astype(jnp.int32), q_abs, q_pe, c_new, pe_new, latent, pe)
+    )(cache_len.astype(jnp.int32), q_abs.astype(jnp.float32),
+      q_pe.astype(jnp.float32), c_new.reshape(B, 1, R).astype(latent.dtype),
+      pe_new.reshape(B, 1, P).astype(pe.dtype), latent, pe)

@@ -1,8 +1,9 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On the CPU container the kernels execute in interpret mode (the kernel body
-runs as traced Python/jnp — numerics validated against `ref.py`); on real TPU
-backends `interpret=False` compiles to Mosaic.
+On a TPU the kernels compile to Mosaic. On the CPU they run in interpret
+mode (the kernel body runs as traced Python/jnp — numerics validated
+against `ref.py`), which is how the tests exercise them. Any other backend
+is an error: there is no silent fallback to the interpreter.
 """
 from __future__ import annotations
 
@@ -14,7 +15,11 @@ from repro.kernels import ref as ref_ops
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"Pallas kernels compile for TPU only (and run "
+                           f"interpreted on CPU), not on {backend!r}")
+    return backend == "cpu"
 
 
 def expert_ffn(x, w_gate, w_up, w_down, *, block_c: int = 128,

@@ -14,10 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific grid spec (works in interpret mode on CPU too)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _slot_ffn_kernel(slot_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref):

@@ -23,18 +23,22 @@ from __future__ import annotations
 
 import argparse
 
+import jax
 import numpy as np
 
-from repro.configs.registry import get_smoke_config
+from repro.compile_cache import enable_compile_cache
+from repro.configs.registry import get_config, get_smoke_config
 from repro.core import (FeatureSpec, ForestPredictor, TraceLog, baseline,
                         expertflow, pregate_fixed, promoe_like)
 from repro.core.faults import FaultPlan
 from repro.data.workloads import (WORKLOAD_PATTERNS, make_workload,
                                   prompt_tokens)
+from repro.models import Model
 from repro.runtime.engine import Engine
 from repro.simulator.events import SimSpec
 from repro.simulator.hardware import (PLATFORMS, expert_bytes,
-                                      layer_time_decode)
+                                      layer_time_decode,
+                                      platform_for_device_kind)
 from repro.simulator.serving import (ServingConfig, ServingRequest,
                                      ServingWorkload, simulate_serving)
 
@@ -48,24 +52,39 @@ def _pad_to_bucket(toks: np.ndarray, bucket: int = 16) -> np.ndarray:
     return np.concatenate([toks, np.zeros(padded - T, toks.dtype)])
 
 
-def _serve_engine(args, cfg, specs, rng) -> None:
-    """--backend engine: the request population on the real slot-path
-    runtime under continuous batching."""
-    from repro.runtime.engine import SlotBufferEngine
+def build_requests(cfg, specs, rng, max_new: int,
+                   temperature: float = 0.0) -> list:
+    """Engine-backend `Request`s for a workload's request shapes."""
     from repro.runtime.request import Request
-    from repro.runtime.serving import EngineServingConfig, ServingEngine
-
     requests = []
     for spec_r in specs:
-        n_steps = max(2, min(spec_r.decode_len, args.max_new))
+        n_steps = max(2, min(spec_r.decode_len, max_new))
         toks = _pad_to_bucket(prompt_tokens(spec_r, cfg.vocab_size, rng))
         requests.append(Request(
             prompt=toks.astype(np.int32), max_new_tokens=n_steps,
-            temperature=args.temperature, arrival_s=spec_r.arrival_s,
+            temperature=temperature, arrival_s=spec_r.arrival_s,
             request_id=spec_r.request_id))
+    return requests
+
+
+def slots_per_layer(cfg, capacity_frac: float) -> int:
+    """Device slot-pool size per MoE layer for a resident fraction."""
+    return max(2, int(cfg.moe.num_experts * capacity_frac))
+
+
+def _serve_engine(args, cfg, specs, rng) -> None:
+    """--backend engine: the request population on the real slot-path
+    runtime under continuous batching. Routed experts are built straight
+    into host memory; the device holds the rest plus the slot pool."""
+    from repro.runtime.engine import SlotBufferEngine, init_serving_params
+    from repro.runtime.serving import EngineServingConfig, ServingEngine
+
+    requests = build_requests(cfg, specs, rng, args.max_new,
+                              args.temperature)
     max_seq = max(r.prompt_len for r in requests) + args.max_new + 8
-    eng = Engine(cfg, max_seq=max_seq)
-    slots = max(2, int(cfg.moe.num_experts * args.capacity_frac))
+    model = Model(cfg)
+    params = init_serving_params(model, jax.random.PRNGKey(args.seed))
+    slots = slots_per_layer(cfg, args.capacity_frac)
     plan = FaultPlan.from_arg(args.fault_plan)
     store = None
     if args.expert_store_dir:
@@ -77,11 +96,9 @@ def _serve_engine(args, cfg, specs, rng) -> None:
         from repro.core.expert_tiers import (SHARD_MANIFEST,
                                              TieredExpertStore,
                                              export_expert_shards)
-        from repro.runtime.engine import build_host_store
         sdir = args.expert_store_dir
         if not os.path.exists(os.path.join(sdir, SHARD_MANIFEST)):
-            export_expert_shards(build_host_store(eng.model, eng.params),
-                                 sdir)
+            export_expert_shards(params.experts, sdir)
             print(f"exported expert shards to {sdir}")
         budget = (args.host_budget_mb * 1e6
                   if args.host_budget_mb is not None else None)
@@ -94,7 +111,7 @@ def _serve_engine(args, cfg, specs, rng) -> None:
               f"{store.model.host_budget_bytes/1e6:.1f}MB, "
               f"disk_bw={args.disk_bandwidth:g}B/tick, "
               f"verify={store.verify}")
-    sb = SlotBufferEngine(cfg, eng.params, eng.model,
+    sb = SlotBufferEngine(cfg, params, model,
                           n_slots_per_layer=slots, max_seq=max_seq,
                           faults=plan, retry_max=args.retry_max,
                           retry_backoff_s=args.retry_backoff,
@@ -121,6 +138,10 @@ def _serve_engine(args, cfg, specs, rng) -> None:
     print(f"  ttft split: queue={s['ttft_queue_mean_s']*1e3:.3f}ms "
           f"prefill={s['ttft_prefill_mean_s']*1e3:.3f}ms "
           f"first_step={s['ttft_first_step_mean_s']*1e3:.3f}ms")
+    dev = jax.devices()[0]
+    if dev.platform != "cpu":
+        print(f"  peak device memory "
+              f"{dev.memory_stats()['peak_bytes_in_use'] / 1e9:.2f}GB")
     if plan is not None:
         print(f"  health: link_failures={s['n_link_failures']} "
               f"retries={s['n_retries']} "
@@ -148,7 +169,12 @@ def main() -> None:
                     help="continuous-batching slots (max batch)")
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--platform", default="a6000",
-                    choices=sorted(PLATFORMS))
+                    choices=sorted(PLATFORMS),
+                    help="sim backend: the simulated platform")
+    ap.add_argument("--published", action="store_true",
+                    help="engine backend: serve the architecture at its "
+                         "published widths and depth (default: the "
+                         "reduced smoke config)")
     ap.add_argument("--capacity-frac", type=float, default=0.6)
     ap.add_argument("--workload", default="poisson",
                     choices=list(WORKLOAD_PATTERNS))
@@ -210,25 +236,32 @@ def main() -> None:
     if args.max_new < 2:
         ap.error("--max-new must be >= 2 (need at least one decode step)")
 
-    cfg = get_smoke_config(args.arch)
-    hw = PLATFORMS[args.platform]
-
-    # deployment capacity plan for the FULL architecture on this platform
-    from repro.configs.registry import get_config
-    from repro.core.capacity_planner import plan
-    full_cfg = get_config(args.arch)
-    cap_plan = plan(full_cfg, hw, batch=args.batch, kv_len=1024)
-    print(f"capacity plan ({full_cfg.name} on {hw.name}): "
-          f"{cap_plan.summary()}")
-
+    if args.published and args.backend != "engine":
+        ap.error("--published serves through the engine backend only")
+    enable_compile_cache()
+    cfg = get_config(args.arch) if args.published \
+        else get_smoke_config(args.arch)
     rng = np.random.default_rng(args.seed)
     specs = make_workload(args.workload, args.requests, seed=args.seed,
                           mean_decode=args.max_new)
 
+    from repro.core.capacity_planner import plan
     if args.backend == "engine":
+        dev = jax.devices()[0]
+        if dev.platform != "cpu":    # CPU runs are interpret-mode checks
+            hw = platform_for_device_kind(dev.device_kind)
+            cap_plan = plan(cfg, hw, batch=args.batch, kv_len=1024)
+            print(f"capacity plan ({cfg.name} on {dev.device_kind}): "
+                  f"{cap_plan.summary()}")
         _serve_engine(args, cfg, specs, rng)
         return
 
+    # sim backend: deployment capacity plan for the FULL architecture on
+    # the simulated platform
+    hw = PLATFORMS[args.platform]
+    full_cfg = get_config(args.arch)
+    print(f"capacity plan ({full_cfg.name} on {hw.name}): "
+          f"{plan(full_cfg, hw, batch=args.batch, kv_len=1024).summary()}")
     eng = Engine(cfg, max_seq=256)
 
     # --- collect a real routing trace per request -------------------------

@@ -115,7 +115,6 @@ def _moe_shard_map(params, x, ids_g, gates_g, moe, capacity, mesh, fsdp):
     `model`. GSPMD's auto-partitioning of the dispatch gather/scatter was
     measured at 2.9-3.1 TB/device/step on qwen3-moe train_4k; this is
     ~0.1 TB."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     G, Tg, d = x.shape
@@ -174,11 +173,11 @@ def _moe_shard_map(params, x, ids_g, gates_g, moe, capacity, mesh, fsdp):
     wspec_in = P("model", "data" if gather_w else None, None)
     wdspec_in = P("model", None, "data" if gather_w else None)
     bspec = P(daxes if daxes else None, None, None)
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(wspec_in, wspec_in, wdspec_in, bspec, bspec, bspec),
         out_specs=bspec,
-        check_rep=False,
+        check_vma=False,
     )(params["w_gate"], params["w_up"], params["w_down"], x, ids_g, gates_g)
 
 
@@ -356,19 +355,24 @@ def _dispatch_gather(x: jnp.ndarray, group_ids: jnp.ndarray, n_groups: int,
 
 
 def _combine_gather(y_flat: jnp.ndarray, flat_slot: jnp.ndarray,
-                    tok: jnp.ndarray, weight: jnp.ndarray, T: int, d: int,
+                    order: jnp.ndarray, weight: jnp.ndarray, T: int, d: int,
                     valid: jnp.ndarray) -> jnp.ndarray:
-    """Gather each assignment's FFN row back and fp32 scatter-add per token.
+    """Gather each assignment's FFN row back and fp32-sum it per token.
 
     y_flat: (rows, d); rows indexed by flat_slot where `valid`, anything else
-    reads the appended zero pad row.
+    reads the appended zero pad row. `order` is the dispatch's sort
+    permutation: the rows go back to (token, choice) order before the sum,
+    so a token's k outputs add up in the router's order whatever groups
+    (slots) they sat in — a sum in group order would change the f32 result
+    with the slot placement.
     """
     rows = y_flat.shape[0]
     y_pad = jnp.concatenate(
         [y_flat, jnp.zeros((1, d), y_flat.dtype)], axis=0)
     idx = jnp.where(valid, flat_slot, rows)
     contrib = y_pad[idx].astype(jnp.float32) * weight[:, None]
-    return jnp.zeros((T, d), jnp.float32).at[tok].add(contrib)
+    contrib = jnp.zeros_like(contrib).at[order].set(contrib)
+    return contrib.reshape(T, -1, d).sum(axis=1)
 
 
 def moe_slotbuf(params, slot_weights, slot_of_expert: jnp.ndarray,
@@ -411,7 +415,7 @@ def moe_slotbuf(params, slot_weights, slot_of_expert: jnp.ndarray,
     if use_kernel:
         # per-EXPERT dispatch; the kernel chases expert -> slot indirection
         from repro.kernels import ops as kernel_ops
-        buf, tok, eid, keep, order, flat_slot = _dispatch_gather(
+        buf, _, _, keep, order, flat_slot = _dispatch_gather(
             x, r.expert_ids, E, capacity)
         slot_valid = jnp.maximum(slot_of_expert, 0).astype(jnp.int32)
         y = kernel_ops.slot_ffn(buf, slot_valid, slot_weights["w_gate"],
@@ -419,12 +423,12 @@ def moe_slotbuf(params, slot_weights, slot_of_expert: jnp.ndarray,
                                 interpret=interpret)              # (E, C, d)
         flat_gates = gates.reshape(-1)[order]
         weight = flat_gates * keep.astype(jnp.float32)
-        out = _combine_gather(y.reshape(E * capacity, d), flat_slot, tok,
+        out = _combine_gather(y.reshape(E * capacity, d), flat_slot, order,
                               weight, T, d, valid=keep).astype(x.dtype)
     else:
         # per-SLOT dispatch; non-resident assignments go to sentinel slot S
         slot_ids = jnp.where(resident, slot_raw, n_slots).astype(jnp.int32)
-        buf, tok, sid, keep, order, flat_slot = _dispatch_gather(
+        buf, _, sid, keep, order, flat_slot = _dispatch_gather(
             x, slot_ids, n_slots, capacity)
         g = jnp.einsum("scd,sdf->scf", buf, slot_weights["w_gate"])
         u = jnp.einsum("scd,sdf->scf", buf, slot_weights["w_up"])
@@ -433,7 +437,7 @@ def moe_slotbuf(params, slot_weights, slot_of_expert: jnp.ndarray,
         flat_gates = gates.reshape(-1)[order]
         weight = flat_gates * keep.astype(jnp.float32)
         out = _combine_gather(y.reshape(n_slots * capacity, d), flat_slot,
-                              tok, weight, T, d,
+                              order, weight, T, d,
                               valid=keep & (sid < n_slots)).astype(x.dtype)
     if "shared" in params:
         s = params["shared"]

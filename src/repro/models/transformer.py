@@ -525,7 +525,8 @@ class Model:
         self.dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
 
     # -- init ---------------------------------------------------------------
-    def init(self, key) -> Dict[str, Any]:
+    def init_top(self, key) -> Dict[str, Any]:
+        """The non-layer parameters: embedding, final norm, LM head."""
         cfg = self.cfg
         ks = jax.random.split(key, 8)
         params: Dict[str, Any] = {
@@ -535,19 +536,45 @@ class Model:
         if not cfg.tie_embeddings:
             params["lm_head"] = dense_init(ks[1], cfg.d_model, cfg.vocab_size,
                                            self.dtype)
-        params["prefix"] = [init_layer(jax.random.fold_in(ks[2], i), cfg, s,
-                                       self.dtype) for i, s in enumerate(self.prefix)]
-        params["tail"] = [init_layer(jax.random.fold_in(ks[5], i), cfg, s,
-                                     self.dtype) for i, s in enumerate(self.tail)]
+        return params
+
+    def layer_key(self, key, i: int):
+        """Init key of absolute layer i — the key `init` builds it from, so
+        a layer initialised alone equals its slice of the stacked tree."""
+        ks = jax.random.split(key, 8)
+        np_, nu = len(self.prefix), len(self.unit)
+        if i < np_:
+            return jax.random.fold_in(ks[2], i)
+        j = i - np_
+        if j < self.num_units * nu:
+            u, k = divmod(j, nu)
+            return jax.random.fold_in(ks[3], u * 131 + k)
+        return jax.random.fold_in(ks[5], j - self.num_units * nu)
+
+    def init(self, key) -> Dict[str, Any]:
+        cfg = self.cfg
+        params = self.init_top(key)
+        np_, nu = len(self.prefix), len(self.unit)
+        params["prefix"] = [init_layer(self.layer_key(key, i), cfg, s,
+                                       self.dtype)
+                            for i, s in enumerate(self.prefix)]
+        t0 = np_ + self.num_units * nu
+        params["tail"] = [init_layer(self.layer_key(key, t0 + i), cfg, s,
+                                     self.dtype)
+                          for i, s in enumerate(self.tail)]
         unit_params = []
         for j, spec in enumerate(self.unit):
-            per_unit = [init_layer(jax.random.fold_in(ks[3], u * 131 + j), cfg,
-                                   spec, self.dtype)
-                        for u in range(self.num_units)]
-            unit_params.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per_unit))
+            # vmapped over the repeats: the stacked tree is built in place,
+            # never as a list of layers plus a stacked copy
+            keys = jnp.stack([self.layer_key(key, np_ + u * nu + j)
+                              for u in range(self.num_units)])
+            unit_params.append(jax.vmap(
+                lambda k, spec=spec: init_layer(k, cfg, spec, self.dtype))(
+                    keys))
         params["unit"] = unit_params
         if cfg.is_encoder_decoder:
-            params["encoder"] = self._init_encoder(ks[4])
+            params["encoder"] = self._init_encoder(
+                jax.random.split(key, 8)[4])
         return params
 
     def _init_encoder(self, key):

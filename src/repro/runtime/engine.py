@@ -29,7 +29,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,10 +46,10 @@ from repro.core.step_size import StepSizeController
 from repro.core.trace import Sample, TraceLog
 from repro.models import moe as moe_mod
 from repro.models.layers import rms_norm, swiglu
-from repro.models.transformer import (LayerSpec, Model, init_layer_cache,
-                                      layer_decode, layer_forward,
-                                      layer_prefill, layer_prefill_chunk,
-                                      split_ffn_params)
+from repro.models.transformer import (LayerSpec, Model, init_layer,
+                                      init_layer_cache, layer_decode,
+                                      layer_forward, layer_prefill,
+                                      layer_prefill_chunk, split_ffn_params)
 from repro.runtime.instrument import Dispatcher, Stopwatch
 from repro.runtime.sampler import sample
 from repro.simulator.events import RoutingTrace, StepTrace
@@ -76,19 +76,66 @@ def _layer_params(model: Model, params, i: int):
     return params["tail"][j - model.num_units * nu]
 
 
-def build_host_store(model: Model, params) -> HostExpertStore:
-    """Pre-staged contiguous host copies of every MoE layer's experts —
-    the same store `SlotBufferEngine` builds internally; exposed so
-    callers can `export_expert_shards` it or hand it to a tiered setup."""
-    store = HostExpertStore()
-    li = 0
+ROUTED_EXPERT_KEYS = ("w_gate", "w_up", "w_down")
+
+
+class ServingParams(NamedTuple):
+    """Parameters as the slot-path runtime holds them: everything but the
+    routed experts on the device, the routed experts in host memory only.
+    The device then keeps no expert outside the bounded slot pool."""
+    top: Dict[str, Any]              # embed / final_norm / lm_head
+    layers: List[Dict[str, Any]]     # per absolute layer, no routed experts
+    experts: HostExpertStore         # every MoE layer's routed experts
+
+
+def _strip_routed(p):
+    """A layer's params without its routed expert weights."""
+    if "moe" not in p:
+        return p
+    moe = {k: v for k, v in p["moe"].items() if k not in ROUTED_EXPERT_KEYS}
+    return {**p, "moe": moe}
+
+
+def split_params(model: Model, params) -> ServingParams:
+    """`ServingParams` from a full stacked tree (`Model.init`)."""
+    top = {k: params[k] for k in ("embed", "final_norm", "lm_head")
+           if k in params}
+    layers, store = [], HostExpertStore()
     for i, s in enumerate(_all_specs(model)):
-        if not s.is_moe:
-            continue
-        mp = _layer_params(model, params, i)["moe"]
-        store.add_layer(li, mp["w_gate"], mp["w_up"], mp["w_down"])
-        li += 1
-    return store
+        p = _layer_params(model, params, i)
+        if s.is_moe:
+            store.add_layer(len(store), *(p["moe"][k]
+                                          for k in ROUTED_EXPERT_KEYS))
+        layers.append(_strip_routed(p))
+    return ServingParams(top, layers, store)
+
+
+def init_serving_params(model: Model, key) -> ServingParams:
+    """`ServingParams` built layer by layer, equal to
+    `split_params(model, model.init(key))` but with at most one layer's
+    routed experts on the device at a time: each is generated there and
+    pulled into the host store before the next."""
+    cfg, dtype = model.cfg, model.dtype
+    fns: Dict[Any, Any] = {}
+
+    def layer_fn(spec: LayerSpec, routed: bool):
+        ck = (LayerSpec(spec.kind, spec.window, spec.is_moe, 0), routed)
+        if ck not in fns:
+            def fn(k):
+                p = init_layer(k, cfg, ck[0], dtype)
+                if routed:
+                    return tuple(p["moe"][n] for n in ROUTED_EXPERT_KEYS)
+                return _strip_routed(p)
+            fns[ck] = jax.jit(fn)
+        return fns[ck]
+
+    layers, store = [], HostExpertStore()
+    for i, spec in enumerate(_all_specs(model)):
+        k = model.layer_key(key, i)
+        layers.append(layer_fn(spec, False)(k))
+        if spec.is_moe:
+            store.add_layer(len(store), *layer_fn(spec, True)(k))
+    return ServingParams(jax.jit(model.init_top)(key), layers, store)
 
 
 class Engine:
@@ -319,6 +366,10 @@ class SlotPathStats:
             setattr(self, f.name, 0)
 
 
+# experts per donated slot-pool write: bounds its device transfer buffers
+# (see `_dispatch_swap`)
+SWAP_CHUNK = 32
+
 # chunked prefill: fixed prompt-chunk width C. Every chunk dispatch is a
 # padded (1, C) shape, so the jit cache is keyed on (C, layer spec) only —
 # compile count stays flat no matter how many distinct prompt lengths a
@@ -424,9 +475,14 @@ class SlotBufferEngine:
                  watchdog: Optional[StepWatchdog] = None,
                  store: Optional[Any] = None):
         assert cfg.moe is not None
+        if not isinstance(params, ServingParams):
+            params = split_params(model, params)
         self.cfg = cfg
         self.model = model
-        self.params = params
+        # device-resident non-layer params; per-layer params live in _p and
+        # the routed experts only in the host store
+        self.params = params.top
+        self.experts = params.experts
         self.max_seq = max_seq
         self.specs = _all_specs(model)
         self.moe_layer_ids = [i for i, s in enumerate(self.specs) if s.is_moe]
@@ -434,7 +490,9 @@ class SlotBufferEngine:
         self.n_slots = n_slots_per_layer * L
         self.table = SlotTable(L, E, self.n_slots)
         self.cache = TwoLevelLRU(self.n_slots)
-        self.buffer = make_buffer(cfg, self.n_slots, jnp.bfloat16)
+        # the pool holds experts in the model's dtype: a bf16 pool under an
+        # f32 model would round every expert it swaps in
+        self.buffer = make_buffer(cfg, self.n_slots, model.dtype)
         self.swap_count = 0
         self.would_stall = 0
         self.fused = fused
@@ -452,18 +510,13 @@ class SlotBufferEngine:
         # every warm jitted dispatch funnels through this counter so
         # jit_calls accounting cannot drift from the calls actually made
         self._dispatch = Dispatcher(self.stats)
-        # per-absolute-layer params, sliced from the stacked tree ONCE
-        self._p = [_layer_params(model, params, i)
-                   for i in range(len(self.specs))]
+        # per-absolute-layer params (routed experts stripped)
+        self._p = params.layers
         # expert weight source: pre-staged contiguous host views by default,
         # or a caller-supplied TieredExpertStore (core.expert_tiers) whose
         # host residency the demand/prefetch paths must guarantee first
         if store is None:
-            self.store = HostExpertStore()
-            for li, i in enumerate(self.moe_layer_ids):
-                mp = self._p[i]["moe"]
-                self.store.add_layer(li, mp["w_gate"], mp["w_up"],
-                                     mp["w_down"])
+            self.store = self.experts
             self.tiers = None
         else:
             self.store = store
@@ -599,10 +652,10 @@ class SlotBufferEngine:
         return self._fns[key]
 
     def _ffn_fn(self, spec: LayerSpec):
-        key = ("ffn", self._spec_key(spec))
+        use_kernel = self.use_kernel
+        key = ("ffn", self._spec_key(spec), use_kernel)
         if key not in self._fns:
             cfg = self.cfg
-            use_kernel = self.use_kernel
             from repro.models.transformer import _zc
 
             def fn(p, slot_weights, slot_map, x, flat, r):
@@ -1108,7 +1161,7 @@ class SlotBufferEngine:
             for key in keys:
                 self.cache.unpin(key)
         if missing:
-            self._dispatch_swap(slots, self.store.gather(li, missing))
+            self._dispatch_swap(slots, [(li, e) for e in missing])
             self.stats.swap_experts += len(missing)
         self.swap_count += len(missing)
         return len(missing)
@@ -1149,13 +1202,19 @@ class SlotBufferEngine:
                 self.controller.record_overfetch()
         self.prefetcher.forget(victim, count_unused=not deferred)
 
-    def _dispatch_swap(self, slots: List[int], weights) -> None:
-        """One batched donated device write; host wall time feeds the
-        controller's bandwidth estimate C_s."""
+    def _dispatch_swap(self, slots: List[int],
+                       keys: List[Tuple[int, int]]) -> None:
+        """Batched donated device writes of `keys` into `slots`, at most
+        SWAP_CHUNK experts each: that bounds the device transfer buffers (a
+        whole prefetch window of olmoe-1b-7b experts would be gigabytes).
+        Host wall time feeds the controller's bandwidth estimate C_s."""
         before = self.swap_timer.elapsed
         with self.swap_timer.section():
-            self.buffer = swap_in_many(self.buffer, slots, *weights)
-        self.stats.swap_calls += 1
+            for i in range(0, len(keys), SWAP_CHUNK):
+                self.buffer = swap_in_many(
+                    self.buffer, slots[i:i + SWAP_CHUNK],
+                    *zip(*self.store.experts(keys[i:i + SWAP_CHUNK])))
+                self.stats.swap_calls += 1
         self.controller.update_bandwidth(
             len(slots) * self._expert_nbytes,
             self.swap_timer.elapsed - before)
@@ -1220,7 +1279,7 @@ class SlotBufferEngine:
             for key in issued_keys:
                 self.cache.unpin(key)
         if issued_keys:
-            self._dispatch_swap(slots, self.store.gather_many(issued_keys))
+            self._dispatch_swap(slots, issued_keys)
             self.stats.swap_experts += len(issued_keys)
             self.stats.prefetched += len(issued_keys)
         self.swap_count += len(issued_keys)
@@ -1274,11 +1333,21 @@ class SlotBufferEngine:
         self.cache.protect_early_layers(1)
         return x
 
+    def _staged_experts(self, li: int, after) -> Dict[str, jnp.ndarray]:
+        """All of MoE layer li's experts, put on the device for the one
+        reference FFN dispatch that reads them (identity slot table).
+        Staged only once `after` — the FFN's input, so every earlier
+        layer — has run: dispatch would otherwise run ahead and hold
+        several layers' experts on the device at once."""
+        jax.block_until_ready(after)
+        return dict(zip(ROUTED_EXPERT_KEYS,
+                        (jnp.asarray(w) for w in self.experts.layer(li))))
+
     def reference_forward(self, tokens: jnp.ndarray) -> jnp.ndarray:
-        """Fully-resident oracle through the SAME jitted functions: MoE
-        weights come straight from the stacked params with the identity
-        slot table — no buffer, no swaps, no cache. The slot path must match
-        this bitwise whenever the working set stays resident."""
+        """Fully-resident oracle through the SAME jitted functions: each MoE
+        layer's experts are staged whole from the host store with the
+        identity slot table — no buffer, no swaps, no cache. The slot path
+        must match this bitwise whenever the working set stays resident."""
         tokens = jnp.asarray(tokens, jnp.int32)
         x, positions = self._embed_fn()(self.params, tokens)
         li = 0
@@ -1293,9 +1362,8 @@ class SlotBufferEngine:
             want_pred = self.prefetch_enabled and nxt is not None
             x, flat, r, _ = self._pre_fn(spec, want_pred)(
                 p, x, positions, nxt if want_pred else None)
-            full = {"w_gate": p["moe"]["w_gate"], "w_up": p["moe"]["w_up"],
-                    "w_down": p["moe"]["w_down"]}
-            x = self._ffn_fn(spec)(p, full, self._ident_map, x, flat, r)
+            x = self._ffn_fn(spec)(p, self._staged_experts(li, x),
+                                   self._ident_map, x, flat, r)
             li += 1
         return x
 
@@ -2092,11 +2160,13 @@ class SlotBufferEngine:
     # -- fully-resident decode oracle ---------------------------------------
     def reference_prefill(self, tokens) -> Tuple[jnp.ndarray, DecodeState]:
         """Prefill through the SAME jitted functions with the identity slot
-        table over the raw stacked weights — no buffer, no swaps."""
+        table over each layer's whole staged expert set — no buffer, no
+        swaps."""
         tokens = jnp.asarray(tokens, jnp.int32)
         B, T = tokens.shape
         x, positions = self._embed_fn()(self.params, tokens)
         caches: List[Any] = []
+        li = 0
         for i, spec in enumerate(self.specs):
             p = self._p[i]
             if not spec.is_moe:
@@ -2105,9 +2175,9 @@ class SlotBufferEngine:
                 continue
             x, flat, r, _, c = self._pre_prefill_fn(spec)(p, x, positions)
             caches.append(c)
-            full = {"w_gate": p["moe"]["w_gate"], "w_up": p["moe"]["w_up"],
-                    "w_down": p["moe"]["w_down"]}
-            x = self._ffn_fn(spec)(p, full, self._ident_map, x, flat, r)
+            x = self._ffn_fn(spec)(p, self._staged_experts(li, x),
+                                   self._ident_map, x, flat, r)
+            li += 1
         logits = self._logits_fn()(self.params, x)
         return logits, DecodeState(caches, jnp.asarray(T, jnp.int32),
                            pos=int(T))
@@ -2129,6 +2199,7 @@ class SlotBufferEngine:
         tok = jnp.asarray(tok, jnp.int32)
         caches, clen = list(state.caches), state.cache_len
         x = self._embed_decode_fn()(self.params, tok, clen)
+        li = 0
         for i, spec in enumerate(self.specs):
             p = self._p[i]
             if not spec.is_moe:
@@ -2138,9 +2209,9 @@ class SlotBufferEngine:
             x2, flat, r, _, c2 = self._pre_decode_fn(spec)(p, x, caches[i],
                                                            clen)
             caches[i] = c2
-            full = {"w_gate": p["moe"]["w_gate"], "w_up": p["moe"]["w_up"],
-                    "w_down": p["moe"]["w_down"]}
-            x = self._ffn_fn(spec)(p, full, self._ident_map, x2, flat, r)
+            x = self._ffn_fn(spec)(p, self._staged_experts(li, x2),
+                                   self._ident_map, x2, flat, r)
+            li += 1
         logits = self._logits_fn()(self.params, x)
         return logits, DecodeState(caches, clen + 1, pos=state.pos + 1)
 
@@ -2165,11 +2236,6 @@ class SlotBufferEngine:
         return np.stack(out, axis=1)
 
     # -- pre-fused execution (benchmark baseline) ---------------------------
-    def _expert_weights(self, li: int, e: int):
-        p = _layer_params(self.model, self.params, self.moe_layer_ids[li])
-        return (p["moe"]["w_gate"][e], p["moe"]["w_up"][e],
-                p["moe"]["w_down"][e])
-
     def _ensure_resident_seq(self, li: int, experts) -> int:
         """Pre-fused swap path: one jitted dispatch + param-tree re-slice
         per missing expert."""
@@ -2184,7 +2250,7 @@ class SlotBufferEngine:
             if victim is not None:
                 self.table.release(*victim)
             slot = self.table.assign(li, int(e))
-            wg, wu, wd = self._expert_weights(li, int(e))
+            wg, wu, wd = (w[int(e)] for w in self.experts.layer(li))
             self.buffer = swap_in(self.buffer, slot, wg, wu, wd)
             self.stats.swap_calls += 1
             self.stats.swap_experts += 1
@@ -2205,7 +2271,7 @@ class SlotBufferEngine:
         li = 0
         from repro.models.transformer import _zc
         for i, spec in enumerate(self.specs):
-            p = _layer_params(model, self.params, i)
+            p = self._p[i]
             if not spec.is_moe:
                 x = layer_forward(p, cfg, spec, x, positions)
                 continue

@@ -35,11 +35,28 @@ PLATFORMS: Dict[str, HardwareSpec] = {
     "rtx4090": HardwareSpec("rtx4090", 32 * GB, 165e12, 1.0 * TB, 20 * GB),
     "arc_b580": HardwareSpec("arc_b580", 16 * GB, 27e12, 0.456 * TB, 12 * GB),
     "rx6500xt": HardwareSpec("rx6500xt", 8 * GB, 16e12, 0.144 * TB, 4 * GB),
-    # TPU v5e: 197 TFLOP/s bf16, 819 GB/s HBM, 16 GB, ~50 GB/s/link ICI,
-    # host DMA ~32 GB/s per direction
+    # TPU v5e (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s bf16,
+    # 819 GB/s HBM, 16 GB; ~50 GB/s/link ICI, host DMA ~32 GB/s per
+    # direction (estimates, not from that page)
     "tpu_v5e": HardwareSpec("tpu_v5e", 32 * GB, 197e12, 819 * GB, 16 * GB,
                             ici_bw=50 * GB),
 }
+
+# `jax.Device.device_kind` -> PLATFORMS entry, for runs on a real device
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v5e": "tpu_v5e",
+}
+
+
+def platform_for_device_kind(kind: str) -> HardwareSpec:
+    """The spec of the device a run is on; an unknown kind is an error,
+    never a default."""
+    if kind not in DEVICE_KINDS:
+        raise ValueError(f"no hardware spec for device kind {kind!r}; add "
+                         "it to simulator.hardware.DEVICE_KINDS with its "
+                         "source")
+    return PLATFORMS[DEVICE_KINDS[kind]]
 
 # the paper caps GPU memory at 20 GB across platforms (§4.1); the expert
 # working set budget is what's left after weights/KV of the dense parts.

@@ -5,7 +5,7 @@ import pytest
 
 from repro.configs.registry import get_config
 from repro.core.capacity_planner import (expected_active_per_layer, plan)
-from repro.simulator.hardware import PLATFORMS
+from repro.simulator.hardware import PLATFORMS, platform_for_device_kind
 
 
 def test_expected_active_monotone_in_batch():
@@ -61,3 +61,9 @@ def test_faster_link_smaller_s():
     # S = N_e*E_s/(C_s*T_l): same T_l model, 4x bandwidth -> smaller-or-equal S
     assert fast.s_initial <= slow.s_initial
     assert fast.summary()  # renders
+
+
+def test_device_kind_maps_to_spec_and_unknown_is_an_error():
+    assert platform_for_device_kind("TPU v5 lite") is PLATFORMS["tpu_v5e"]
+    with pytest.raises(ValueError, match="TPU v99"):
+        platform_for_device_kind("TPU v99")

@@ -26,20 +26,21 @@ from repro.runtime.instrument import Stopwatch
 # fast lane: new supporting pieces
 # ---------------------------------------------------------------------------
 
-def test_host_store_gather_many_matches_per_layer_gather():
+def test_host_store_experts_match_per_layer_gather():
     rng = np.random.default_rng(0)
     store = HostExpertStore()
     for layer in range(3):
         store.add_layer(layer, rng.normal(size=(4, 6, 5)),
                         rng.normal(size=(4, 6, 5)), rng.normal(size=(4, 5, 6)))
     keys = [(0, 1), (0, 3), (2, 0), (1, 2), (1, 1)]
-    wg, wu, wd = store.gather_many(keys)
-    assert wg.shape == (5, 6, 5) and wd.shape == (5, 5, 6)
-    for row, (layer, e) in enumerate(keys):
-        g1, u1, d1 = store.gather(layer, [e])
-        np.testing.assert_array_equal(wg[row], g1[0])
-        np.testing.assert_array_equal(wu[row], u1[0])
-        np.testing.assert_array_equal(wd[row], d1[0])
+    got = store.experts(keys)
+    assert len(got) == len(keys)
+    for (wg, wu, wd), (layer, e) in zip(got, keys):
+        assert wg.shape == (6, 5) and wd.shape == (5, 6)
+        g1, u1, d1 = store.layer(layer)
+        np.testing.assert_array_equal(wg, g1[e])
+        np.testing.assert_array_equal(wu, u1[e])
+        np.testing.assert_array_equal(wd, d1[e])
 
 
 def test_prefetcher_unused_prefetch_accounting():

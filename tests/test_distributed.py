@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.registry import get_smoke_config
 from repro.distributed import sharding as shd
@@ -17,7 +17,7 @@ from repro.models import Model
 
 
 def test_resolve_spec_drops_nondivisible():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = jax.make_mesh((1,), ("model",), axis_types=(AxisType.Auto,))
     # (shape divisible check) — 1-device mesh: everything divides
     spec = shd.resolve_spec(("model", None), (7, 3), mesh)
     assert spec == P("model", None)
@@ -72,10 +72,9 @@ def test_pipeline_stages_single_stage_identity():
 
     pipelined = pipeline_stages(stage, n_stages=1, n_microbatches=3,
                                 axis_name="pod")
-    mesh = jax.make_mesh((1,), ("pod",))
-    from jax.experimental.shard_map import shard_map
-    f = shard_map(pipelined, mesh=mesh, in_specs=(P(), P()),
-                  out_specs=P(), check_rep=False)
+    mesh = jax.make_mesh((1,), ("pod",), axis_types=(AxisType.Auto,))
+    f = jax.shard_map(pipelined, mesh=mesh, in_specs=(P(), P()),
+                      out_specs=P(), check_vma=False)
     x = jnp.arange(6, dtype=jnp.float32).reshape(3, 2)
     out = f(jnp.asarray(2.0), x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x) * 2)
@@ -97,7 +96,8 @@ def test_small_mesh_pjit_forward_matches_single_device():
     toks = jnp.asarray(np.random.default_rng(0).integers(
         0, cfg.vocab_size, (2, 8)), jnp.int32)
     ref = model.forward(params, toks)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     with shd.mesh_context(mesh):
         shardings = shd.param_shardings(params, mesh)
         p_sh = jax.device_put(params, shardings)

@@ -104,11 +104,9 @@ def test_host_expert_store_gathers_contiguous_views():
     wd = jnp.asarray(rng.standard_normal((E, f, d)), jnp.bfloat16)
     store = HostExpertStore()
     store.add_layer(0, wg, wu, wd)
-    g_wg, g_wu, g_wd = store.gather(0, [4, 1])
-    assert isinstance(g_wg, np.ndarray) and g_wg.flags["C_CONTIGUOUS"]
-    np.testing.assert_array_equal(np.asarray(g_wg, np.float32),
-                                  np.asarray(wg[jnp.asarray([4, 1])],
-                                             np.float32))
-    np.testing.assert_array_equal(np.asarray(g_wd, np.float32),
-                                  np.asarray(wd[jnp.asarray([4, 1])],
-                                             np.float32))
+    for e, (g_wg, g_wu, g_wd) in zip((4, 1), store.experts([(0, 4), (0, 1)])):
+        assert isinstance(g_wg, np.ndarray) and g_wg.flags["C_CONTIGUOUS"]
+        np.testing.assert_array_equal(np.asarray(g_wg, np.float32),
+                                      np.asarray(wg[e], np.float32))
+        np.testing.assert_array_equal(np.asarray(g_wd, np.float32),
+                                      np.asarray(wd[e], np.float32))

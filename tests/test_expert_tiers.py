@@ -1,5 +1,5 @@
 """Tiered expert store: shard format round-trips, host staging tier
-semantics (budget/LRU/pins), gather_many staging-buffer regression, and
+semantics (budget/LRU/pins), per-expert host views, and
 bit-exact engine serving through the disk->host->device chain."""
 import json
 import os
@@ -46,10 +46,9 @@ def test_shard_roundtrip_bitwise(tmp_path, dtype):
     for li in range(2):
         for e in range(4):
             got = rd.read_expert(li, e)
-            want = st.gather(li, [e])
-            for g, w in zip(got, want):
+            for g, w in zip(got, st.experts([(li, e)])[0]):
                 assert g.dtype == w.dtype
-                np.testing.assert_array_equal(_bits(g), _bits(w[0]))
+                np.testing.assert_array_equal(_bits(g), _bits(w))
 
 
 def test_shard_noncontiguous_subset_and_cross_layer_gather(tmp_path):
@@ -59,23 +58,26 @@ def test_shard_noncontiguous_subset_and_cross_layer_gather(tmp_path):
         export_expert_shards(st, str(tmp_path / "sh")))
     # non-contiguous, unordered subset within one layer
     subset = [6, 1, 3]
-    for key in [(1, e) for e in subset]:
+    sub_keys = [(1, e) for e in subset]
+    for key in sub_keys:
         assert tiered.demand_host(key, 0.0) is not None
-    for g, w in zip(tiered.gather(1, subset), st.gather(1, subset)):
-        np.testing.assert_array_equal(g, w)
-    # gather_many spanning layers in interleaved order
+    for ge, we in zip(tiered.experts(sub_keys), st.experts(sub_keys)):
+        for g, w in zip(ge, we):
+            np.testing.assert_array_equal(g, w)
+    # per-expert weights spanning layers in interleaved order
     keys = [(0, 5), (2, 0), (1, 6), (0, 2), (2, 7)]
     for key in keys:
         assert tiered.demand_host(key, 0.0) is not None
-    for g, w in zip(tiered.gather_many(keys), st.gather_many(keys)):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    for ge, we in zip(tiered.experts(keys), st.experts(keys)):
+        for g, w in zip(ge, we):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_gather_before_residency_is_a_scheduling_bug(tmp_path):
     st = _store(np.random.default_rng(2))
     tiered = TieredExpertStore(export_expert_shards(st, str(tmp_path / "s")))
     with pytest.raises(RuntimeError, match="not staged"):
-        tiered.gather(0, [0])
+        tiered.experts([(0, 0)])
 
 
 def test_truncated_and_corrupt_shards_raise_shard_error(tmp_path):
@@ -222,39 +224,18 @@ def test_disk_prefetch_converts_misses_to_hits():
 
 
 # --------------------------------------------------------------------------
-# gather_many staging buffer regression (satellite b)
+# per-expert host views: what swap_in_many sends, with no host copy
 # --------------------------------------------------------------------------
 
-def test_gather_many_staging_buffer_bit_exact_and_reused():
+def test_host_store_experts_are_uncopied_views_bit_exact():
     rng = np.random.default_rng(7)
     for dtype in (np.float32, ml_dtypes.bfloat16):
         st = _store(rng, layers=3, experts=8, dtype=dtype)
-
-        def naive(keys):
-            outs = [st.gather(li, [e]) for li, e in keys]
-            return tuple(np.concatenate([o[t] for o in outs])
-                         for t in range(3))
-
-        k1 = [(0, 3), (0, 5), (1, 1), (2, 7), (2, 0)]
-        got1 = st.gather_many(k1)
-        for g, w in zip(got1, naive(k1)):
-            np.testing.assert_array_equal(_bits(g), _bits(w))
-        got1 = tuple(np.array(g) for g in got1)   # copy before reuse
-
-        # second call with the same padded shape reuses the SAME buffer
-        k2 = [(2, 2), (1, 4), (0, 0), (1, 6), (0, 7)]
-        got2 = st.gather_many(k2)
-        for g, w in zip(got2, naive(k2)):
-            np.testing.assert_array_equal(_bits(g), _bits(w))
-        # first result copies are unaffected by the buffer reuse
-        for g, w in zip(got1, naive(k1)):
-            np.testing.assert_array_equal(_bits(g), _bits(w))
-        assert len(st._staging) == 1          # one signature -> one buffer
-
-        # single-layer call keeps the fancy-index fast path
-        for g, w in zip(st.gather_many([(1, 2), (1, 5)]),
-                        st.gather(1, [2, 5])):
-            np.testing.assert_array_equal(_bits(g), _bits(w))
+        keys = [(0, 3), (0, 5), (1, 1), (2, 7), (2, 0)]
+        for (li, e), ws in zip(keys, st.experts(keys)):
+            for w, whole in zip(ws, st.layer(li)):
+                np.testing.assert_array_equal(_bits(w), _bits(whole[e]))
+                assert np.shares_memory(w, whole) and w.flags.c_contiguous
 
 
 # --------------------------------------------------------------------------
@@ -281,8 +262,7 @@ def test_engine_bit_exact_through_tier_at_half_budget(tmp_path, arch):
     pre-staged HostExpertStore, under host-tier eviction churn (GQA and
     MLA architectures)."""
     from repro.configs.registry import get_smoke_config
-    from repro.runtime.engine import (Engine, SlotBufferEngine,
-                                      build_host_store)
+    from repro.runtime.engine import Engine, SlotBufferEngine
     cfg = get_smoke_config(arch)
     eng = Engine(cfg, max_seq=48)
     kw = dict(n_slots_per_layer=2, step_size=1, max_seq=48)
@@ -292,8 +272,7 @@ def test_engine_bit_exact_through_tier_at_half_budget(tmp_path, arch):
     ref = SlotBufferEngine(cfg, eng.params, eng.model, **kw)
     want = _greedy_tokens(ref, prompt, 6)
 
-    sdir = export_expert_shards(build_host_store(eng.model, eng.params),
-                                str(tmp_path / arch))
+    sdir = export_expert_shards(ref.experts, str(tmp_path / arch))
     store = TieredExpertStore(
         sdir, host_budget_bytes=0.5 * TieredExpertStore(sdir).
         total_expert_bytes)

@@ -130,3 +130,14 @@ def test_slot_ffn_non_tile_aligned_shapes(C, F):
     ref = ops.slot_ffn_ref(x, soe, sg, su, sd)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_interpret_mode_on_cpu_only(monkeypatch):
+    """Mosaic on the TPU, the interpreter on the CPU, and an error on any
+    other backend: the kernels never fall back to the interpreter there."""
+    for backend, want in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert ops._default_interpret() is want
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops._default_interpret()

@@ -162,3 +162,28 @@ def test_router_out_skips_rerouting():
     out_b, _ = moe_mod.moe_slotbuf(params, sw, ident, x, moe, capacity=T * k,
                                    router_out=r)
     np.testing.assert_array_equal(np.asarray(out_a), np.asarray(out_b))
+
+
+@pytest.mark.parametrize("use_kernel", [False, True], ids=["einsum", "kernel"])
+def test_output_independent_of_slot_placement(use_kernel):
+    """Which slots hold a layer's experts must not change its output: each
+    token's k expert outputs are summed in the router's order, not in slot
+    order (in f32 a different order of the sum changes the last bits)."""
+    rng = np.random.default_rng(4)
+    d, E, f, T = 32, 8, 16, 24
+    moe = MoEConfig(num_experts=E, top_k=4, d_expert=f, capacity_factor=2.0)
+    p = _mk_params(rng, d, E, f)
+    p["router"] = jnp.asarray(rng.standard_normal((d, E)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((T, d)), jnp.float32)
+    ws = {k: p[k] for k in ("w_gate", "w_up", "w_down")}
+    outs = []
+    for perm in (np.arange(E), rng.permutation(E), rng.permutation(E)[::-1]):
+        # expert e in slot perm[e] of a buffer with two spare slots
+        slots = {k: jnp.zeros((E + 2,) + w.shape[1:], w.dtype)
+                 .at[perm].set(w) for k, w in ws.items()}
+        out, _ = moe_mod.moe_slotbuf(p, slots, jnp.asarray(perm, jnp.int32),
+                                     x, moe, capacity=T * 4,
+                                     use_kernel=use_kernel)
+        outs.append(np.asarray(out))
+    for o in outs[1:]:
+        np.testing.assert_array_equal(o, outs[0])

@@ -8,8 +8,13 @@ from repro.checkpoint import Checkpointer, load_checkpoint, save_checkpoint
 from repro.configs.registry import get_smoke_config
 from repro.core import FeatureSpec, ForestPredictor
 from repro.runtime.batching import ContinuousBatcher
-from repro.runtime.engine import Engine, SlotBufferEngine, _all_specs, \
-    _layer_params
+from repro import compile_cache
+from repro.models import Model
+from repro.runtime import engine as engine_mod
+from repro.runtime.engine import (ROUTED_EXPERT_KEYS, Engine,
+                                  SlotBufferEngine, _all_specs,
+                                  _layer_params, init_serving_params,
+                                  split_params)
 from repro.runtime.request import Request
 from repro.models.transformer import layer_forward
 
@@ -166,10 +171,10 @@ def test_prefetch_never_self_evicts_into_duplicate_slots():
     s0 = sb.table.lookup(1, 0)                # stacked onto the first
     assert s0 >= 0 and sb.table.lookup(1, 1) == -1
     # table and buffer agree: the issued expert's weights are in its slot
-    wg_expected = sb.store.gather(1, [0])[0]
+    wg_expected = sb.store.experts([(1, 0)])[0][0]
     np.testing.assert_array_equal(
         np.asarray(sb.buffer["w_gate"][s0], np.float32),
-        np.asarray(wg_expected[0], np.float32))
+        np.asarray(wg_expected, np.float32))
 
 
 @pytest.mark.slow
@@ -192,6 +197,70 @@ def test_slot_buffer_kernel_path_matches_einsum():
     np.testing.assert_allclose(np.asarray(x_k, np.float32),
                                np.asarray(x_e, np.float32),
                                rtol=5e-2, atol=5e-2)
+    # switching an engine to the kernel after it served on the einsum path
+    # runs the kernel, not the einsum function cached for the same layer
+    sb_e.use_kernel = True
+    assert float(jnp.max(jnp.abs(sb_e.forward(toks) - x_k))) == 0.0
+
+
+@pytest.mark.parametrize("arch", ["olmoe-1b-7b", "deepseek-v2-lite"],
+                         ids=["gqa", "mla"])
+def test_init_serving_params_equals_split_of_full_init(arch):
+    """Built layer by layer, the routed experts land in host memory only,
+    and every value equals the full stacked tree's (`Model.init`)."""
+    cfg = get_smoke_config(arch)
+    model = Model(cfg)
+    key = jax.random.PRNGKey(3)
+    got = init_serving_params(model, key)
+    want = split_params(model, model.init(key))
+
+    def same(a, b):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+    n_moe = sum(s.is_moe for s in _all_specs(model))
+    assert len(got.experts) == len(want.experts) == n_moe
+    for li in range(n_moe):
+        for a, b in zip(got.experts.layer(li), want.experts.layer(li)):
+            assert isinstance(a, np.ndarray)
+            same(a, b)
+    jax.tree.map(same, (got.top, got.layers), (want.top, want.layers))
+    assert not any(set(ROUTED_EXPERT_KEYS) & set(p.get("moe", {}))
+                   for p in got.layers)
+
+
+@pytest.mark.slow
+def test_swap_writes_chunked_and_exact(monkeypatch):
+    """A swap window wider than SWAP_CHUNK goes to the device in writes of
+    at most SWAP_CHUNK experts, and the slot path stays bit-exact."""
+    monkeypatch.setattr(engine_mod, "SWAP_CHUNK", 2)
+    cfg = get_smoke_config("olmoe-1b-7b")
+    eng = Engine(cfg, max_seq=64)
+    toks = jnp.asarray(np.random.default_rng(5).integers(
+        0, cfg.vocab_size, (2, 12)), jnp.int32)
+    sb = SlotBufferEngine(cfg, eng.params, eng.model,
+                          n_slots_per_layer=cfg.moe.num_experts)
+    x = sb.forward(toks)
+    assert float(jnp.max(jnp.abs(x - sb.reference_forward(toks)))) == 0.0
+    st = sb.stats
+    assert st.swap_calls >= st.swap_experts / 2
+    assert st.swap_calls > 2 * len(sb.moe_layer_ids)   # windows were split
+
+
+def test_compile_cache_env_dir_wins_else_checkout(monkeypatch, tmp_path):
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", prev)
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev   # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = compile_cache.enable_compile_cache()
+        assert got == jax.config.jax_compilation_cache_dir
+        assert got == str(compile_cache.CACHE_DIR)
+        assert (compile_cache.CACHE_DIR.parent / "src" / "repro").is_dir()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 @pytest.mark.slow
