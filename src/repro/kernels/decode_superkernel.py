@@ -40,6 +40,7 @@ from repro.kernels.slot_gather import _fit_block
 
 NEG = -1e30                 # top-k masking (matches kernels/topk_gating.py)
 NEG_INF = -2.0 ** 30        # attention masking (matches models/attention.py)
+BLOCK_F = 128               # fused MoE entry's d_ff tile (fitted to d_ff)
 
 
 # ---------------------------------------------------------------------------
@@ -106,13 +107,12 @@ def _fused_moe_kernel(slot_ref, x_ref, rw_ref, bias_ref, raw_ref, wg_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("top_k", "norm_topk",
-                                             "block_f", "interpret"))
+                                             "interpret"))
 def fused_moe_entry(x: jnp.ndarray, router_w: jnp.ndarray,
                     logit_bias: jnp.ndarray, slot_of_expert: jnp.ndarray,
                     s_gate: jnp.ndarray, s_up: jnp.ndarray,
                     s_down: jnp.ndarray, *, top_k: int,
-                    norm_topk: bool = True, block_f: int = 128,
-                    interpret: bool = False):
+                    norm_topk: bool = True, interpret: bool = False):
     """x: (T, d) tokens; router_w: (d, E); logit_bias: (E,) additive fp32
     (zeros when cache-aware routing is off — bit-exact); slot_of_expert:
     (E,) int32, -1 = non-resident; slot buffers (S, d, f)/(S, f, d).
@@ -124,7 +124,7 @@ def fused_moe_entry(x: jnp.ndarray, router_w: jnp.ndarray,
     T, d = x.shape
     E = router_w.shape[1]
     f = s_gate.shape[-1]
-    bf = _fit_block(f, block_f)
+    bf = _fit_block(f, BLOCK_F)
     raw = slot_of_expert.astype(jnp.int32)
     # scalar prefetch: the clamped slot row (a valid BlockSpec index even
     # for misses, whose output is gate-zeroed); the raw row rides in VMEM

@@ -370,6 +370,10 @@ class SlotPathStats:
 # (see `_dispatch_swap`)
 SWAP_CHUNK = 32
 
+# one swap window in this many is timed to completion for the controller's
+# host->device bandwidth estimate C_s (see `_dispatch_swap`)
+BANDWIDTH_SAMPLE_EVERY = 8
+
 # chunked prefill: fixed prompt-chunk width C. Every chunk dispatch is a
 # padded (1, C) shape, so the jit cache is keyed on (C, layer spec) only —
 # compile count stays flat no matter how many distinct prompt lengths a
@@ -1207,17 +1211,28 @@ class SlotBufferEngine:
         """Batched donated device writes of `keys` into `slots`, at most
         SWAP_CHUNK experts each: that bounds the device transfer buffers (a
         whole prefetch window of olmoe-1b-7b experts would be gigabytes).
-        Host wall time feeds the controller's bandwidth estimate C_s."""
-        before = self.swap_timer.elapsed
+
+        `device_put` returns before its transfer ends, so a window's host
+        time is an enqueue time, not a transfer rate. One window in
+        BANDWIDTH_SAMPLE_EVERY feeds the controller's C_s instead: it
+        starts once earlier writes to the pool have landed (untimed) and is
+        timed until its last write has landed. Compute still reading the
+        pool delays that write too, so a sample errs slow, never fast."""
+        sample = self.swap_timer.calls % BANDWIDTH_SAMPLE_EVERY == 0
+        if sample:
+            jax.block_until_ready(self.buffer)
         with self.swap_timer.section():
+            t0 = time.perf_counter()
             for i in range(0, len(keys), SWAP_CHUNK):
                 self.buffer = swap_in_many(
                     self.buffer, slots[i:i + SWAP_CHUNK],
                     *zip(*self.store.experts(keys[i:i + SWAP_CHUNK])))
                 self.stats.swap_calls += 1
-        self.controller.update_bandwidth(
-            len(slots) * self._expert_nbytes,
-            self.swap_timer.elapsed - before)
+            if sample:
+                jax.block_until_ready(self.buffer)
+                self.controller.update_bandwidth(
+                    len(slots) * self._expert_nbytes,
+                    time.perf_counter() - t0)
 
     def prefetch_layer(self, li: int, experts) -> int:
         """Speculatively swap in predicted experts for ONE future layer

@@ -117,12 +117,10 @@ class Dispatcher:
 class Stopwatch:
     """Tiny wall-clock section timer feeding the step-size controller.
 
-    The engine times swap dispatches (-> `update_bandwidth`) and whole
-    decode steps (-> `update_layer_time`). Host wall time around an async
-    dispatch under-reports true transfer latency, but tracks it
-    monotonically — exactly what the controller's EWMA needs as a signal,
-    without inserting blocking `block_until_ready` barriers into the hot
-    path."""
+    The engine times swap dispatches and whole decode steps
+    (-> `update_layer_time`). Host wall time around an async dispatch is
+    its enqueue time, so the engine feeds `update_bandwidth` only from
+    swap windows it blocks on (see `SlotBufferEngine._dispatch_swap`)."""
     elapsed: float = 0.0
     calls: int = 0
 

@@ -124,20 +124,13 @@ def test_chunked_prefill_bit_exact_vs_monolithic_under_churn(chunk_setup):
         assert chun.cache.stats.evictions > 0    # the cache really churned
 
 
-@pytest.mark.slow
-def test_chunked_prefill_matches_monolithic_at_published_attention_width():
-    """At olmoe-1b-7b's attention widths (d_model 2048, 16 heads of 128)
-    bitwise equality no longer holds: XLA reduces a 16- or 32-row chunk's
-    dots in another order than a 64-row prompt's. In f32 that reordering
-    is all that differs — 5e-7 relative RMS measured on the CPU — so 1e-5
-    holds the chunk path to it; a wrong mask or offset moves the logits by
-    orders of magnitude more. (In bf16 the same reordering flips rounding
-    and compounds with depth: 1.7e-3 at 2 layers on the CPU, 1.7e-2 at 16
-    on a v5e.)"""
+def _check_chunked_vs_monolithic_f32(layers):
+    """Chunks of 32 and 16 vs whole-prompt prefill of a 64-token prompt, in
+    f32 at olmoe-1b-7b's attention widths with 4 experts of d_ff 128."""
     import dataclasses
     base = get_config("olmoe-1b-7b")
     cfg = dataclasses.replace(
-        base, num_layers=1, vocab_size=512, dtype="float32",
+        base, num_layers=layers, vocab_size=512, dtype="float32",
         moe=dataclasses.replace(base.moe, num_experts=4, top_k=2,
                                 d_expert=128))
     eng = Engine(cfg, max_seq=64)
@@ -151,6 +144,28 @@ def test_chunked_prefill_matches_monolithic_at_published_attention_width():
                              np.float64)
         rel = np.sqrt(np.mean((chunked - mono) ** 2) / np.mean(mono ** 2))
         assert rel < 1e-5, (C, rel)
+
+
+@pytest.mark.slow
+def test_chunked_prefill_matches_monolithic_at_published_attention_width():
+    """At olmoe-1b-7b's attention widths (d_model 2048, 16 heads of 128)
+    bitwise equality no longer holds: XLA reduces a 16- or 32-row chunk's
+    dots in another order than a 64-row prompt's. In f32 that reordering
+    is all that differs — 4e-7..5e-7 relative RMS on the CPU at one layer —
+    so 1e-5 holds the chunk path to it; a wrong mask or offset moves the
+    logits by orders of magnitude more."""
+    _check_chunked_vs_monolithic_f32(layers=1)
+
+
+@pytest.mark.slow
+def test_chunked_prefill_matches_monolithic_at_published_depth():
+    """The same at olmoe-1b-7b's 16 layers: in f32 the reordering stays at
+    1.0e-6..1.2e-6 relative RMS on the CPU, so the chunk path holds 1e-5
+    through the whole depth. In bf16 it flips roundings that compound with
+    depth instead (1.1e-2..7.6e-2 at 16 layers on the CPU, 1.7e-2 on a
+    v5e), which is why `chip_smoke.py` prints the bf16 chunked gap and does
+    not bound it."""
+    _check_chunked_vs_monolithic_f32(layers=16)
 
 
 @pytest.mark.slow

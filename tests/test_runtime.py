@@ -247,6 +247,26 @@ def test_swap_writes_chunked_and_exact(monkeypatch):
     assert st.swap_calls > 2 * len(sb.moe_layer_ids)   # windows were split
 
 
+def test_bandwidth_estimate_sampled_from_completed_windows(monkeypatch):
+    """C_s takes one swap window in BANDWIDTH_SAMPLE_EVERY, and each sample
+    is timed until the window's writes have landed on the device."""
+    cfg = get_smoke_config("olmoe-1b-7b")
+    eng = Engine(cfg, max_seq=64)
+    sb = SlotBufferEngine(cfg, eng.params, eng.model, n_slots_per_layer=3)
+    ready, samples = [], []
+    real_block = jax.block_until_ready
+    monkeypatch.setattr(engine_mod.jax, "block_until_ready",
+                        lambda x: ready.append(x) or real_block(x))
+    monkeypatch.setattr(sb.controller, "update_bandwidth",
+                        lambda b, s: samples.append((b, len(ready))))
+    n = 2 * engine_mod.BANDWIDTH_SAMPLE_EVERY + 1
+    for w in range(n):
+        sb._dispatch_swap([w % 3], [(sb.moe_layer_ids[0], w % 4)])
+    assert [b for b, _ in samples] == [sb._expert_nbytes] * 3
+    # each sample drained the device before its window and after it
+    assert [r for _, r in samples] == [2, 4, 6]
+
+
 def test_compile_cache_env_dir_wins_else_checkout(monkeypatch, tmp_path):
     prev = jax.config.jax_compilation_cache_dir
     try:
