@@ -128,7 +128,7 @@ def _serve(cfg, eng, p, reqs, route_bias=None, adaptive=False,
         "late_hits": stats["late_hits"],
         "replays": stats["replays"],
         "swap_experts": stats["swap_experts"],
-        "stall_events": sb.would_stall,
+        "stall_events": stats["demand_misses"],
         "throughput_tok_s": report.throughput_tok_s,
         "makespan_s": report.makespan_s,
         "route_bias_final": sb.controller.route_bias,

@@ -163,9 +163,9 @@ def serve_and_check(cfg, *, max_new=MAX_NEW, short_prompt=SHORT_PROMPT,
           f"{st.swap_experts / max(st.steps, 1):.1f} experts in "
           f"{st.swap_calls / max(st.steps, 1):.2f} writes, host syncs "
           f"{st.host_syncs / max(st.steps, 1):.1f} ({st.steps} steps); "
-          f"swap host time {sb.swap_timer.elapsed:.1f}s for "
-          f"{st.swap_experts * sb._expert_nbytes / 1e9:.1f} GB; host->device "
-          f"rate estimate C_s {sb.controller.bandwidth_est / 1e9:.2f} GB/s; "
+          f"{st.swap_experts * sb._expert_nbytes / 1e9:.1f} GB swapped in; "
+          f"host->device rate estimate C_s "
+          f"{sb.controller.bandwidth_est / 1e9:.2f} GB/s; "
           f"peak memory so far {_peak_gb(dev):.2f} GB")
 
     # -- 3. one request through the Mosaic slot_ffn kernel -----------------

@@ -50,7 +50,7 @@ from repro.models.transformer import (LayerSpec, Model, init_layer,
                                       init_layer_cache, layer_decode,
                                       layer_forward, layer_prefill,
                                       layer_prefill_chunk, split_ffn_params)
-from repro.runtime.instrument import Dispatcher, Stopwatch
+from repro.runtime.instrument import Dispatcher, named_jit, span
 from repro.runtime.sampler import sample
 from repro.simulator.events import RoutingTrace, StepTrace
 
@@ -401,6 +401,7 @@ class PrefillCursor:
     logits: Optional[jnp.ndarray] = None   # set when done
     skipped: int = 0             # scheduler aging: consecutive iterations
                                  # another cursor was advanced instead
+    request_id: int = -1         # the request it ingests, for its spans
 
     @property
     def done(self) -> bool:
@@ -497,8 +498,6 @@ class SlotBufferEngine:
         # the pool holds experts in the model's dtype: a bf16 pool under an
         # f32 model would round every expert it swaps in
         self.buffer = make_buffer(cfg, self.n_slots, model.dtype)
-        self.swap_count = 0
-        self.would_stall = 0
         self.fused = fused
         self.use_kernel = use_kernel
         # decode superkernel: batched decode restructured into per-MoE-layer
@@ -562,7 +561,9 @@ class SlotBufferEngine:
         # near-boundary experts (the §3.2.1 cumulative-probability tail)
         # prefetch too instead of forcing a replay when routing lands on them
         self.pregate_margin = pregate_margin
-        self.swap_timer = Stopwatch()
+        # swap windows dispatched so far (one in BANDWIDTH_SAMPLE_EVERY is
+        # timed to completion; see `_dispatch_swap`)
+        self._swap_windows = 0
         # all MoE routers stacked (L, d, E) so the pre-gate fn can take any
         # lookahead window as ONE device slice
         self._router_stack = jnp.stack(
@@ -614,6 +615,14 @@ class SlotBufferEngine:
         # share one trace
         return LayerSpec(spec.kind, spec.window, spec.is_moe, 0)
 
+    @staticmethod
+    def _spec_tag(spec: LayerSpec) -> str:
+        """Name suffix of a layer shape in a jitted function's name: empty
+        for global attention, else its kind and window."""
+        if spec.kind == "attn" and spec.window == 0:
+            return ""
+        return f"_{spec.kind}" + (f"_w{spec.window}" if spec.window else "")
+
     def _embed_fn(self):
         if "embed" not in self._fns:
             model = self.model
@@ -623,14 +632,15 @@ class SlotBufferEngine:
                 B, T = tokens.shape
                 positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
                 return x, positions
-            self._fns["embed"] = jax.jit(fn)
+            self._fns["embed"] = named_jit("embed", fn)
         return self._fns["embed"]
 
     def _dense_fn(self, spec: LayerSpec):
         key = ("dense", self._spec_key(spec))
         if key not in self._fns:
             cfg, cspec = self.cfg, self._spec_key(spec)
-            self._fns[key] = jax.jit(
+            self._fns[key] = named_jit(
+                "dense" + self._spec_tag(spec),
                 lambda p, x, pos: layer_forward(p, cfg, cspec, x, pos))
         return self._fns[key]
 
@@ -652,12 +662,18 @@ class SlotBufferEngine:
                                        cfg.moe.router_norm_topk)
                     masks = masks.at[1, rn.expert_ids.reshape(-1)].set(True)
                 return x, flat, r, masks
-            self._fns[key] = jax.jit(fn)
+            self._fns[key] = named_jit(
+                "pre" + self._spec_tag(spec) + ("_pregate" if has_next else ""),
+                fn)
         return self._fns[key]
 
-    def _ffn_fn(self, spec: LayerSpec):
+    def _ffn_fn(self, spec: LayerSpec, role: str):
+        """The slot-pool MoE FFN. `role` names the path it serves
+        (`moe_ffn` whole prompts, `moe_ffn_chunk` prefill chunks,
+        `moe_ffn_decode` decode steps): one registry entry, compiled and
+        traced under that name, per path."""
         use_kernel = self.use_kernel
-        key = ("ffn", self._spec_key(spec), use_kernel)
+        key = ("ffn", self._spec_key(spec), use_kernel, role)
         if key not in self._fns:
             cfg = self.cfg
             from repro.models.transformer import _zc
@@ -673,7 +689,9 @@ class SlotBufferEngine:
                     ff = rms_norm(ff, p["post_ffn_norm"], cfg.norm_eps,
                                   zero_centered=_zc(cfg))
                 return x + ff
-            self._fns[key] = jax.jit(fn)
+            self._fns[key] = named_jit(
+                role + self._spec_tag(spec) + ("_kernel" if use_kernel else ""),
+                fn)
         return self._fns[key]
 
     def _next_router(self, li: int):
@@ -691,21 +709,22 @@ class SlotBufferEngine:
                 pos = jnp.broadcast_to(jnp.asarray(cache_len).reshape(-1, 1),
                                        (tok.shape[0], 1))
                 return model.embed(params, tok[:, None], positions=pos)
-            self._fns["embed_decode"] = jax.jit(fn)
+            self._fns["embed_decode"] = named_jit("embed_decode", fn)
         return self._fns["embed_decode"]
 
     def _logits_fn(self):
         if "logits" not in self._fns:
             model = self.model
-            self._fns["logits"] = jax.jit(
-                lambda params, x: model.logits(params, x[:, -1]))
+            self._fns["logits"] = named_jit(
+                "logits", lambda params, x: model.logits(params, x[:, -1]))
         return self._fns["logits"]
 
     def _dense_prefill_fn(self, spec: LayerSpec):
         key = ("dense_prefill", self._spec_key(spec))
         if key not in self._fns:
             cfg, cspec, max_seq = self.cfg, self._spec_key(spec), self.max_seq
-            self._fns[key] = jax.jit(
+            self._fns[key] = named_jit(
+                "dense_prefill" + self._spec_tag(spec),
                 lambda p, x, pos: layer_prefill(p, cfg, cspec, x, pos,
                                                 max_seq))
         return self._fns[key]
@@ -714,7 +733,8 @@ class SlotBufferEngine:
         key = ("dense_decode", self._spec_key(spec))
         if key not in self._fns:
             cfg, cspec = self.cfg, self._spec_key(spec)
-            self._fns[key] = jax.jit(
+            self._fns[key] = named_jit(
+                "dense_decode" + self._spec_tag(spec),
                 lambda p, x, c, n: layer_decode(p, cfg, cspec, x, c, n))
         return self._fns[key]
 
@@ -731,7 +751,8 @@ class SlotBufferEngine:
                                          max_seq)
                 flat, r, needed = _route_ffn_entry(p, cfg, x)
                 return x, flat, r, needed, cache
-            self._fns[key] = jax.jit(fn)
+            self._fns[key] = named_jit("pre_prefill" + self._spec_tag(spec),
+                                       fn)
         return self._fns[key]
 
     def _embed_chunk_fn(self):
@@ -746,7 +767,7 @@ class SlotBufferEngine:
                     offset + jnp.arange(C)[None, :], (B, C))
                 x = model.embed(params, tokens, positions=positions)
                 return x, positions, jnp.arange(C) < n_valid
-            self._fns["embed_chunk"] = jax.jit(fn)
+            self._fns["embed_chunk"] = named_jit("embed_chunk", fn)
         return self._fns["embed_chunk"]
 
     @staticmethod
@@ -766,7 +787,8 @@ class SlotBufferEngine:
         key = ("dense_prefill_chunk", self._spec_key(spec), bucket)
         if key not in self._fns:
             cfg, cspec = self.cfg, self._spec_key(spec)
-            self._fns[key] = jax.jit(
+            self._fns[key] = named_jit(
+                f"dense_prefill_chunk{self._spec_tag(spec)}_kv{bucket}",
                 lambda p, x, pos, c, clen, nv: layer_prefill_chunk(
                     p, cfg, cspec, x, pos, c, clen, nv, kv_bucket=bucket))
         return self._fns[key]
@@ -789,7 +811,8 @@ class SlotBufferEngine:
                 active = jnp.arange(x.shape[1]) < n_valid
                 flat, r, needed = _route_ffn_entry(p, cfg, x, active)
                 return x, flat, r, needed, new_cache
-            self._fns[key] = jax.jit(fn)
+            self._fns[key] = named_jit(
+                f"pre_prefill_chunk{self._spec_tag(spec)}_kv{bucket}", fn)
         return self._fns[key]
 
     def _logits_at_fn(self):
@@ -797,7 +820,8 @@ class SlotBufferEngine:
         valid row lands mid-buffer, not at -1)."""
         if "logits_at" not in self._fns:
             model = self.model
-            self._fns["logits_at"] = jax.jit(
+            self._fns["logits_at"] = named_jit(
+                "logits_at",
                 lambda params, x, idx: model.logits(params, x[:, idx]))
         return self._fns["logits_at"]
 
@@ -824,7 +848,9 @@ class SlotBufferEngine:
                                             cache_len)
                 flat, r, needed = _route_ffn_entry(p, cfg, x, active, rbias)
                 return x, flat, r, needed, new_cache
-            self._fns[key] = jax.jit(fn)
+            self._fns[key] = named_jit(
+                "pre_decode" + self._spec_tag(spec)
+                + ("_batched" if batched else ""), fn)
         return self._fns[key]
 
     def _pregate_fn(self, n_next: int, batched: bool = False):
@@ -860,7 +886,8 @@ class SlotBufferEngine:
                     m = m.at[ids.reshape(-1)].set(True, mode="drop")
                     rows.append(m[None])
                 return jnp.concatenate(rows, axis=0)
-            self._fns[key] = jax.jit(fn)
+            self._fns[key] = named_jit(
+                f"pregate_s{n_next}" + ("_batched" if batched else ""), fn)
         return self._fns[key]
 
     # -- cache-aware routing (§3.4) ------------------------------------------
@@ -1117,7 +1144,6 @@ class SlotBufferEngine:
                             key, self.prefetcher.is_ready(key, self._clock))
                     continue
                 if not speculative:
-                    self.would_stall += 1
                     self.stats.demand_misses += 1
                     self.controller.record_stall()
                     if not self._fault_transfer_ok(key, demand=True):
@@ -1167,7 +1193,6 @@ class SlotBufferEngine:
         if missing:
             self._dispatch_swap(slots, [(li, e) for e in missing])
             self.stats.swap_experts += len(missing)
-        self.swap_count += len(missing)
         return len(missing)
 
     def _settle_hit(self, key: Tuple[int, int], ready: bool, *,
@@ -1217,11 +1242,18 @@ class SlotBufferEngine:
         BANDWIDTH_SAMPLE_EVERY feeds the controller's C_s instead: it
         starts once earlier writes to the pool have landed (untimed) and is
         timed until its last write has landed. Compute still reading the
-        pool delays that write too, so a sample errs slow, never fast."""
-        sample = self.swap_timer.calls % BANDWIDTH_SAMPLE_EVERY == 0
-        if sample:
-            jax.block_until_ready(self.buffer)
-        with self.swap_timer.section():
+        pool delays that write too, so a sample errs slow, never fast.
+        The window is a `swap.write` span; a sampled one holds the
+        `swap.drain` and `swap.sample_wait` spans in which the host waits
+        and dispatches nothing."""
+        sample = self._swap_windows % BANDWIDTH_SAMPLE_EVERY == 0
+        self._swap_windows += 1
+        nbytes = len(slots) * self._expert_nbytes
+        with span("swap.write", experts=len(keys), bytes=int(nbytes),
+                  sampled=int(sample)):
+            if sample:
+                with span("swap.drain"):
+                    jax.block_until_ready(self.buffer)
             t0 = time.perf_counter()
             for i in range(0, len(keys), SWAP_CHUNK):
                 self.buffer = swap_in_many(
@@ -1229,10 +1261,10 @@ class SlotBufferEngine:
                     *zip(*self.store.experts(keys[i:i + SWAP_CHUNK])))
                 self.stats.swap_calls += 1
             if sample:
-                jax.block_until_ready(self.buffer)
-                self.controller.update_bandwidth(
-                    len(slots) * self._expert_nbytes,
-                    time.perf_counter() - t0)
+                with span("swap.sample_wait"):
+                    jax.block_until_ready(self.buffer)
+                self.controller.update_bandwidth(nbytes,
+                                                 time.perf_counter() - t0)
 
     def prefetch_layer(self, li: int, experts) -> int:
         """Speculatively swap in predicted experts for ONE future layer
@@ -1297,7 +1329,6 @@ class SlotBufferEngine:
             self._dispatch_swap(slots, issued_keys)
             self.stats.swap_experts += len(issued_keys)
             self.stats.prefetched += len(issued_keys)
-        self.swap_count += len(issued_keys)
         return len(issued_keys)
 
     # -- forward ------------------------------------------------------------
@@ -1322,7 +1353,8 @@ class SlotBufferEngine:
                 self._pre_fn(spec, want_pred), p, x, positions,
                 nxt if want_pred else None)
             # ONE small host pull: (2, E) needed/predicted bool masks
-            masks_h = np.asarray(masks)
+            with span("engine.mask_pull", layer=li, rows=2):
+                masks_h = np.asarray(masks)
             self.stats.host_syncs += 1
             self._advance_clock()
             needed = np.nonzero(masks_h[0])[0]
@@ -1340,8 +1372,8 @@ class SlotBufferEngine:
                 # issue next-layer swap-ins BEFORE this layer's FFN dispatch
                 self.prefetch_layer(li + 1, predicted)
             slot_map = jnp.asarray(self.table.layer_slot_map(li))
-            x = self._dispatch(self._ffn_fn(spec), p, self.buffer,
-                               slot_map, x, flat, r)
+            x = self._dispatch(self._ffn_fn(spec, "moe_ffn"), p,
+                               self.buffer, slot_map, x, flat, r)
             li += 1
         # next step's sweep restarts at layer 0: shield the first layer's
         # residents from the step-boundary prefetches (paper §3.3.1)
@@ -1377,8 +1409,8 @@ class SlotBufferEngine:
             want_pred = self.prefetch_enabled and nxt is not None
             x, flat, r, _ = self._pre_fn(spec, want_pred)(
                 p, x, positions, nxt if want_pred else None)
-            x = self._ffn_fn(spec)(p, self._staged_experts(li, x),
-                                   self._ident_map, x, flat, r)
+            x = self._ffn_fn(spec, "moe_ffn")(
+                p, self._staged_experts(li, x), self._ident_map, x, flat, r)
             li += 1
         return x
 
@@ -1438,11 +1470,13 @@ class SlotBufferEngine:
         Returns the layer's slot map for the FFN dispatch."""
         s = self._horizon(li)
         masks = self._sync_masks_dev(li, s, flat, needed_dev, active_dev)
-        masks_h = np.asarray(masks)          # ONE (S+1, E) blocking pull
+        with span("engine.mask_pull", layer=li, rows=s + 1):
+            masks_h = np.asarray(masks)      # ONE (S+1, E) blocking pull
         self.stats.host_syncs += 1
         self._advance_clock()
-        needed, predicted = self._decode_sync_rows(li, s, masks_h)
-        self._sync_moe_layer(li, needed, predicted)
+        with span("engine.residency", layer=li):
+            needed, predicted = self._decode_sync_rows(li, s, masks_h)
+            self._sync_moe_layer(li, needed, predicted)
         return jnp.asarray(self.table.layer_slot_map(li))
 
     def prefill(self, tokens) -> Tuple[jnp.ndarray, DecodeState]:
@@ -1473,8 +1507,8 @@ class SlotBufferEngine:
                 self._pre_prefill_fn(spec), p, x, positions)
             caches.append(c)
             slot_map = self._prefill_moe_sync(li, flat, needed_dev)
-            x = self._dispatch(self._ffn_fn(spec), p, self.buffer,
-                               slot_map, x, flat, r)
+            x = self._dispatch(self._ffn_fn(spec, "moe_ffn"), p,
+                               self.buffer, slot_map, x, flat, r)
             li += 1
         self.cache.protect_early_layers(
             max(1, min(self._s_eff(), len(self.moe_layer_ids))))
@@ -1535,33 +1569,35 @@ class SlotBufferEngine:
         assert not cursor.done, "cursor already consumed its prompt"
         o, C = cursor.offset, cursor.chunk
         t = min(C, len(cursor.tokens) - o)
-        bucket = self._kv_bucket(o + C, self.max_seq)
-        buf = np.zeros((1, C), np.int32)
-        buf[0, :t] = cursor.tokens[o:o + t]
-        self.stats.steps += 1
-        x, positions, valid = self._dispatch(
-            self._embed_chunk_fn(), self.params, jnp.asarray(buf), o, t)
-        li = 0
-        for i, spec in enumerate(self.specs):
-            p = self._p[i]
-            if not spec.is_moe:
-                x, cursor.caches[i] = self._dispatch(
-                    self._dense_prefill_chunk_fn(spec, bucket), p, x,
-                    positions, cursor.caches[i], o, t)
-                continue
-            x, flat, r, needed_dev, cursor.caches[i] = self._dispatch(
-                self._pre_prefill_chunk_fn(spec, bucket), p, x, positions,
-                cursor.caches[i], o, t)
-            slot_map = self._prefill_moe_sync(li, flat, needed_dev, valid)
-            x = self._dispatch(self._ffn_fn(spec), p, self.buffer,
-                               slot_map, x, flat, r)
-            li += 1
-        self.cache.protect_early_layers(
-            max(1, min(self._s_eff(), len(self.moe_layer_ids))))
-        cursor.offset = o + t
-        if cursor.done:
-            cursor.logits = self._dispatch(self._logits_at_fn(),
-                                           self.params, x, t - 1)
+        with span("engine.prefill_chunk", offset=o, tokens=t,
+                  request_id=cursor.request_id):
+            bucket = self._kv_bucket(o + C, self.max_seq)
+            buf = np.zeros((1, C), np.int32)
+            buf[0, :t] = cursor.tokens[o:o + t]
+            self.stats.steps += 1
+            x, positions, valid = self._dispatch(
+                self._embed_chunk_fn(), self.params, jnp.asarray(buf), o, t)
+            li = 0
+            for i, spec in enumerate(self.specs):
+                p = self._p[i]
+                if not spec.is_moe:
+                    x, cursor.caches[i] = self._dispatch(
+                        self._dense_prefill_chunk_fn(spec, bucket), p, x,
+                        positions, cursor.caches[i], o, t)
+                    continue
+                x, flat, r, needed_dev, cursor.caches[i] = self._dispatch(
+                    self._pre_prefill_chunk_fn(spec, bucket), p, x, positions,
+                    cursor.caches[i], o, t)
+                slot_map = self._prefill_moe_sync(li, flat, needed_dev, valid)
+                x = self._dispatch(self._ffn_fn(spec, "moe_ffn_chunk"), p,
+                                   self.buffer, slot_map, x, flat, r)
+                li += 1
+            self.cache.protect_early_layers(
+                max(1, min(self._s_eff(), len(self.moe_layer_ids))))
+            cursor.offset = o + t
+            if cursor.done:
+                cursor.logits = self._dispatch(self._logits_at_fn(),
+                                               self.params, x, t - 1)
         return cursor.done
 
     def _run_prefill_cursor(self, tokens, chunk_size: int) -> PrefillCursor:
@@ -1687,8 +1723,18 @@ class SlotBufferEngine:
         neighbours and residency is guaranteed (or replayed) before each
         FFN dispatch."""
         assert self.fused, "incremental decode requires the fused runtime"
-        if self.use_superkernel:
-            return self._decode_step_superkernel(tok, state)
+        rows = (int(np.count_nonzero(state.active)) if state.batched
+                else int(np.shape(tok)[0]))
+        with span("engine.decode_step", step=self.stats.steps, rows=rows,
+                  S=self._s_eff()):
+            if self.use_superkernel:
+                return self._decode_step_superkernel(tok, state)
+            return self._decode_step_layers(tok, state)
+
+    def _decode_step_layers(self, tok, state: DecodeState
+                            ) -> Tuple[jnp.ndarray, DecodeState]:
+        """`decode_step` through one `pre` and one FFN dispatch per MoE
+        layer (the path without the superkernel)."""
         # cache-aware routing is gated on the CEILING, not the live strength:
         # an adaptive engine at strength 0 keeps using the biased traces
         # (with a zero bias) so ramping costs no recompiles mid-serve.
@@ -1730,23 +1776,24 @@ class SlotBufferEngine:
         def replay_from(fail_idx: int) -> Tuple[int, int, jnp.ndarray]:
             """Roll back to the first mis-speculated layer (§3.4 stall)."""
             plj, pabs = pending[fail_idx][0], pending[fail_idx][1]
-            self.stats.replays += 1
-            for k, (_, old_c) in ckpt.items():
-                if k >= pabs:
-                    caches[k] = old_c
-            x_r = ckpt[pabs][0]
-            # mid-window evictions parked for rolled-back layers: their
-            # consuming dispatch is being discarded, so the transfer WAS
-            # wasted — settle as overfetch now, or a re-prefetch after
-            # replay would double-settle the stale entry as a hit
-            for k in [k for k in self._evicted_spec if k[0] >= plj]:
-                del self._evicted_spec[k]
-                self.prefetcher.note_unused(k)
-                self.controller.record_overfetch()
-            predicted.clear()
-            pending.clear()
-            ckpt.clear()
-            self._window_layers.clear()
+            with span("engine.replay", layer=plj):
+                self.stats.replays += 1
+                for k, (_, old_c) in ckpt.items():
+                    if k >= pabs:
+                        caches[k] = old_c
+                x_r = ckpt[pabs][0]
+                # mid-window evictions parked for rolled-back layers: their
+                # consuming dispatch is being discarded, so the transfer WAS
+                # wasted — settle as overfetch now, or a re-prefetch after
+                # replay would double-settle the stale entry as a hit
+                for k in [k for k in self._evicted_spec if k[0] >= plj]:
+                    del self._evicted_spec[k]
+                    self.prefetcher.note_unused(k)
+                    self.controller.record_overfetch()
+                predicted.clear()
+                pending.clear()
+                ckpt.clear()
+                self._window_layers.clear()
             return pabs, plj, x_r
 
         def verify(masks_h: np.ndarray) -> int:
@@ -1772,7 +1819,8 @@ class SlotBufferEngine:
             if extra is not None:
                 mats.append(extra)
             stacked = mats[0] if len(mats) == 1 else jnp.concatenate(mats, 0)
-            masks_h = np.asarray(stacked)
+            with span("engine.mask_pull", layer=li, rows=stacked.shape[0]):
+                masks_h = np.asarray(stacked)
             self.stats.host_syncs += 1
             npend = len(pending)
             fail = verify(masks_h[:npend])
@@ -1821,15 +1869,18 @@ class SlotBufferEngine:
                 # ---- speculative layer: no host pull ----------------------
                 ckpt[i] = (x_in, old_c)
                 caches[i] = c2
-                self.ensure_resident(li, sorted(predicted[li]),
-                                     speculative=True)
-                snap = self.table.layer_slot_map(li)
-                ready_snap = {k: self.prefetcher.is_ready(k, self._clock)
-                              for k in self._prefetch_pending if k[0] == li}
+                with span("engine.residency", layer=li):
+                    self.ensure_resident(li, sorted(predicted[li]),
+                                         speculative=True)
+                    snap = self.table.layer_slot_map(li)
+                    ready_snap = {k: self.prefetcher.is_ready(k, self._clock)
+                                  for k in self._prefetch_pending
+                                  if k[0] == li}
                 pending.append((li, i, needed_dev, snap, ready_snap))
                 self._window_layers.add(li)
-                x = self._dispatch(self._ffn_fn(spec), p, self.buffer,
-                                   jnp.asarray(snap), x2, flat, r)
+                x = self._dispatch(self._ffn_fn(spec, "moe_ffn_decode"), p,
+                                   self.buffer, jnp.asarray(snap), x2, flat,
+                                   r)
                 self.stats.spec_layers += 1
                 i += 1
                 li += 1
@@ -1843,14 +1894,15 @@ class SlotBufferEngine:
             if fail >= 0:
                 i, li, x = replay_from(fail)
                 continue
-            needed, pred = self._decode_sync_rows(li, s, sync)
-            predicted.clear()
-            predicted.update(pred)
-            self._sync_moe_layer(li, needed, predicted)
+            with span("engine.residency", layer=li):
+                needed, pred = self._decode_sync_rows(li, s, sync)
+                predicted.clear()
+                predicted.update(pred)
+                self._sync_moe_layer(li, needed, predicted)
             caches[i] = c2
             slot_map = jnp.asarray(self.table.layer_slot_map(li))
-            x = self._dispatch(self._ffn_fn(spec), p, self.buffer,
-                               slot_map, x2, flat, r)
+            x = self._dispatch(self._ffn_fn(spec, "moe_ffn_decode"), p,
+                               self.buffer, slot_map, x2, flat, r)
             i += 1
             li += 1
 
@@ -1889,6 +1941,12 @@ class SlotBufferEngine:
             assert segs, "superkernel decode requires at least one MoE layer"
             self._sk_segs = (segs, cur)
         return self._sk_segs
+
+    def _specs_tag(self, cspecs) -> str:
+        """Name suffix of a run of layers: each one's shape, and its length
+        when it holds more than one."""
+        return ("".join(self._spec_tag(sp) for sp in cspecs)
+                + (f"_{len(cspecs)}l" if len(cspecs) > 1 else ""))
 
     def _sk_seg_fn(self, specs_seg, s: int, batched: bool, first: bool,
                    with_logits: bool = False):
@@ -1955,7 +2013,10 @@ class SlotBufferEngine:
                 logits = (model.logits(params, x[:, -1]) if with_logits
                           else None)
                 return x, jnp.concatenate(rows, axis=0), new_caches, logits
-            self._fns[key] = jax.jit(fn)
+            self._fns[key] = named_jit(
+                "decode_segment" + self._specs_tag(cspecs) + f"_s{s}"
+                + ("_first" if first else "") + ("_logits" if with_logits else "")
+                + ("_batched" if batched else ""), fn)
         return self._fns[key]
 
     def _sk_tail_fn(self, specs_tail):
@@ -1972,7 +2033,8 @@ class SlotBufferEngine:
                                         clen, use_kernel=True)
                     new_caches.append(c)
                 return model.logits(params, x[:, -1]), new_caches
-            self._fns[key] = jax.jit(fn)
+            self._fns[key] = named_jit("decode_tail" + self._specs_tag(cspecs),
+                                       fn)
         return self._fns[key]
 
     def _decode_step_superkernel(self, tok, state: DecodeState
@@ -2025,25 +2087,27 @@ class SlotBufferEngine:
 
         def replay_from(fail_idx: int, needed_h) -> Tuple[int, jnp.ndarray]:
             plj, psi = pending[fail_idx][0], pending[fail_idx][1]
-            self.stats.replays += 1
-            for kk, (_, cs_old) in ckpt.items():
-                if kk >= psi:
-                    for jj, aj in enumerate(segs[kk]):
-                        caches[aj] = cs_old[jj]
-            x_r = ckpt[psi][0]
-            for kk in [kk for kk in self._evicted_spec if kk[0] >= plj]:
-                del self._evicted_spec[kk]
-                self.prefetcher.note_unused(kk)
-                self.controller.record_overfetch()
-            # the pulled mask IS the failed segment's demand: replay it with
-            # residency ensured up front (union with any earlier hint so the
-            # hint set grows monotonically -> the replay loop terminates)
-            demand_hint[plj] = demand_hint.get(plj, set()) | {
-                int(e) for e in needed_h}
-            predicted.clear()
-            pending.clear()
-            ckpt.clear()
-            self._window_layers.clear()
+            with span("engine.replay", layer=plj):
+                self.stats.replays += 1
+                for kk, (_, cs_old) in ckpt.items():
+                    if kk >= psi:
+                        for jj, aj in enumerate(segs[kk]):
+                            caches[aj] = cs_old[jj]
+                x_r = ckpt[psi][0]
+                for kk in [kk for kk in self._evicted_spec if kk[0] >= plj]:
+                    del self._evicted_spec[kk]
+                    self.prefetcher.note_unused(kk)
+                    self.controller.record_overfetch()
+                # the pulled mask IS the failed segment's demand: replay it
+                # with residency ensured up front (union with any earlier
+                # hint so the hint set grows monotonically -> the replay
+                # loop terminates)
+                demand_hint[plj] = demand_hint.get(plj, set()) | {
+                    int(e) for e in needed_h}
+                predicted.clear()
+                pending.clear()
+                ckpt.clear()
+                self._window_layers.clear()
             return psi, x_r
 
         def pull_and_verify():
@@ -2053,7 +2117,8 @@ class SlotBufferEngine:
             block (needed row + pre-gate rows) for `_decode_sync_rows`."""
             stacked = (pending[0][2] if len(pending) == 1
                        else jnp.concatenate([pp[2] for pp in pending], 0))
-            masks_h = np.asarray(stacked)
+            with span("engine.mask_pull", layer=si, rows=stacked.shape[0]):
+                masks_h = np.asarray(stacked)
             self.stats.host_syncs += 1
             row = 0
             for idx, (plj, _, mdev, snap, rsnap, hint) in enumerate(pending):
@@ -2089,13 +2154,14 @@ class SlotBufferEngine:
             seg = segs[si]
             first = si == 0
             hint = demand_hint.pop(li, set())
-            if hint:
-                self.cache.retier([(li, int(e)) for e in sorted(hint)],
-                                  recent_layers=(), current_layer=li)
-                self.ensure_resident(li, sorted(hint))
-            elif li in predicted:
-                self.ensure_resident(li, sorted(predicted[li]),
-                                     speculative=True)
+            with span("engine.residency", layer=li):
+                if hint:
+                    self.cache.retier([(li, int(e)) for e in sorted(hint)],
+                                      recent_layers=(), current_layer=li)
+                    self.ensure_resident(li, sorted(hint))
+                elif li in predicted:
+                    self.ensure_resident(li, sorted(predicted[li]),
+                                         speculative=True)
             sync = li not in predicted or bool(hint)
             s = self._horizon(li) if sync else 0
             if ca:
@@ -2134,19 +2200,21 @@ class SlotBufferEngine:
             if fail >= 0:
                 si, x = replay_from(fail, needed_h)
                 continue
-            needed, pred = self._decode_sync_rows(li, s, sync_rows)
-            predicted.clear()
-            predicted.update(pred)
-            self.cache.retier(
-                [(li, int(e)) for e in needed]
-                + [(lj, int(e)) for lj, es in pred.items() for e in es],
-                recent_layers=(), current_layer=li)
-            # verified: pure LRU touches (all needed are resident), unless a
-            # hinted segment overflowed capacity — then this books the miss
-            self.ensure_resident(li, needed)
-            if pred:
-                self.prefetch_window(
-                    [(lj, sorted(es)) for lj, es in sorted(pred.items())])
+            with span("engine.residency", layer=li):
+                needed, pred = self._decode_sync_rows(li, s, sync_rows)
+                predicted.clear()
+                predicted.update(pred)
+                self.cache.retier(
+                    [(li, int(e)) for e in needed]
+                    + [(lj, int(e)) for lj, es in pred.items() for e in es],
+                    recent_layers=(), current_layer=li)
+                # verified: pure LRU touches (all needed are resident),
+                # unless a hinted segment overflowed capacity — then this
+                # books the miss
+                self.ensure_resident(li, needed)
+                if pred:
+                    self.prefetch_window(
+                        [(lj, sorted(es)) for lj, es in sorted(pred.items())])
             pending.clear()
             ckpt.clear()
             self._window_layers.clear()
@@ -2190,8 +2258,8 @@ class SlotBufferEngine:
                 continue
             x, flat, r, _, c = self._pre_prefill_fn(spec)(p, x, positions)
             caches.append(c)
-            x = self._ffn_fn(spec)(p, self._staged_experts(li, x),
-                                   self._ident_map, x, flat, r)
+            x = self._ffn_fn(spec, "moe_ffn")(
+                p, self._staged_experts(li, x), self._ident_map, x, flat, r)
             li += 1
         logits = self._logits_fn()(self.params, x)
         return logits, DecodeState(caches, jnp.asarray(T, jnp.int32),
@@ -2224,8 +2292,8 @@ class SlotBufferEngine:
             x2, flat, r, _, c2 = self._pre_decode_fn(spec)(p, x, caches[i],
                                                            clen)
             caches[i] = c2
-            x = self._ffn_fn(spec)(p, self._staged_experts(li, x2),
-                                   self._ident_map, x2, flat, r)
+            x = self._ffn_fn(spec, "moe_ffn_decode")(
+                p, self._staged_experts(li, x2), self._ident_map, x2, flat, r)
             li += 1
         logits = self._logits_fn()(self.params, x)
         return logits, DecodeState(caches, clen + 1, pos=state.pos + 1)
@@ -2259,7 +2327,6 @@ class SlotBufferEngine:
             key = (li, int(e))
             if self.cache.touch(key):
                 continue
-            self.would_stall += 1
             self.stats.demand_misses += 1
             victim = self.cache.insert(key)
             if victim is not None:
@@ -2270,7 +2337,6 @@ class SlotBufferEngine:
             self.stats.swap_calls += 1
             self.stats.swap_experts += 1
             swaps += 1
-        self.swap_count += swaps
         return swaps
 
     def _forward_legacy(self, tokens: jnp.ndarray) -> jnp.ndarray:

@@ -1,4 +1,12 @@
-"""Device-dispatch accounting for the slot-path benchmark.
+"""Device-dispatch accounting, compile probes and profiler spans for the
+slot-path runtime.
+
+`span(name, **args)` opens a `jax.profiler.TraceAnnotation`: with the
+profiler on, it lands on the host plane of the trace, on the same clock as
+the device's operations; with it off, it costs about a microsecond. Names
+are `serve.*` (serving loop), `engine.*` (SlotBufferEngine) and `swap.*`
+(slot-pool writes). `named_jit` gives a jitted function the name its role
+has, so the trace's `XLA Modules` line reads `jit_<role>`.
 
 `count_dispatches` wraps `jax.core.Primitive.bind` to count EAGER primitive
 executions — outside of jit, every bind is a separate XLA executable
@@ -13,10 +21,21 @@ fast path and never reach Python `bind`; callers count those explicitly
 from __future__ import annotations
 
 import contextlib
-import time
 from dataclasses import dataclass
 
 import jax
+
+
+def span(name: str, **args):
+    """A host span of the profiler's trace, named `name`, with `args` as its
+    metadata (a `with` block)."""
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
+def named_jit(name: str, fn, **jit_kwargs):
+    """`jax.jit(fn)`, traced and compiled as `jit_<name>`."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **jit_kwargs)
 
 
 @dataclass
@@ -104,36 +123,12 @@ class Dispatcher:
     (`self._dispatch(fn, *args)`) instead of ~20 hand-sprinkled
     `stats.jit_calls += 1` sites, so dispatch accounting cannot drift from
     the calls actually made — the superkernel's claimed dispatch reduction
-    is measured through this funnel.
+    is measured through this funnel. Each call is an `engine.dispatch` span
+    carrying the function's name.
     """
     stats: object
 
     def __call__(self, fn, *args, **kwargs):
         self.stats.jit_calls += 1
-        return fn(*args, **kwargs)
-
-
-@dataclass
-class Stopwatch:
-    """Tiny wall-clock section timer feeding the step-size controller.
-
-    The engine times swap dispatches and whole decode steps
-    (-> `update_layer_time`). Host wall time around an async dispatch is
-    its enqueue time, so the engine feeds `update_bandwidth` only from
-    swap windows it blocks on (see `SlotBufferEngine._dispatch_swap`)."""
-    elapsed: float = 0.0
-    calls: int = 0
-
-    @contextlib.contextmanager
-    def section(self):
-        t0 = time.perf_counter()
-        try:
-            yield self
-        finally:
-            self.elapsed += time.perf_counter() - t0
-            self.calls += 1
-
-    def take(self) -> float:
-        """Return accumulated seconds and reset."""
-        e, self.elapsed, self.calls = self.elapsed, 0.0, 0
-        return e
+        with span("engine.dispatch", fn=fn.__name__):
+            return fn(*args, **kwargs)

@@ -48,6 +48,7 @@ from repro.core.metrics import (RunReport, ServingReport, StepMetrics,
 from repro.distributed.fault_tolerance import StragglerPolicy
 from repro.runtime.batching import ContinuousBatcher, WorkingSetAdmission
 from repro.runtime.engine import SlotBufferEngine
+from repro.runtime.instrument import named_jit, span
 from repro.runtime.request import Request
 from repro.runtime.sampler import sample, sample_rows
 
@@ -196,7 +197,7 @@ class ServingEngine:
                              ids.reshape(ids.shape[0], -1)].set(
                                  True, mode="drop")
                 return hot.sum(axis=1)                      # (L,) distinct
-            eng._fns["predict_ws"] = jax.jit(fn)
+            eng._fns["predict_ws"] = named_jit("predict_ws", fn)
         return eng._fns["predict_ws"]
 
     # -- lifecycle helpers ---------------------------------------------------
@@ -217,16 +218,17 @@ class ServingEngine:
                           it: int) -> None:
         """Sample the prompt's first output token and stamp TTFT."""
         eng = self.engine
-        key = jax.random.fold_in(self.base_key, req.request_id)
-        tok = sample(logits, key, req.temperature)
-        self._row_key[slot] = key
-        self._row_temp[slot] = max(float(req.temperature), 0.0)
-        self._row_step[slot] = 0
-        req.output.append(int(np.asarray(tok)[0]))
-        req.first_token_s = time.perf_counter() - self._t0
-        if self.cfg.trace_logits:
-            self.logits_trace.setdefault(req.request_id, []).append(
-                np.asarray(logits)[0])
+        with span("serve.sample", request_id=req.request_id):
+            key = jax.random.fold_in(self.base_key, req.request_id)
+            tok = sample(logits, key, req.temperature)
+            self._row_key[slot] = key
+            self._row_temp[slot] = max(float(req.temperature), 0.0)
+            self._row_step[slot] = 0
+            req.output.append(int(np.asarray(tok)[0]))
+            req.first_token_s = time.perf_counter() - self._t0
+            if self.cfg.trace_logits:
+                self.logits_trace.setdefault(req.request_id, []).append(
+                    np.asarray(logits)[0])
         sm = StepMetrics(step=it, compute_s=req.first_token_s - t_start,
                          step_size=eng.controller.s)
         report.run.add(sm)
@@ -262,12 +264,14 @@ class ServingEngine:
                 step_size=eng.controller.s))
             return
         self._prefills.pop(pick)
-        logits = eng.finish_prefill_into(state, req.slot, cursor)
+        with span("serve.admit", request_id=req.request_id):
+            logits = eng.finish_prefill_into(state, req.slot, cursor)
         req.prefill_done_s = time.perf_counter() - self._t0
         self._emit_first_token(req, req.slot, logits, t0, report, it)
         if req.done:                 # 1-token request: done at prefill
-            finish(req)
-            self.batcher.release(req)
+            with span("serve.retire", request_id=req.request_id):
+                finish(req)
+                self.batcher.release(req)
 
     # -- the serving loop ----------------------------------------------------
     def serve(self, requests: List[Request]) -> ServingReport:
@@ -330,27 +334,31 @@ class ServingEngine:
             if not self.batcher.has_work:
                 # nothing can happen before the next arrival: sleep through
                 # the gap instead of polling it away
-                time.sleep(max(pending[0].arrival_s - tnow,
-                               cfg.idle_sleep_s))
+                with span("serve.sleep"):
+                    time.sleep(max(pending[0].arrival_s - tnow,
+                                   cfg.idle_sleep_s))
                 continue
 
             for req in self.batcher.admit(now=tnow):
-                if self._chunked:
-                    # chunked: admission only OPENS the cursor; ingestion is
-                    # scheduled one chunk per iteration below
-                    req.admitted_s = now()
-                    cursor = eng.start_prefill(
-                        np.asarray(req.prompt, np.int32),
-                        cfg.prefill_chunk)
-                    self._prefills.append((req, cursor))
-                    continue
-                self._admit_one(req, req.slot, state, now(), report, it)
-                it += 1
-                if req.done:          # 1-token request: done at prefill
-                    # release BEFORE decode so the slot frees immediately
-                    finish(req)
-                    # release bookkeeping via batcher (slot back to pool)
-                    self.batcher.release(req)
+                with span("serve.admit", request_id=req.request_id):
+                    if self._chunked:
+                        # chunked: admission only OPENS the cursor;
+                        # ingestion is scheduled one chunk per iteration
+                        # below
+                        req.admitted_s = now()
+                        cursor = eng.start_prefill(
+                            np.asarray(req.prompt, np.int32),
+                            cfg.prefill_chunk)
+                        cursor.request_id = req.request_id
+                        self._prefills.append((req, cursor))
+                        continue
+                    self._admit_one(req, req.slot, state, now(), report, it)
+                    it += 1
+                    if req.done:      # 1-token request: done at prefill
+                        # release BEFORE decode so the slot frees immediately
+                        finish(req)
+                        # release bookkeeping via batcher (slot back to pool)
+                        self.batcher.release(req)
 
             # -- one prefill chunk per iteration, interleaved with decode --
             if self._prefills:
@@ -374,42 +382,45 @@ class ServingEngine:
             for slot in active_slots:
                 toks[slot] = self.batcher.active[slot].output[-1]
             logits, state = eng.decode_step(jnp.asarray(toks), state)
-            if any(self._row_temp[s] > 0.0 for s in active_slots):
-                # advance every active row's key BEFORE sampling — the same
-                # fold_in(key, step) schedule `SlotBufferEngine.generate`
-                # walks, so a sampled request's stream matches its
-                # single-request run
-                for slot in active_slots:
-                    self._row_step[slot] += 1
-                    self._row_key[slot] = jax.random.fold_in(
-                        self._row_key[slot], self._row_step[slot])
-                keys = jnp.stack([self._row_key[s]
-                                  for s in range(cfg.max_batch)])
-                temps = jnp.asarray(self._row_temp)
-                sampled = np.asarray(sample_rows(logits, keys, temps))
-            else:
-                # all-greedy iteration: keys are never consumed — skip the
-                # per-row fold/stack and the discarded categorical draw
-                sampled = np.asarray(
-                    jnp.argmax(logits, axis=-1).astype(jnp.int32))
-            if cfg.trace_logits:
-                logits_h = np.asarray(logits)
-                for slot in active_slots:
-                    rid = self.batcher.active[slot].request_id
-                    self.logits_trace.setdefault(rid, []).append(
-                        logits_h[slot])
+            with span("serve.sample", rows=len(active_slots)):
+                if any(self._row_temp[s] > 0.0 for s in active_slots):
+                    # advance every active row's key BEFORE sampling — the
+                    # same fold_in(key, step) schedule
+                    # `SlotBufferEngine.generate` walks, so a sampled
+                    # request's stream matches its single-request run
+                    for slot in active_slots:
+                        self._row_step[slot] += 1
+                        self._row_key[slot] = jax.random.fold_in(
+                            self._row_key[slot], self._row_step[slot])
+                    keys = jnp.stack([self._row_key[s]
+                                      for s in range(cfg.max_batch)])
+                    temps = jnp.asarray(self._row_temp)
+                    sampled = np.asarray(sample_rows(logits, keys, temps))
+                else:
+                    # all-greedy iteration: keys are never consumed — skip
+                    # the per-row fold/stack and the discarded categorical
+                    # draw
+                    sampled = np.asarray(
+                        jnp.argmax(logits, axis=-1).astype(jnp.int32))
+                if cfg.trace_logits:
+                    logits_h = np.asarray(logits)
+                    for slot in active_slots:
+                        rid = self.batcher.active[slot].request_id
+                        self.logits_trace.setdefault(rid, []).append(
+                            logits_h[slot])
             next_tokens = {slot: int(sampled[slot]) for slot in active_slots}
-            slot_of = {self.batcher.active[s].request_id: s
-                       for s in active_slots}
-            for req in self.batcher.step(next_tokens):
-                finish(req, slot_of[req.request_id])
-            sm.compute_s = now() - t_step
-            sm.n_misses = eng.stats.demand_misses - misses0
-            sm.n_hits = eng.stats.prefetch_hits - hits0
-            sm.n_prefetched = eng.stats.prefetched - pf0
-            report.run.add(sm)
-            # feed the brownout detector with real decode-step wall time
-            self.straggler.record(0, sm.compute_s)
+            with span("serve.retire"):
+                slot_of = {self.batcher.active[s].request_id: s
+                           for s in active_slots}
+                for req in self.batcher.step(next_tokens):
+                    finish(req, slot_of[req.request_id])
+                sm.compute_s = now() - t_step
+                sm.n_misses = eng.stats.demand_misses - misses0
+                sm.n_hits = eng.stats.prefetch_hits - hits0
+                sm.n_prefetched = eng.stats.prefetched - pf0
+                report.run.add(sm)
+                # feed the brownout detector with real decode-step wall time
+                self.straggler.record(0, sm.compute_s)
 
         report.makespan_s = now()
         report.mean_occupancy = self.batcher.stats.mean_occupancy

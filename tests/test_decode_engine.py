@@ -19,7 +19,6 @@ from repro.core.expert_buffer import HostExpertStore
 from repro.core.prefetcher import Prefetcher, TransferLink
 from repro.core.step_size import StepSizeConfig, StepSizeController
 from repro.runtime.engine import Engine, SlotBufferEngine
-from repro.runtime.instrument import Stopwatch
 
 
 # ---------------------------------------------------------------------------
@@ -72,17 +71,6 @@ def test_controller_horizon_clamps_to_remaining_layers():
     assert c.horizon(0) == 0
     snap = c.snapshot()
     assert snap["s"] == 4 and "bandwidth_est" in snap
-
-
-def test_stopwatch_accumulates_and_resets():
-    sw = Stopwatch()
-    with sw.section():
-        pass
-    with sw.section():
-        pass
-    assert sw.calls == 2 and sw.elapsed >= 0.0
-    sw.take()
-    assert sw.elapsed == 0.0 and sw.calls == 0
 
 
 # ---------------------------------------------------------------------------
