@@ -10,17 +10,18 @@ One process, in this order:
    straight to a host store, the device keeps the rest plus a slot pool of
    60% of each layer's experts;
 2. serves a poisson stream of 8 requests (prompts of 64 or 512 tokens, 32
-   new tokens each) at batch 4 through `ServingEngine`;
-3. serves one request again through the Mosaic `slot_ffn` kernel;
-4. checks the slot path's logits after whole-prompt prefill of 64 random
+   new tokens each) at batch 4 through `ServingEngine`, its MoE FFN the
+   Mosaic `slot_ffn` kernel over each layer's routed experts;
+3. checks the slot path's logits after whole-prompt prefill of 64 random
    tokens and two decode steps against the fully-resident reference,
    which stages each layer's 64 experts on the device only while that
    layer runs — and checks that a reference missing one expert fails the
    same tolerance.
 
 Earlier lines report device kind, compile seconds, TTFT, tokens/s, swaps
-and host syncs per step, and peak device memory. They are a bring-up
-record, not benchmark numbers. The last line is one JSON object,
+and host syncs per step, experts streamed per FFN call, and peak device
+memory. They are a bring-up record, not benchmark numbers. The last line
+is one JSON object,
 `{"ok": ..., "device": {"platform", "kind", "count"}}`; the exit code is 0
 only if every phase passed on a TPU.
 """
@@ -106,7 +107,6 @@ def serve_and_check(cfg, *, max_new=MAX_NEW, short_prompt=SHORT_PROMPT,
     from repro.launch.serve import build_requests, slots_per_layer
     from repro.models import Model
     from repro.runtime.engine import (SlotBufferEngine, init_serving_params)
-    from repro.runtime.request import Request
     from repro.runtime.serving import EngineServingConfig, ServingEngine
 
     dev = jax.devices()[0]
@@ -163,28 +163,15 @@ def serve_and_check(cfg, *, max_new=MAX_NEW, short_prompt=SHORT_PROMPT,
           f"{st.swap_experts / max(st.steps, 1):.1f} experts in "
           f"{st.swap_calls / max(st.steps, 1):.2f} writes, host syncs "
           f"{st.host_syncs / max(st.steps, 1):.1f} ({st.steps} steps); "
+          f"experts streamed per FFN call "
+          f"{st.ffn_experts / max(st.ffn_calls, 1):.1f} of {m.num_experts} "
+          f"({st.ffn_calls} calls, pool {sb.n_slots} slots); "
           f"{st.swap_experts * sb._expert_nbytes / 1e9:.1f} GB swapped in; "
           f"host->device rate estimate C_s "
           f"{sb.controller.bandwidth_est / 1e9:.2f} GB/s; "
           f"peak memory so far {_peak_gb(dev):.2f} GB")
 
-    # -- 3. one request through the Mosaic slot_ffn kernel -----------------
-    first = requests[0]
-    again = Request(prompt=first.prompt, max_new_tokens=max_new,
-                    request_id=first.request_id)
-    sb.use_kernel = True
-    c0, t0 = compile_s[0], time.perf_counter()
-    ServingEngine(sb, EngineServingConfig(max_batch=BATCH)).serve([again])
-    sb.use_kernel = False
-    assert len(again.output) == max_new
-    same = sum(a == b for a, b in zip(again.output, first.output))
-    print(f"use_kernel: request {first.request_id} served through "
-          f"slot_ffn in {time.perf_counter() - t0:.1f}s (compile "
-          f"{compile_s[0] - c0:.1f}s); {same}/{max_new} tokens equal "
-          f"to the einsum path's; peak memory so far "
-          f"{_peak_gb(dev):.2f} GB")
-
-    # -- 4. logits: slot path vs the fully-resident reference --------------
+    # -- 3. logits: slot path vs the fully-resident reference --------------
     # uniform tokens: the served prompts are topic-anchored, so they can
     # leave expert 0 unrouted, and then the missing-expert control is void
     prompt = jnp.asarray(np.random.default_rng(SEED).integers(

@@ -16,13 +16,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def silu(x):
+    """`jax.nn.silu` spelled out in x's dtype: Mosaic cannot lower a bf16
+    logistic, and this is the op sequence XLA expands that logistic to, so
+    the kernels round where XLA's own silu does."""
+    return x * (1 / (1 + jnp.exp(-x)))
+
+
+def ffn_block(x, wg, wu, wd):
+    """One d_ff tile of an expert FFN: (Cb, D) rows -> (Cb, D) f32 partial
+    of the down projection. The numerics contract of every expert FFN
+    kernel and its XLA reference: the gate and up projections accumulate
+    in f32 and round to the model dtype before silu(g) * u (taken in the
+    model dtype); the down projection accumulates in f32, across d_ff
+    tiles too."""
+    f32 = jnp.float32
+    g = jnp.dot(x, wg, preferred_element_type=f32).astype(x.dtype)
+    u = jnp.dot(x, wu, preferred_element_type=f32).astype(x.dtype)
+    return jnp.dot(silu(g) * u, wd, preferred_element_type=f32)
+
+
 def _ffn_kernel(x_ref, wg_ref, wu_ref, wd_ref, o_ref):
     ft = pl.program_id(2)
-    x = x_ref[0]                                   # (Cb, D)
-    g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-    u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-    h = (jax.nn.silu(g) * u).astype(x.dtype)       # (Cb, Fb)
-    part = jnp.dot(h, wd_ref[0], preferred_element_type=jnp.float32)
+    part = ffn_block(x_ref[0], wg_ref[0], wu_ref[0], wd_ref[0])
 
     @pl.when(ft == 0)
     def _init():

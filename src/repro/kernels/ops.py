@@ -38,11 +38,11 @@ def topk(logits, k: int, *, norm: bool = True, block_t: int = 256,
                                    interpret=interpret)
 
 
-def slot_ffn(x, slot_of_expert, s_gate, s_up, s_down, *, block_c: int = 128,
-             block_f: int = 128, interpret=None):
+def slot_ffn(x, slot_of_group, s_gate, s_up, s_down, *, block_c: int = 128,
+             block_f: int = 1024, interpret=None):
     if interpret is None:
         interpret = _default_interpret()
-    return slot_gather.slot_ffn(x, slot_of_expert, s_gate, s_up, s_down,
+    return slot_gather.slot_ffn(x, slot_of_group, s_gate, s_up, s_down,
                                 block_c=block_c, block_f=block_f,
                                 interpret=interpret)
 

@@ -1,10 +1,17 @@
 """Pallas TPU kernel: slot-indirect expert FFN (ExpertFlow's cache read path).
 
-The expert weights live in a bounded slot buffer (S < E slots); the
-(layer, expert) -> slot table is a scalar-prefetch operand, and the BlockSpec
-index maps perform the indirection — weight tiles stream HBM->VMEM directly
-from the right slot with NO materialized gather copy. This is the TPU-native
-replacement for the paper's GPU pointer-chase into the expert cache.
+The expert weights live in a bounded slot pool; the group -> slot table is a
+scalar-prefetch operand, and the BlockSpec index maps perform the
+indirection — weight tiles stream HBM->VMEM directly from the right slot
+with NO materialized gather copy. This is the TPU-native replacement for the
+paper's GPU pointer-chase into the expert cache.
+
+Only live groups stream: a group whose slot is -1 (no token routed to it, or
+its expert not resident) reads nothing. The live groups are compacted to the
+front of the grid (a scalar-prefetched order and count, the ragged /
+megablox idiom); every grid step past the count repeats the previous step's
+block indices, so the pipeline issues no DMA for it, and `pl.when` skips its
+compute. The output rows of a dead group are left undefined.
 """
 from __future__ import annotations
 
@@ -16,59 +23,112 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.moe_gemm import ffn_block
 
-def _slot_ffn_kernel(slot_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref):
-    ft = pl.program_id(2)
-    x = x_ref[0]
-    g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-    u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-    h = (jax.nn.silu(g) * u).astype(x.dtype)
-    part = jnp.dot(h, wd_ref[0], preferred_element_type=jnp.float32)
-
-    @pl.when(ft == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    o_ref[0] += part
+LANE, SUBLANE = 128, 16
 
 
-def _fit_block(n: int, want: int) -> int:
-    """Largest divisor of n that is <= want (tile sizes must divide the
-    axis; callers on real TPUs should pass aligned shapes, interpret mode
-    accepts anything)."""
-    b = min(want, n)
-    while n % b:
-        b -= 1
-    return b
+def _slot_ffn_kernel(slot_ref, group_ref, n_ref, x_ref, wg_ref, wu_ref,
+                     wd_ref, o_ref, acc_ref):
+    f = pl.program_id(2)
+
+    @pl.when(pl.program_id(0) < n_ref[0])
+    def _live():
+        @pl.when(f == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        acc_ref[...] += ffn_block(x_ref[0], wg_ref[0], wu_ref[0], wd_ref[0])
+
+        @pl.when(f == pl.num_programs(2) - 1)
+        def _store():
+            o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _fit_block(n: int, want: int, align: int = 1) -> int:
+    """Tile of an axis of length n: the whole axis if it fits in `want`,
+    else the largest multiple of `align` that divides n and is <= want,
+    else the whole axis (a block equal to the array dim is always legal)."""
+    if n <= want:
+        return n
+    for b in range(want - want % align, 0, -align):
+        if n % b == 0:
+            return b
+    return n
+
+
+def live_groups(slot_of_group: jnp.ndarray):
+    """(order, n): the live groups (slot >= 0) first, in group order, then
+    the dead ones; n (1,) int32 counts the live ones. The kernel's grid
+    visits order[0..n) and streams nothing after."""
+    dead = (slot_of_group < 0).astype(jnp.int32)
+    order = jnp.argsort(dead, stable=True).astype(jnp.int32)
+    return order, jnp.sum(1 - dead, dtype=jnp.int32).reshape(1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "block_f",
                                              "interpret"))
-def slot_ffn(x: jnp.ndarray, slot_of_expert: jnp.ndarray,
+def slot_ffn(x: jnp.ndarray, slot_of_group: jnp.ndarray,
              s_gate: jnp.ndarray, s_up: jnp.ndarray, s_down: jnp.ndarray, *,
-             block_c: int = 128, block_f: int = 128,
+             block_c: int = 128, block_f: int = 1024,
              interpret: bool = False) -> jnp.ndarray:
-    """x: (E, C, D) dispatch buffer; slot_of_expert: (E,) int32 (valid);
-    slot buffers (S, D, F) / (S, F, D). Returns (E, C, D) float32."""
-    E, C, D = x.shape
+    """x: (G, C, D) dispatch buffer, one group of C rows per expert;
+    slot_of_group: (G,) int32, the pool slot holding the group's weights or
+    -1 for a dead group; slot pool (S, D, F) / (S, F, D). Returns (G, C, D)
+    in x's dtype, defined on live groups only.
+
+    `block_f` 1024 streams a whole olmoe-1b-7b expert (12.6 MB) per grid
+    step: on a v5e a layer's 43 routed experts at 8 tokens took 1.38 ms,
+    against 1.44-1.50 ms at tiles of 128-512 (and 18.5 ms for an einsum
+    over all 864 slots of the pool)."""
+    G, C, D = x.shape
     F = s_gate.shape[-1]
-    block_c = _fit_block(C, block_c)
-    block_f = _fit_block(F, block_f)
-    grid = (E, C // block_c, F // block_f)
+    bc = _fit_block(C, block_c, SUBLANE)
+    bf = _fit_block(F, block_f, LANE)
+    nc, nf = C // bc, F // bf
+    order, n = live_groups(slot_of_group)
+
+    def at(g, c, f, slot, group, n):
+        """(group, c tile, f tile, slot) of grid step (g, c, f); a dead
+        step repeats the last live step's blocks."""
+        live = g < n[0]
+        e = group[jnp.where(live, g, jnp.maximum(n[0] - 1, 0))]
+        return (e, jnp.where(live, c, nc - 1), jnp.where(live, f, nf - 1),
+                jnp.maximum(slot[e], 0))
+
+    def rows(*idx):
+        e, c, _, _ = at(*idx)
+        return e, c, 0
+
+    def w_in(*idx):
+        _, _, f, s = at(*idx)
+        return s, 0, f
+
+    def w_out(*idx):
+        _, _, f, s = at(*idx)
+        return s, f, 0
+
+    isz, wsz = x.dtype.itemsize, s_gate.dtype.itemsize
+    # double-buffered x / weight / out blocks plus the f32 accumulator
+    vmem = 2 * (2 * bc * D * isz + 3 * D * bf * wsz) + bc * D * 4
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
+        num_scalar_prefetch=3,
+        grid=(G, nc, nf),
         in_specs=[
-            pl.BlockSpec((1, block_c, D), lambda e, c, f, s: (e, c, 0)),
-            pl.BlockSpec((1, D, block_f), lambda e, c, f, s: (s[e], 0, f)),
-            pl.BlockSpec((1, D, block_f), lambda e, c, f, s: (s[e], 0, f)),
-            pl.BlockSpec((1, block_f, D), lambda e, c, f, s: (s[e], f, 0)),
+            pl.BlockSpec((1, bc, D), rows),
+            pl.BlockSpec((1, D, bf), w_in),
+            pl.BlockSpec((1, D, bf), w_in),
+            pl.BlockSpec((1, bf, D), w_out),
         ],
-        out_specs=pl.BlockSpec((1, block_c, D), lambda e, c, f, s: (e, c, 0)),
+        out_specs=pl.BlockSpec((1, bc, D), rows),
+        scratch_shapes=[pltpu.VMEM((bc, D), jnp.float32)],
     )
     return pl.pallas_call(
         _slot_ffn_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((E, C, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((G, C, D), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=max(32 << 20, vmem + (8 << 20))),
         interpret=interpret,
-    )(slot_of_expert.astype(jnp.int32), x, s_gate, s_up, s_down)
+    )(slot_of_group.astype(jnp.int32), order, n, x, s_gate, s_up, s_down)

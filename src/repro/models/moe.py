@@ -5,9 +5,10 @@ Three compute formulations, all numerically equivalent (up to capacity drops):
 - `moe_reference`   dense all-experts oracle (smoke tests / kernels ref)
 - `moe_grouped`     sort + capacity-buffer + grouped einsum — the production
                     path; expert dim shards over the `model` mesh axis (EP)
-- `moe_slotbuf`     ExpertFlow runtime path: expert weights are fetched from a
-                    bounded device-resident slot buffer via an indirection
-                    table (the paper's GPU-memory cache, TPU-adapted)
+- `moe_slotbuf`     ExpertFlow runtime path: the routed experts' weights are
+                    streamed from a bounded device-resident slot pool via
+                    an indirection table (the paper's GPU-memory cache,
+                    TPU-adapted)
 """
 from __future__ import annotations
 
@@ -375,70 +376,70 @@ def _combine_gather(y_flat: jnp.ndarray, flat_slot: jnp.ndarray,
     return contrib.reshape(T, -1, d).sum(axis=1)
 
 
-def moe_slotbuf(params, slot_weights, slot_of_expert: jnp.ndarray,
-                x: jnp.ndarray, moe, capacity: Optional[int] = None,
-                router_out: Optional[RouterOutput] = None,
-                use_kernel: bool = False, interpret: Optional[bool] = None):
-    """MoE compute where expert weights live in a bounded slot buffer.
+# a slot-FFN group's rows are a multiple of the sublane tile (free on the
+# TPU): a one-row group would be a vector-matrix product, whose f32 sum XLA
+# orders differently, so a request served alone would not match its row of
+# a batch bit for bit
+ROW_TILE = 8
 
-    slot_weights: dict(w_gate (S, d, f), w_up (S, d, f), w_down (S, f, d))
-    with S = n_slots (usually < E). `slot_of_expert`: (E,) int32, -1 if not
-    resident. Tokens routed to a non-resident expert have their gates zeroed
-    AND dispatch to a dead sentinel slot past the real buffer, so they can
-    never consume a real slot's capacity (clamping them to slot 0 let misses
-    evict slot-0's own tokens). The runtime guarantees residency before
-    dispatch, so in normal operation the sentinel slot stays empty.
+
+def live_slots(slot_of_expert: jnp.ndarray,
+               expert_ids: jnp.ndarray) -> jnp.ndarray:
+    """(E,) int32: the slot of each expert that has an assignment in
+    `expert_ids` and is resident, -1 for every other expert — the groups
+    whose weights the slot FFN streams."""
+    routed = jnp.zeros(slot_of_expert.shape, jnp.bool_).at[
+        expert_ids.reshape(-1)].set(True)
+    return jnp.where(routed, slot_of_expert, -1).astype(jnp.int32)
+
+
+def moe_slotbuf(params, slot_weights, slot_of_expert: jnp.ndarray,
+                x: jnp.ndarray, moe,
+                router_out: Optional[RouterOutput] = None,
+                interpret: Optional[bool] = None):
+    """MoE compute where expert weights live in a bounded slot pool.
+
+    slot_weights: dict(w_gate (S, d, f), w_up (S, d, f), w_down (S, f, d));
+    `slot_of_expert`: (E,) int32 slot of each of the layer's experts, -1 if
+    not resident. Tokens are dispatched by EXPERT, E groups of T rows (T
+    rounded up to ROW_TILE): top-k picks distinct experts per token, so no
+    expert can get more than T assignments and nothing drops. The Pallas
+    kernel (`kernels.slot_gather.slot_ffn`) then streams, through the slot
+    indirection, only the weights of experts that have an assignment and are
+    resident — the pool is never a compute dimension. On the CPU the
+    kernel's XLA reference (`slot_ffn_ref`) stands in for it unless
+    `interpret` asks for the kernel; it gathers the same experts' weights
+    and rounds the same way. Assignments to non-resident experts get zero
+    gates and read a zero row in the combine, so they contribute nothing;
+    the runtime guarantees residency before dispatch, so in normal operation
+    there are none.
 
     `router_out` skips re-routing when the caller already routed (the fused
     engine routes on device first to learn the needed-expert set).
-
-    Two numerically equivalent expert paths:
-    - einsum over the slot-grouped buffer (the numerics oracle; dispatch
-      groups by *slot*, so compute scales with S not E);
-    - ``use_kernel=True``: the Pallas slot-indirect kernel
-      (`kernels.slot_gather.slot_ffn`) — dispatch groups by *expert* and the
-      kernel's scalar-prefetch indirection streams each expert's weights
-      from its slot (interpret mode on CPU, Mosaic on TPU).
     Router weights / shared experts stay permanently resident (small).
     """
+    from repro.kernels import ops as kernel_ops
     T, d = x.shape
     E, k = moe.num_experts, moe.top_k
-    n_slots = slot_weights["w_gate"].shape[0]
-    if capacity is None:
-        capacity = max(1, int(T * k / max(E, 1) * moe.capacity_factor) * 4)
     r = router_out if router_out is not None else route(
         params["router"], x, k, moe.router_norm_topk)
-    slot_raw = slot_of_expert[r.expert_ids]                       # (T, k)
-    resident = slot_raw >= 0
-    gates = r.gates * resident.astype(r.gates.dtype)
-
-    if use_kernel:
-        # per-EXPERT dispatch; the kernel chases expert -> slot indirection
-        from repro.kernels import ops as kernel_ops
-        buf, _, _, keep, order, flat_slot = _dispatch_gather(
-            x, r.expert_ids, E, capacity)
-        slot_valid = jnp.maximum(slot_of_expert, 0).astype(jnp.int32)
-        y = kernel_ops.slot_ffn(buf, slot_valid, slot_weights["w_gate"],
-                                slot_weights["w_up"], slot_weights["w_down"],
-                                interpret=interpret)              # (E, C, d)
-        flat_gates = gates.reshape(-1)[order]
-        weight = flat_gates * keep.astype(jnp.float32)
-        out = _combine_gather(y.reshape(E * capacity, d), flat_slot, order,
-                              weight, T, d, valid=keep).astype(x.dtype)
+    resident = slot_of_expert[r.expert_ids] >= 0                  # (T, k)
+    C = -(-T // ROW_TILE) * ROW_TILE
+    buf, _, _, _, order, flat_slot = _dispatch_gather(
+        x, r.expert_ids, E, C)
+    live_slot = live_slots(slot_of_expert, r.expert_ids)
+    w = (slot_weights["w_gate"], slot_weights["w_up"], slot_weights["w_down"])
+    if interpret is None and jax.default_backend() == "cpu":
+        # the kernel's XLA reference, under its numerics contract: running
+        # the kernel interpreted in every engine test cost the CPU test
+        # suite 28% more time
+        y = kernel_ops.slot_ffn_ref(buf, live_slot, *w)
     else:
-        # per-SLOT dispatch; non-resident assignments go to sentinel slot S
-        slot_ids = jnp.where(resident, slot_raw, n_slots).astype(jnp.int32)
-        buf, _, sid, keep, order, flat_slot = _dispatch_gather(
-            x, slot_ids, n_slots, capacity)
-        g = jnp.einsum("scd,sdf->scf", buf, slot_weights["w_gate"])
-        u = jnp.einsum("scd,sdf->scf", buf, slot_weights["w_up"])
-        h = jax.nn.silu(g) * u
-        y = jnp.einsum("scf,sfd->scd", h, slot_weights["w_down"])
-        flat_gates = gates.reshape(-1)[order]
-        weight = flat_gates * keep.astype(jnp.float32)
-        out = _combine_gather(y.reshape(n_slots * capacity, d), flat_slot,
-                              order, weight, T, d,
-                              valid=keep & (sid < n_slots)).astype(x.dtype)
+        y = kernel_ops.slot_ffn(buf, live_slot, *w, interpret=interpret)
+    live = resident.reshape(-1)[order]
+    weight = r.gates.reshape(-1)[order] * live.astype(jnp.float32)
+    out = _combine_gather(y.reshape(E * C, d), flat_slot, order, weight, T,
+                          d, valid=live).astype(x.dtype)
     if "shared" in params:
         s = params["shared"]
         out = out + swiglu(x, s["w_gate"], s["w_up"], s["w_down"])

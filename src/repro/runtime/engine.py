@@ -357,6 +357,8 @@ class SlotPathStats:
     host_hits: int = 0         # demanded experts already staged in host tier
     host_misses: int = 0       # demanded experts promoted disk->host first
     disk_stall_s: float = 0.0  # exposed disk-link stall (link-clock units)
+    ffn_calls: int = 0         # slot-pool FFN dispatches (one per MoE layer)
+    ffn_experts: int = 0       # experts whose weights those FFNs streamed
 
     def snapshot(self) -> Dict[str, int]:
         return dataclasses.asdict(self)
@@ -466,7 +468,7 @@ class SlotBufferEngine:
 
     def __init__(self, cfg: ModelConfig, params, model: Model,
                  n_slots_per_layer: int, *, fused: bool = True,
-                 use_kernel: bool = False, prefetch: bool = True,
+                 prefetch: bool = True,
                  link_bandwidth: float = 64e9, max_seq: int = 256,
                  step_size: Optional[int] = None,
                  controller: Optional[StepSizeController] = None,
@@ -499,7 +501,6 @@ class SlotBufferEngine:
         # f32 model would round every expert it swaps in
         self.buffer = make_buffer(cfg, self.n_slots, model.dtype)
         self.fused = fused
-        self.use_kernel = use_kernel
         # decode superkernel: batched decode restructured into per-MoE-layer
         # SEGMENTS (preceding dense layers + the MoE layer), each ONE jitted
         # dispatch built on the fused Pallas kernels (attention insert +
@@ -672,8 +673,7 @@ class SlotBufferEngine:
         (`moe_ffn` whole prompts, `moe_ffn_chunk` prefill chunks,
         `moe_ffn_decode` decode steps): one registry entry, compiled and
         traced under that name, per path."""
-        use_kernel = self.use_kernel
-        key = ("ffn", self._spec_key(spec), use_kernel, role)
+        key = ("ffn", self._spec_key(spec), role)
         if key not in self._fns:
             cfg = self.cfg
             from repro.models.transformer import _zc
@@ -682,17 +682,24 @@ class SlotBufferEngine:
                 B, T, d = x.shape
                 out, _ = moe_mod.moe_slotbuf(
                     p["moe"], slot_weights, slot_map, flat, cfg.moe,
-                    capacity=B * T * cfg.moe.top_k, router_out=r,
-                    use_kernel=use_kernel)
+                    router_out=r)
                 ff = out.reshape(B, T, d)
                 if "post_ffn_norm" in p:
                     ff = rms_norm(ff, p["post_ffn_norm"], cfg.norm_eps,
                                   zero_centered=_zc(cfg))
                 return x + ff
-            self._fns[key] = named_jit(
-                role + self._spec_tag(spec) + ("_kernel" if use_kernel else ""),
-                fn)
+            self._fns[key] = named_jit(role + self._spec_tag(spec), fn)
         return self._fns[key]
+
+    def _ffn(self, spec: LayerSpec, role: str, p, slot_map, x, flat, r,
+             n_experts: int):
+        """Dispatch the layer's FFN against the pool, counting the call and
+        the `n_experts` resident experts it streams (host-side: the needed
+        set of a sync layer, the prediction a speculative one runs on)."""
+        self.stats.ffn_calls += 1
+        self.stats.ffn_experts += n_experts
+        return self._dispatch(self._ffn_fn(spec, role), p, self.buffer,
+                              slot_map, x, flat, r)
 
     def _next_router(self, li: int):
         """Router weights of MoE layer li (device array), or None."""
@@ -1372,8 +1379,8 @@ class SlotBufferEngine:
                 # issue next-layer swap-ins BEFORE this layer's FFN dispatch
                 self.prefetch_layer(li + 1, predicted)
             slot_map = jnp.asarray(self.table.layer_slot_map(li))
-            x = self._dispatch(self._ffn_fn(spec, "moe_ffn"), p,
-                               self.buffer, slot_map, x, flat, r)
+            x = self._ffn(spec, "moe_ffn", p, slot_map, x, flat, r,
+                          len(needed))
             li += 1
         # next step's sweep restarts at layer 0: shield the first layer's
         # residents from the step-boundary prefetches (paper §3.3.1)
@@ -1467,7 +1474,8 @@ class SlotBufferEngine:
         and `prefill_chunk` MUST run this identically — any accounting or
         residency change that touched only one would silently diverge the
         two ingestion paths the bit-exactness contract pins together.
-        Returns the layer's slot map for the FFN dispatch."""
+        Returns the layer's slot map for the FFN dispatch and the number of
+        experts it needs."""
         s = self._horizon(li)
         masks = self._sync_masks_dev(li, s, flat, needed_dev, active_dev)
         with span("engine.mask_pull", layer=li, rows=s + 1):
@@ -1477,7 +1485,7 @@ class SlotBufferEngine:
         with span("engine.residency", layer=li):
             needed, predicted = self._decode_sync_rows(li, s, masks_h)
             self._sync_moe_layer(li, needed, predicted)
-        return jnp.asarray(self.table.layer_slot_map(li))
+        return jnp.asarray(self.table.layer_slot_map(li)), len(needed)
 
     def prefill(self, tokens) -> Tuple[jnp.ndarray, DecodeState]:
         """Run the prompt through the slot path, populating per-layer KV /
@@ -1506,9 +1514,8 @@ class SlotBufferEngine:
             x, flat, r, needed_dev, c = self._dispatch(
                 self._pre_prefill_fn(spec), p, x, positions)
             caches.append(c)
-            slot_map = self._prefill_moe_sync(li, flat, needed_dev)
-            x = self._dispatch(self._ffn_fn(spec, "moe_ffn"), p,
-                               self.buffer, slot_map, x, flat, r)
+            slot_map, n = self._prefill_moe_sync(li, flat, needed_dev)
+            x = self._ffn(spec, "moe_ffn", p, slot_map, x, flat, r, n)
             li += 1
         self.cache.protect_early_layers(
             max(1, min(self._s_eff(), len(self.moe_layer_ids))))
@@ -1588,9 +1595,10 @@ class SlotBufferEngine:
                 x, flat, r, needed_dev, cursor.caches[i] = self._dispatch(
                     self._pre_prefill_chunk_fn(spec, bucket), p, x, positions,
                     cursor.caches[i], o, t)
-                slot_map = self._prefill_moe_sync(li, flat, needed_dev, valid)
-                x = self._dispatch(self._ffn_fn(spec, "moe_ffn_chunk"), p,
-                                   self.buffer, slot_map, x, flat, r)
+                slot_map, n = self._prefill_moe_sync(li, flat, needed_dev,
+                                                     valid)
+                x = self._ffn(spec, "moe_ffn_chunk", p, slot_map, x, flat, r,
+                              n)
                 li += 1
             self.cache.protect_early_layers(
                 max(1, min(self._s_eff(), len(self.moe_layer_ids))))
@@ -1878,9 +1886,9 @@ class SlotBufferEngine:
                                   if k[0] == li}
                 pending.append((li, i, needed_dev, snap, ready_snap))
                 self._window_layers.add(li)
-                x = self._dispatch(self._ffn_fn(spec, "moe_ffn_decode"), p,
-                                   self.buffer, jnp.asarray(snap), x2, flat,
-                                   r)
+                x = self._ffn(spec, "moe_ffn_decode", p, jnp.asarray(snap),
+                              x2, flat, r,
+                              int(sum(snap[e] >= 0 for e in predicted[li])))
                 self.stats.spec_layers += 1
                 i += 1
                 li += 1
@@ -1901,8 +1909,8 @@ class SlotBufferEngine:
                 self._sync_moe_layer(li, needed, predicted)
             caches[i] = c2
             slot_map = jnp.asarray(self.table.layer_slot_map(li))
-            x = self._dispatch(self._ffn_fn(spec, "moe_ffn_decode"), p,
-                               self.buffer, slot_map, x2, flat, r)
+            x = self._ffn(spec, "moe_ffn_decode", p, slot_map, x2, flat, r,
+                          len(needed))
             i += 1
             li += 1
 
@@ -2368,9 +2376,10 @@ class SlotBufferEngine:
             self.stats.host_syncs += 1
             self._ensure_resident_seq(li, needed)
             slot_map = jnp.asarray(self.table.layer_slot_map(li))
+            self.stats.ffn_calls += 1
+            self.stats.ffn_experts += len(needed)
             out, _ = moe_mod.moe_slotbuf(
-                p["moe"], self.buffer, slot_map, flat, cfg.moe,
-                capacity=B * T * cfg.moe.top_k)
+                p["moe"], self.buffer, slot_map, flat, cfg.moe)
             ff = out.reshape(B, T, -1)
             if "post_ffn_norm" in p:
                 ff = rms_norm(ff, p["post_ffn_norm"], cfg.norm_eps,

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops
+from repro.kernels import ops, slot_gather
 
 SHAPES_FFN = [
     # (E, C, D, F, block_c, block_f)
@@ -74,9 +74,12 @@ def test_slot_ffn_equals_expert_ffn_under_identity_mapping():
     wu = jnp.asarray(rng.standard_normal((E, D, F)), jnp.bfloat16) * 0.1
     wd = jnp.asarray(rng.standard_normal((E, F, D)), jnp.bfloat16) * 0.1
     ident = jnp.arange(E, dtype=jnp.int32)
-    a = ops.slot_ffn(x, ident, wg, wu, wd, interpret=True)
-    b = ops.expert_ffn(x, wg, wu, wd, interpret=True)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    a = ops.slot_ffn(x, ident, wg, wu, wd, block_f=128, interpret=True)
+    b = ops.expert_ffn(x, wg, wu, wd, block_f=128, interpret=True)
+    # one numerics contract (`moe_gemm.ffn_block`); slot_ffn rounds its f32
+    # sum to the model dtype once, expert_ffn returns the f32 sum
+    np.testing.assert_array_equal(np.asarray(a),
+                                  np.asarray(b.astype(jnp.bfloat16)))
 
 
 # slot tables exercising the scalar-prefetch indirection for real:
@@ -107,7 +110,8 @@ def test_slot_ffn_indirection_tables(name, S, table, dtype):
     # the kernel's indirection must be EXACTLY a weight gather: same Pallas
     # arithmetic on pre-gathered weights gives bit-identical output
     via_gather = ops.expert_ffn(x, sg[soe], su[soe], sd[soe], interpret=True)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(via_gather))
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(via_gather.astype(dtype)))
     ref = ops.slot_ffn_ref(x, soe, sg, su, sd)
     tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -130,6 +134,41 @@ def test_slot_ffn_non_tile_aligned_shapes(C, F):
     ref = ops.slot_ffn_ref(x, soe, sg, su, sd)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("block_f", [32, 128], ids=["f_tiled", "f_whole"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_slot_ffn_skips_dead_groups(block_f, dtype):
+    """Groups with slot -1 (unrouted, or not resident) are dead: compacted
+    past the live count and never computed, so even NaN rows and a NaN
+    slot 0 behind them cannot reach a live group, whose rows equal the XLA
+    reference — bit for bit in one d_ff tile, to f32 summation order across
+    tiles."""
+    E, C, D, F, S = 6, 8, 64, 128, 7
+    rng = np.random.default_rng(block_f)
+    table = np.array([-1, 4, -1, 2, 6, -1])
+    live = table >= 0
+    x = np.asarray(rng.standard_normal((E, C, D)), np.float32) * 0.5
+    x[~live] = np.nan
+    sg, su, sd = (np.asarray(rng.standard_normal(s), np.float32) * 0.1
+                  for s in ((S, D, F), (S, D, F), (S, F, D)))
+    for w in (sg, su, sd):
+        w[0] = np.nan                       # where a dead group would read
+    args = [jnp.asarray(a, dtype) for a in (x, sg, su, sd)]
+    soe = jnp.asarray(table, jnp.int32)
+    order, n = slot_gather.live_groups(soe)
+    assert int(n[0]) == live.sum()
+    assert np.asarray(order)[:live.sum()].tolist() == [1, 3, 4]
+    out = ops.slot_ffn(args[0], soe, *args[1:], block_f=block_f,
+                       interpret=True)
+    want = ops.slot_ffn_ref(args[0], soe, *args[1:])
+    got, want = np.asarray(out, np.float32), np.asarray(want, np.float32)
+    assert np.isfinite(got[live]).all()
+    if block_f == F:
+        np.testing.assert_array_equal(got[live], want[live])
+    else:
+        tol = 8e-3 if dtype == jnp.bfloat16 else 2e-6
+        np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
 
 
 def test_interpret_mode_on_cpu_only(monkeypatch):

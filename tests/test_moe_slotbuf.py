@@ -1,11 +1,15 @@
-"""moe_slotbuf unit tests (fast lane): sentinel-slot capacity isolation,
-gather-dispatch parity with the grouped path, and the kernel path."""
+"""moe_slotbuf unit tests (fast lane): per-expert dispatch that never
+drops, non-resident and unrouted experts streamed by nothing, parity with
+the grouped path, the per-slot einsum oracle and the dense reference."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.base import MoEConfig
+from repro.kernels import ref, slot_gather
 from repro.models import moe as moe_mod
 
 
@@ -42,7 +46,8 @@ def test_non_resident_misses_cannot_evict_slot0_tokens():
     """Regression (sentinel slot): tokens routed to a NON-resident expert
     used to be clamped onto slot 0 and, gates zeroed or not, consumed slot
     0's dispatch capacity — evicting the resident slot-0 expert's own
-    tokens. They must go to a dead sentinel slot instead."""
+    tokens. A non-resident expert's group now streams nothing and its
+    assignments read a zero row."""
     d, E, f, C = 16, 4, 8, 4
     moe = MoEConfig(num_experts=E, top_k=1, d_expert=f)
     rng = np.random.default_rng(0)
@@ -57,7 +62,7 @@ def test_non_resident_misses_cannot_evict_slot0_tokens():
     # tokens, so under the old clamping they stole all of slot 0's capacity.
     x = _onehot_tokens([2] * C + [0] * C, d)
     out, r = moe_mod.moe_slotbuf(params, slot_weights, slot_of_expert, x,
-                                 moe, capacity=C)
+                                 moe, interpret=True)
     assert np.array_equal(np.asarray(r.expert_ids).reshape(-1),
                           [2] * C + [0] * C)
     expected = np.asarray(_expert_ffn_rows(params, x[C:], 0))
@@ -71,9 +76,10 @@ def test_non_resident_misses_cannot_evict_slot0_tokens():
 
 def test_over_capacity_drop_does_not_clobber_last_kept_token():
     """Regression (gather dispatch): assignments dropped for exceeding a
-    slot's capacity must write OUT of range — not onto (slot, capacity-1),
-    where a duplicate-index set could zero the kept occupant of the last
-    row."""
+    group's capacity must write OUT of range — not onto (group,
+    capacity-1), where a duplicate-index set could zero the kept occupant
+    of the last row. moe_slotbuf's capacity of T rows an expert cannot
+    drop; the per-slot oracle, which shares the dispatch, can."""
     d, E, f, C = 16, 4, 8, 4
     moe = MoEConfig(num_experts=E, top_k=1, d_expert=f)
     rng = np.random.default_rng(4)
@@ -83,7 +89,8 @@ def test_over_capacity_drop_does_not_clobber_last_kept_token():
     # 5 tokens onto expert 0 with capacity 4: the first 4 (stable sort) are
     # kept — INCLUDING the one at position capacity-1 — and the 5th drops
     x = _onehot_tokens([0] * 5, d)
-    out, _ = moe_mod.moe_slotbuf(params, sw, ident, x, moe, capacity=C)
+    out, _ = ref.moe_slotbuf_einsum_ref(params, sw, ident, x, moe,
+                                        capacity=C)
     expected = np.asarray(_expert_ffn_rows(params, x[:C], 0))
     np.testing.assert_allclose(np.asarray(out[:C]), expected,
                                rtol=1e-5, atol=1e-6)
@@ -110,15 +117,17 @@ def test_full_residency_matches_grouped_bitwise():
     slot_weights = {kk: params[kk][jnp.asarray(perm)]
                     for kk in ("w_gate", "w_up", "w_down")}
     out_s, _ = moe_mod.moe_slotbuf(params, slot_weights, slot_of_expert, x,
-                                   moe, capacity=T * k)
+                                   moe, interpret=True)
     out_g, _ = moe_mod.moe_grouped(params, x, moe, capacity=T * k)
     np.testing.assert_array_equal(np.asarray(out_s, np.float32),
                                   np.asarray(out_g, np.float32))
 
 
 def test_kernel_path_matches_einsum_path():
-    """use_kernel=True (per-expert dispatch + Pallas slot indirection) must
-    agree with the einsum oracle, including with non-resident experts."""
+    """The kernel path (per-expert dispatch + Pallas slot indirection over
+    the routed, resident experts only) must agree bit for bit with the
+    per-slot einsum oracle over the whole pool, including with non-resident
+    experts — and so must the CPU's default, the kernel's XLA reference."""
     d, E, f, T, k = 32, 6, 16, 20, 2
     moe = MoEConfig(num_experts=E, top_k=k, d_expert=f)
     rng = np.random.default_rng(2)
@@ -138,14 +147,14 @@ def test_kernel_path_matches_einsum_path():
     for e, s in enumerate(slots):
         if s >= 0:
             sw = {kk: sw[kk].at[s].set(params[kk][e]) for kk in sw}
-    out_e, _ = moe_mod.moe_slotbuf(params, sw, slot_of_expert, x, moe,
-                                   capacity=T * k)
+    out_e, _ = ref.moe_slotbuf_einsum_ref(params, sw, slot_of_expert, x,
+                                          moe)
     out_k, _ = moe_mod.moe_slotbuf(params, sw, slot_of_expert, x, moe,
-                                   capacity=T * k, use_kernel=True,
                                    interpret=True)
-    np.testing.assert_allclose(np.asarray(out_k, np.float32),
-                               np.asarray(out_e, np.float32),
-                               rtol=3e-2, atol=3e-2)
+    out_r, _ = moe_mod.moe_slotbuf(params, sw, slot_of_expert, x, moe)
+    for out in (out_k, out_r):
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(out_e, np.float32))
 
 
 def test_router_out_skips_rerouting():
@@ -158,17 +167,20 @@ def test_router_out_skips_rerouting():
     sw = {kk: params[kk] for kk in ("w_gate", "w_up", "w_down")}
     ident = jnp.arange(E, dtype=jnp.int32)
     x = jnp.asarray(rng.standard_normal((T, d)), jnp.float32)
-    out_a, r = moe_mod.moe_slotbuf(params, sw, ident, x, moe, capacity=T * k)
-    out_b, _ = moe_mod.moe_slotbuf(params, sw, ident, x, moe, capacity=T * k,
-                                   router_out=r)
+    out_a, r = moe_mod.moe_slotbuf(params, sw, ident, x, moe)
+    out_b, _ = moe_mod.moe_slotbuf(params, sw, ident, x, moe, router_out=r)
     np.testing.assert_array_equal(np.asarray(out_a), np.asarray(out_b))
 
 
-@pytest.mark.parametrize("use_kernel", [False, True], ids=["einsum", "kernel"])
-def test_output_independent_of_slot_placement(use_kernel):
+@pytest.mark.parametrize("impl", [ref.moe_slotbuf_einsum_ref,
+                                  functools.partial(moe_mod.moe_slotbuf,
+                                                    interpret=True)],
+                         ids=["einsum", "kernel"])
+def test_output_independent_of_slot_placement(impl):
     """Which slots hold a layer's experts must not change its output: each
     token's k expert outputs are summed in the router's order, not in slot
-    order (in f32 a different order of the sum changes the last bits)."""
+    order (in f32 a different order of the sum changes the last bits) —
+    in the per-slot oracle and in the kernel path alike."""
     rng = np.random.default_rng(4)
     d, E, f, T = 32, 8, 16, 24
     moe = MoEConfig(num_experts=E, top_k=4, d_expert=f, capacity_factor=2.0)
@@ -181,9 +193,129 @@ def test_output_independent_of_slot_placement(use_kernel):
         # expert e in slot perm[e] of a buffer with two spare slots
         slots = {k: jnp.zeros((E + 2,) + w.shape[1:], w.dtype)
                  .at[perm].set(w) for k, w in ws.items()}
-        out, _ = moe_mod.moe_slotbuf(p, slots, jnp.asarray(perm, jnp.int32),
-                                     x, moe, capacity=T * 4,
-                                     use_kernel=use_kernel)
+        out, _ = impl(p, slots, jnp.asarray(perm, jnp.int32), x, moe)
         outs.append(np.asarray(out))
     for o in outs[1:]:
         np.testing.assert_array_equal(o, outs[0])
+
+
+def _olmoe_like(rng, d=64, E=64, f=32, dtype=jnp.float32):
+    """olmoe-1b-7b's routing shape (64 experts, top-8) at small widths."""
+    p = _mk_params(rng, d, E, f, dtype)
+    p["router"] = jnp.asarray(rng.standard_normal((d, E)), jnp.float32)
+    return p
+
+
+def _pool(rng, params, slot_of_expert, n_slots):
+    """A pool of n_slots slots (noise in the unused ones) holding expert e
+    in slot slot_of_expert[e] where that is >= 0."""
+    pool = {}
+    for kk in ("w_gate", "w_up", "w_down"):
+        w = params[kk]
+        buf = jnp.asarray(rng.standard_normal((n_slots,) + w.shape[1:]),
+                          w.dtype)
+        for e, s in enumerate(slot_of_expert):
+            if s >= 0:
+                buf = buf.at[s].set(w[e])
+        pool[kk] = buf
+    return pool
+
+
+@pytest.mark.parametrize("T", [8, 32], ids=["decode", "chunk"])
+def test_matches_einsum_oracle_and_dense_reference(T):
+    """At a decode shape (8 tokens, top-8 of 64) and a chunk shape (32
+    tokens): bit-exact in bf16 with the per-slot einsum over the whole pool;
+    in f32, where a dot of another shape sums in another order, within f32
+    rounding of it and of the dense all-experts reference."""
+    rng = np.random.default_rng(20 + T)
+    E, k, S = 64, 8, 80
+    moe = MoEConfig(num_experts=E, top_k=k, d_expert=32)
+    soe = jnp.asarray(rng.permutation(S)[:E], jnp.int32)
+    outs = {}
+    for dtype in (jnp.bfloat16, jnp.float32):
+        p = _olmoe_like(rng, dtype=dtype)
+        pool = _pool(rng, p, np.asarray(soe), S)
+        x = jnp.asarray(rng.standard_normal((T, 64)), dtype)
+        out, _ = moe_mod.moe_slotbuf(p, pool, soe, x, moe, interpret=True)
+        oracle, _ = ref.moe_slotbuf_einsum_ref(p, pool, soe, x, moe)
+        outs[dtype] = np.asarray(out, np.float32), np.asarray(oracle,
+                                                              np.float32)
+    np.testing.assert_array_equal(*outs[jnp.bfloat16])
+    np.testing.assert_allclose(*outs[jnp.float32], rtol=1e-5, atol=1e-6)
+    dense, _ = moe_mod.moe_reference(p, x, moe)
+    np.testing.assert_allclose(outs[jnp.float32][0], np.asarray(dense),
+                               rtol=1e-5, atol=1e-6)
+
+
+def test_non_resident_experts_contribute_zero_and_clobber_nothing():
+    """Half of the routed experts not resident: every token gets exactly
+    the gate-weighted sum of its RESIDENT experts' outputs (a missing
+    expert adds zero), whichever slots the resident ones sit in — the
+    missing experts' groups are dead and cannot overwrite a live one."""
+    rng = np.random.default_rng(31)
+    d, E, f, T, k, S = 32, 8, 16, 12, 4, 6
+    moe = MoEConfig(num_experts=E, top_k=k, d_expert=f)
+    p = _olmoe_like(rng, d=d, E=E, f=f)
+    soe = np.array([4, -1, 0, -1, 5, -1, 2, -1])   # odd experts missing
+    pool = _pool(rng, p, soe, S)
+    x = jnp.asarray(rng.standard_normal((T, d)), jnp.float32)
+    out, r = moe_mod.moe_slotbuf(p, pool, jnp.asarray(soe, jnp.int32), x,
+                                 moe, interpret=True)
+    ids, gates = np.asarray(r.expert_ids), np.asarray(r.gates)
+    assert (soe[ids] < 0).any() and (soe[ids] >= 0).any()
+    want = np.zeros((T, d), np.float32)
+    for t in range(T):
+        for j in range(k):
+            e = int(ids[t, j])
+            if soe[e] >= 0:
+                want[t] += gates[t, j] * np.asarray(
+                    _expert_ffn_rows(p, x[t:t + 1], e))[0]
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-5, atol=1e-6)
+
+
+def test_empty_groups_stream_nothing():
+    """Unrouted and non-resident experts are dead groups: the kernel's
+    compacted group count is the number of distinct experts that are both
+    routed and resident, and the output still equals the reference."""
+    rng = np.random.default_rng(32)
+    d, E, f, k = 16, 16, 8, 2
+    moe = MoEConfig(num_experts=E, top_k=k, d_expert=f)
+    p = _mk_params(rng, d, E, f)
+    # one-hot tokens under the forced router: experts 0..5 routed (each
+    # token's second choice is a tie, so take them from the ids), 6..15 not
+    x = _onehot_tokens([0, 1, 2, 3, 4, 5, 0, 2], d)
+    soe = np.full(E, -1)
+    soe[[0, 2, 3, 7, 9]] = [3, 0, 5, 1, 2]          # 1, 4, 5 routed, missing
+    soe_d = jnp.asarray(soe, jnp.int32)
+    pool = _pool(rng, p, soe, 6)
+    out, r = moe_mod.moe_slotbuf(p, pool, soe_d, x, moe, interpret=True)
+    routed = set(np.asarray(r.expert_ids).reshape(-1).tolist())
+    want_live = {e for e in routed if soe[e] >= 0}
+    live = moe_mod.live_slots(soe_d, r.expert_ids)
+    assert set(np.nonzero(np.asarray(live) >= 0)[0].tolist()) == want_live
+    order, n = slot_gather.live_groups(live)
+    assert int(n[0]) == len(want_live) > 0
+    assert set(np.asarray(order)[:int(n[0])].tolist()) == want_live
+    oracle, _ = ref.moe_slotbuf_einsum_ref(p, pool, soe_d, x, moe)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(oracle))
+
+
+def test_capacity_of_t_rows_never_drops():
+    """Every token routed to the same k experts: each expert gets all T
+    tokens, which its T rows hold — no assignment drops, and every token's
+    output equals the dense reference."""
+    rng = np.random.default_rng(33)
+    d, E, f, T, k = 16, 8, 8, 24, 3
+    moe = MoEConfig(num_experts=E, top_k=k, d_expert=f)
+    p = _mk_params(rng, d, E, f)
+    router = np.zeros((d, E), np.float32)
+    router[0, :k] = [3.0, 2.0, 1.0]                  # experts 0, 1, 2 win
+    p["router"] = jnp.asarray(router)
+    x = jnp.asarray(np.abs(rng.standard_normal((T, d))) + 0.5, jnp.float32)
+    out, r = moe_mod.moe_slotbuf(p, _pool(rng, p, np.arange(E)[::-1], E),
+                                 jnp.asarray(np.arange(E)[::-1], jnp.int32),
+                                 x, moe, interpret=True)
+    assert (np.sort(np.asarray(r.expert_ids), 1) == [0, 1, 2]).all()
+    dense, _ = moe_mod.moe_reference(p, x, moe)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
+                               rtol=1e-5, atol=1e-6)

@@ -179,28 +179,104 @@ def test_prefetch_never_self_evicts_into_duplicate_slots():
 
 @pytest.mark.slow
 def test_slot_buffer_kernel_path_matches_einsum():
-    """use_kernel=True routes the FFN through the Pallas slot-indirect
-    kernel (interpret mode on CPU): bit-exact vs its own reference, and
-    within bf16 tolerance of the einsum path."""
+    """The FFN runs through the Pallas slot-indirect kernel over the routed,
+    resident experts only (interpret mode on CPU), with a pool of half the
+    experts so slots churn: bit-exact vs the engine's own fully-resident
+    reference, and within bf16 tolerance of the eager model, whose MoE is
+    the grouped einsum."""
     cfg = get_smoke_config("olmoe-1b-7b")
     eng = Engine(cfg, max_seq=64)
     toks = jnp.asarray(np.random.default_rng(7).integers(
         0, cfg.vocab_size, (2, 8)), jnp.int32)
-    sb_e = SlotBufferEngine(cfg, eng.params, eng.model,
-                            n_slots_per_layer=cfg.moe.num_experts)
-    sb_k = SlotBufferEngine(cfg, eng.params, eng.model,
-                            n_slots_per_layer=cfg.moe.num_experts,
-                            use_kernel=True)
-    x_e = sb_e.forward(toks)
-    x_k = sb_k.forward(toks)
-    assert float(jnp.max(jnp.abs(x_k - sb_k.reference_forward(toks)))) == 0.0
+    sb = SlotBufferEngine(cfg, eng.params, eng.model,
+                          n_slots_per_layer=cfg.moe.num_experts // 2 + 1)
+    x_k = sb.forward(toks)
+    assert float(jnp.max(jnp.abs(x_k - sb.reference_forward(toks)))) == 0.0
+    x_e = _eager_unrolled(eng.model, eng.params, cfg, toks)
     np.testing.assert_allclose(np.asarray(x_k, np.float32),
                                np.asarray(x_e, np.float32),
                                rtol=5e-2, atol=5e-2)
-    # switching an engine to the kernel after it served on the einsum path
-    # runs the kernel, not the einsum function cached for the same layer
-    sb_e.use_kernel = True
-    assert float(jnp.max(jnp.abs(sb_e.forward(toks) - x_k))) == 0.0
+    L = len(sb.moe_layer_ids)
+    assert sb.stats.ffn_calls == L
+    assert 0 < sb.stats.ffn_experts <= L * cfg.moe.num_experts
+
+
+def _out_shapes(jaxpr):
+    """(primitive, shape) of every value computed in `jaxpr`, sub-jaxprs
+    (jit, pallas kernels, loops) included; a jaxpr's inputs are not."""
+    from jax.extend import core as jcore
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield eqn.primitive.name, tuple(getattr(v.aval, "shape", ()))
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (tuple, list)) else (p,):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    yield from _out_shapes(sub.jaxpr)
+                elif isinstance(sub, jcore.Jaxpr):
+                    yield from _out_shapes(sub)
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+@pytest.mark.parametrize("role,B,T", [("moe_ffn_decode", 3, 1),
+                                      ("moe_ffn_chunk", 1, 32)],
+                         ids=["decode", "chunk"])
+def test_ffn_holds_nothing_pool_sized(role, B, T, backend, monkeypatch):
+    """Guard: the decode and chunk FFNs compute nothing over the slot pool.
+    No value in their traced program has the pool's n_slots as its leading
+    dimension — only the pool operand itself does — so a compute over
+    every slot cannot come back unnoticed. Traced as on the CPU (the
+    kernel's XLA reference) and as on the TPU (the Mosaic kernel)."""
+    from repro.models import moe as moe_mod
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = get_smoke_config("olmoe-1b-7b")
+    eng = Engine(cfg, max_seq=64)
+    sb = SlotBufferEngine(cfg, eng.params, eng.model, n_slots_per_layer=7)
+    n_slots = sb.n_slots
+    d, E, k = cfg.d_model, cfg.moe.num_experts, cfg.moe.top_k
+    assert n_slots not in (d, E, E + 1, k, B * T, B * T * k, E * B * T,
+                           cfg.moe.d_expert)
+    i = sb.moe_layer_ids[0]
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal((B, T, d)), eng.model.dtype)
+    flat = x.reshape(B * T, d)
+    r = moe_mod.route(sb._p[i]["moe"]["router"], flat, k)
+    slot_map = jnp.asarray(rng.permutation(n_slots)[:E], jnp.int32)
+    fn = sb._ffn_fn(sb.specs[i], role)
+    jaxpr = jax.make_jaxpr(fn)(sb._p[i], sb.buffer, slot_map, x, flat, r)
+    pool_sized = [(name, shape) for name, shape in _out_shapes(jaxpr.jaxpr)
+                  if shape and shape[0] == n_slots]
+    assert not pool_sized, pool_sized
+    assert any(len(v.aval.shape) and v.aval.shape[0] == n_slots
+               for v in jaxpr.jaxpr.invars)
+
+
+def test_ffn_counters_count_routed_experts(monkeypatch):
+    """`ffn_calls` counts the FFN dispatches and `ffn_experts` the experts
+    they stream: under a hand-built routing, token t picks experts
+    2t mod E and 2t+1 mod E in every layer."""
+    from repro.models import moe as moe_mod
+
+    def route(router_w, x, top_k, norm_topk=True, logit_bias=None):
+        T, E = x.shape[0], router_w.shape[1]
+        ids = (jnp.arange(T)[:, None] * top_k
+               + jnp.arange(top_k)[None]) % E
+        gates = jnp.full((T, top_k), 1.0 / top_k, jnp.float32)
+        logits = jnp.zeros((T, E), jnp.float32)
+        return moe_mod.RouterOutput(ids.astype(jnp.int32), gates, logits,
+                                    jax.nn.softmax(logits, -1))
+
+    monkeypatch.setattr(moe_mod, "route", route)
+    cfg = get_smoke_config("olmoe-1b-7b")
+    assert (cfg.moe.num_experts, cfg.moe.top_k) == (8, 2)
+    eng = Engine(cfg, max_seq=64)
+    sb = SlotBufferEngine(cfg, eng.params, eng.model,
+                          n_slots_per_layer=cfg.moe.num_experts)
+    L = len(sb.moe_layer_ids)
+    toks = jnp.zeros((1, 3), jnp.int32)      # 3 tokens: experts 0..5
+    sb.forward(toks)
+    assert (sb.stats.ffn_calls, sb.stats.ffn_experts) == (L, 6 * L)
+    sb.forward(jnp.zeros((2, 3), jnp.int32))  # 6 tokens: all 8 experts
+    assert (sb.stats.ffn_calls, sb.stats.ffn_experts) == (2 * L, 14 * L)
 
 
 @pytest.mark.parametrize("arch", ["olmoe-1b-7b", "deepseek-v2-lite"],

@@ -1,9 +1,10 @@
 """The main path's Pallas kernels, compiled for a described TPU v5e chip at
 the widths they serve: olmoe-1b-7b (d_model 2048, 64 experts of d_ff 1024,
-top-8, 16 MHA heads of 128) and, for MLA decode attention,
-deepseek-v2-lite (kv_lora_rank 512, rope dim 64). Nothing runs: the TPU
-compiler refuses here what the chip would refuse (block shapes off the
-tiling, scoped-VMEM overruns, primitives Mosaic cannot lower).
+top-8, 16 MHA heads of 128) and, for MLA decode attention and the expert
+FFN's tiling, deepseek-v2-lite (kv_lora_rank 512, rope dim 64, d_ff 1408).
+Nothing runs: the TPU compiler refuses here what the chip would refuse
+(block shapes off the tiling, scoped-VMEM overruns, primitives Mosaic
+cannot lower).
 
 The topology is described inside a fixture, never at import, so every
 pytest worker collects the same tests and only the one running this file
@@ -23,6 +24,7 @@ H, HD = 16, 128                          # olmoe-1b-7b attention
 B, MAX_SEQ = 4, 1024                     # serving batch and KV length
 SLOTS = 38 * 16                          # capacity 0.6 x 64, 16 layers
 MLA_H, MLA_R, MLA_P = 16, 512, 64        # deepseek-v2-lite MLA
+DSV2_F = 1408                            # deepseek-v2-lite expert d_ff
 
 
 @pytest.fixture(scope="module")
@@ -55,11 +57,14 @@ def _compile(one_chip, fn, *shapes):
 bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 
-@pytest.mark.parametrize("cap", [B * K, 32 * K], ids=["decode", "chunk"])
-def test_slot_ffn_compiles(one_chip, cap):
+@pytest.mark.parametrize("cap,f", [(8, F), (32, F), (8, DSV2_F)],
+                         ids=["decode", "chunk", "decode_dsv2"])
+def test_slot_ffn_compiles(one_chip, cap, f):
+    """One group of `cap` rows per expert (a batch of 8 decoding, a
+    32-token prefill chunk); deepseek-v2-lite's d_ff of 1408 tiles by 128."""
     _compile(one_chip, functools.partial(ops.slot_ffn, interpret=False),
-             ((E, cap, D), bf16), ((E,), i32), ((SLOTS, D, F), bf16),
-             ((SLOTS, D, F), bf16), ((SLOTS, F, D), bf16))
+             ((E, cap, D), bf16), ((E,), i32), ((SLOTS, D, f), bf16),
+             ((SLOTS, D, f), bf16), ((SLOTS, f, D), bf16))
 
 
 def test_expert_ffn_compiles(one_chip):
