@@ -2,8 +2,8 @@
 
 [arXiv:2405.04434] 27L (first layer dense) d_model=2048 16H, MLA
 (kv_lora=512, qk_nope=128, qk_rope=64, v=128, no q-lora), MoE: 64 routed
-experts top-6 + 2 shared, expert d_ff=1408, dense-layer d_ff=10944,
-vocab=102400.
+experts top-6 (softmax gates, not renormalized) + 2 shared, expert
+d_ff=1408, dense-layer d_ff=10944, vocab=102400.
 """
 from repro.configs.base import MLAConfig, MoEConfig, ModelConfig, reduce_config
 
@@ -22,6 +22,7 @@ CONFIG = ModelConfig(
     rope_theta=10000.0,
     moe=MoEConfig(num_experts=64, top_k=6, d_expert=1408,
                   num_shared_experts=2, d_shared=1408,
+                  router_norm_topk=False,      # published norm_topk_prob: false
                   first_dense_layers=1),
 )
 

@@ -616,13 +616,17 @@ class SlotBufferEngine:
         # share one trace
         return LayerSpec(spec.kind, spec.window, spec.is_moe, 0)
 
-    @staticmethod
-    def _spec_tag(spec: LayerSpec) -> str:
+    def _spec_tag(self, spec: LayerSpec, attention: bool = True) -> str:
         """Name suffix of a layer shape in a jitted function's name: empty
-        for global attention, else its kind and window."""
-        if spec.kind == "attn" and spec.window == 0:
+        for global GQA attention, `_mla` for latent attention, else the
+        mixer kind and its window. Roles that run no attention (the MoE
+        FFN) pass `attention=False` and name latent layers as GQA ones."""
+        kind = spec.kind
+        if kind == "attn" and attention and self.cfg.attention == "mla":
+            kind = "mla"
+        if kind == "attn" and spec.window == 0:
             return ""
-        return f"_{spec.kind}" + (f"_w{spec.window}" if spec.window else "")
+        return f"_{kind}" + (f"_w{spec.window}" if spec.window else "")
 
     def _embed_fn(self):
         if "embed" not in self._fns:
@@ -688,7 +692,8 @@ class SlotBufferEngine:
                     ff = rms_norm(ff, p["post_ffn_norm"], cfg.norm_eps,
                                   zero_centered=_zc(cfg))
                 return x + ff
-            self._fns[key] = named_jit(role + self._spec_tag(spec), fn)
+            self._fns[key] = named_jit(
+                role + self._spec_tag(spec, attention=False), fn)
         return self._fns[key]
 
     def _ffn(self, spec: LayerSpec, role: str, p, slot_map, x, flat, r,

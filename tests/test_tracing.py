@@ -137,6 +137,43 @@ def test_every_registered_function_has_a_name_of_its_own(arch, superkernel):
     assert want <= set(names), want - set(names)
 
 
+SHARED_ROLES = ["embed", "embed_chunk", "embed_decode", "logits", "logits_at",
+                "moe_ffn", "moe_ffn_chunk", "moe_ffn_decode", "pregate_s1",
+                "pregate_s1_batched"]
+
+
+@pytest.mark.parametrize("arch,attention_roles", [
+    # GQA layers keep the names the benchmark's trace readers match
+    ("olmoe-1b-7b", ["pre", "pre_decode_batched", "pre_prefill",
+                     "pre_prefill_chunk_kv16", "pre_prefill_chunk_kv8",
+                     "pre_pregate"]),
+    # latent attention says so in every role that runs it, the dense first
+    # layer's included; the MoE FFN (shared experts inside) is named as
+    # for GQA
+    ("deepseek-v2-lite", ["dense_decode_mla", "dense_mla",
+                          "dense_prefill_chunk_mla_kv16",
+                          "dense_prefill_chunk_mla_kv8", "dense_prefill_mla",
+                          "pre_decode_mla_batched", "pre_mla",
+                          "pre_mla_pregate", "pre_prefill_chunk_mla_kv16",
+                          "pre_prefill_chunk_mla_kv8", "pre_prefill_mla"])])
+def test_attention_roles_are_named_by_attention_kind(arch, attention_roles):
+    cfg = get_smoke_config(arch)
+    eng = Engine(cfg, max_seq=48)
+    sb = SlotBufferEngine(cfg, eng.params, eng.model, max_seq=48,
+                          n_slots_per_layer=cfg.moe.num_experts // 2,
+                          step_size=1)
+    rng = np.random.default_rng(3)
+    reqs = [Request(prompt=rng.integers(0, cfg.vocab_size, L).astype(np.int32),
+                    max_new_tokens=3, request_id=i)
+            for i, L in enumerate((8, 13))]
+    ServingEngine(sb, EngineServingConfig(max_batch=2, prefill_chunk=8,
+                                          admission_cap=False)).serve(reqs)
+    sb.prefill(reqs[0].prompt[None, :])          # whole-prompt roles
+    sb.forward(reqs[0].prompt[None, :])
+    names = sorted(f.__name__ for f in sb._fns.values())
+    assert names == sorted(SHARED_ROLES + attention_roles)
+
+
 def test_named_jit_compiles_under_its_name():
     f = named_jit("moe_ffn_decode", lambda x: x + 1)
     assert f.__name__ == "moe_ffn_decode"
