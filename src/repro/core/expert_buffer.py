@@ -5,11 +5,12 @@ A bounded number of *slots* hold expert FFN weights in device memory; an
 indirection table maps (layer, expert) -> slot. The host-side controller
 (`TwoLevelLRU` + prefetcher) owns the replacement policy; the device side is
 purely functional: `swap_in_many` writes ALL of a layer's missing experts in
-one jitted donated scatter fed from `HostExpertStore`'s pre-staged
-contiguous host views (standing in for the batched async host->HBM DMA a
-real deployment would issue; `swap_in` is the per-expert legacy form), and
-the MoE layer computes through `repro.models.moe.moe_slotbuf` using the
-indirection.
+a few jitted donated scatters. `HostExpertStore` keeps every routed expert
+in pinned host memory as a device-addressable array, and the scatter
+program copies its experts from there itself, so the host->HBM copy is an
+asynchronous DMA inside the program, not a copy on the calling thread
+(`swap_in` is the per-expert legacy form). The MoE layer computes through
+`repro.models.moe.moe_slotbuf` using the indirection.
 """
 from __future__ import annotations
 
@@ -19,18 +20,24 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import ModelConfig
 
+PINNED = "pinned_host"
+
 
 def make_buffer(cfg: ModelConfig, n_slots: int, dtype=jnp.bfloat16):
+    """The slot pool, committed to the first device as every write leaves
+    it: its jitted users then see one placement throughout."""
     m = cfg.moe
     assert m is not None, "slot buffer only applies to MoE configs"
     d, f = cfg.d_model, m.d_expert
+    device = jax.devices()[0]
     slots = {
-        "w_gate": jnp.zeros((n_slots, d, f), dtype),
-        "w_up": jnp.zeros((n_slots, d, f), dtype),
-        "w_down": jnp.zeros((n_slots, f, d), dtype),
+        "w_gate": jnp.zeros((n_slots, d, f), dtype, device=device),
+        "w_up": jnp.zeros((n_slots, d, f), dtype, device=device),
+        "w_down": jnp.zeros((n_slots, f, d), dtype, device=device),
     }
     return slots
 
@@ -53,73 +60,102 @@ def swap_in(slots: Dict[str, jnp.ndarray], slot_idx: jnp.ndarray,
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _swap_in_many(slots: Dict[str, jnp.ndarray], slot_idx: jnp.ndarray,
                   w_gate, w_up, w_down):
+    """Scatter N experts into `slot_idx`. Each weight is moved to device
+    memory inside the program: from pinned host memory that is a DMA the
+    executable issues and waits on itself, so the dispatch returns at once;
+    a weight already on the device moves nowhere."""
+    def stack(ws):
+        return jnp.stack([jax.device_put(w, jax.memory.Space.Device)
+                          for w in ws])
     return {
-        "w_gate": slots["w_gate"].at[slot_idx].set(jnp.stack(w_gate)),
-        "w_up": slots["w_up"].at[slot_idx].set(jnp.stack(w_up)),
-        "w_down": slots["w_down"].at[slot_idx].set(jnp.stack(w_down)),
+        "w_gate": slots["w_gate"].at[slot_idx].set(stack(w_gate)),
+        "w_up": slots["w_up"].at[slot_idx].set(stack(w_up)),
+        "w_down": slots["w_down"].at[slot_idx].set(stack(w_down)),
     }
 
 
-def _next_pow2(n: int) -> int:
-    return 1 << (n - 1).bit_length()
+def _pow2_parts(n: int) -> List[int]:
+    """n as a sum of distinct powers of two, largest first: 7 -> 4, 2, 1."""
+    return [1 << b for b in reversed(range(n.bit_length())) if n >> b & 1]
+
+
+def is_pinned(w) -> bool:
+    """Whether `w` is an array held in pinned host memory."""
+    sharding = getattr(w, "sharding", None)
+    return getattr(sharding, "memory_kind", None) == PINNED
 
 
 def swap_in_many(slots: Dict[str, jnp.ndarray], slot_idx,
                  w_gate, w_up, w_down) -> Dict[str, jnp.ndarray]:
-    """Write N experts' weights in ONE donated device dispatch.
+    """Write N experts' weights in at most log2(N)+1 donated dispatches.
 
     Replaces N sequential `swap_in` calls (N dispatches, N param-tree
-    re-slices) with a single batched scatter. slot_idx: (N,) distinct slots;
-    w_*: the N experts' weights, each a sequence of N arrays — views into
-    the host store (see `HostExpertStore.experts`) or a stacked (N, d, f) /
-    (N, f, d) array. Each expert goes to the device as it is and is stacked
-    there: no host-side gather or staging copy (on a TPU v5e host a copy
-    into a fresh host buffer ran at under 1 GB/s, the host->device
-    transfer itself at 5 GB/s). N is padded up to the next power of two by
-    repeating the LAST entry on the device — duplicate indices then carry
-    identical payloads, so the scatter stays deterministic — bounding the
-    jit cache at O(log n_slots) entries.
+    re-slices) with batched scatters. slot_idx: (N,) distinct slots; w_*:
+    the N experts' weights, each a sequence of N arrays — the host store's
+    pinned per-expert arrays (see `HostExpertStore.experts`), numpy arrays,
+    or a stacked (N, d, f) / (N, f, d) array. Pinned arrays go to the
+    program as they are, and the program copies them to the device (see
+    `_swap_in_many`), so the calling thread neither copies nor waits on the
+    transfer. (A numpy source is still read through a staging copy on the
+    calling thread when the program is dispatched.) N is split into
+    powers of two (7 -> 4 + 2 + 1), one dispatch each, which bounds the jit
+    cache at O(log n_slots) entries with no padding entry transferred.
     """
     idx = np.asarray(slot_idx, np.int32)
     n = idx.shape[0]
     assert n > 0, "swap_in_many needs at least one expert"
-    pad = _next_pow2(n) - n
-    idx = np.concatenate([idx, np.full((pad,), idx[-1], np.int32)])
-    ws = []
-    for w in (w_gate, w_up, w_down):
-        w = jax.device_put(list(w))
-        assert len(w) == n, "one weight per slot"
-        ws.append(tuple(w + w[-1:] * pad))
-    return _swap_in_many(slots, jnp.asarray(idx), *ws)
+    ws = [list(w) for w in (w_gate, w_up, w_down)]
+    assert all(len(w) == n for w in ws), "one weight per slot"
+    start = 0
+    for size in _pow2_parts(n):
+        part = slice(start, start + size)
+        slots = _swap_in_many(slots, jnp.asarray(idx[part]),
+                              *(tuple(w[part]) for w in ws))
+        start += size
+    return slots
 
 
 class HostExpertStore:
-    """Pre-staged contiguous host copies of every MoE layer's expert weights.
+    """Every MoE layer's routed experts, one pinned host array per (layer,
+    expert, weight), which the first device (the slot pool's) reads by DMA.
 
-    Built once, before the engine; `experts` hands out per-expert views —
-    the swap path never re-slices a device param tree."""
+    Built once, before the engine; `experts` hands out those arrays by
+    reference — the swap path neither slices nor copies on the host."""
 
     def __init__(self):
-        self._layers: Dict[int, Tuple[np.ndarray, np.ndarray, np.ndarray]] = {}
+        self._pinned = SingleDeviceSharding(jax.devices()[0],
+                                            memory_kind=PINNED)
+        self._layers: Dict[int, List[Tuple[jax.Array, ...]]] = {}
 
     def add_layer(self, layer: int, w_gate, w_up, w_down) -> None:
-        self._layers[layer] = tuple(
-            np.ascontiguousarray(np.asarray(w))
-            for w in (w_gate, w_up, w_down))
+        """Take one layer's (E, d, f), (E, d, f), (E, f, d) experts, device
+        or numpy arrays: sliced on their own side, each expert put to pinned
+        host memory. Waits for the copies, so a device source's slices are
+        freed before the caller builds the next layer."""
+        per_weight = [jax.device_put(list(w), self._pinned)
+                      for w in (w_gate, w_up, w_down)]
+        jax.block_until_ready(per_weight)
+        self._layers[layer] = list(zip(*per_weight))
 
     def __len__(self) -> int:
         return len(self._layers)
 
-    def layer(self, layer: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """All of one layer's experts (w_gate, w_up, w_down), uncopied."""
-        return self._layers[layer]
+    def layer_ids(self) -> List[int]:
+        return sorted(self._layers)
 
-    def experts(self, keys) -> List[Tuple[np.ndarray, np.ndarray, np.ndarray]]:
+    def layer(self, layer: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """All of one layer's experts (w_gate, w_up, w_down), stacked into
+        fresh numpy arrays: a host copy, for paths off the serving loop."""
+        return tuple(np.stack([np.asarray(w) for w in ws])
+                     for ws in zip(*self._layers[layer]))
+
+    def experts(self, keys) -> List[Tuple[jax.Array, jax.Array, jax.Array]]:
         """(w_gate, w_up, w_down) of each (layer, expert) key, in any layer
-        order: contiguous views into the store, uncopied. This is what lets
-        the multi-layer prefetch horizon fan speculative fills across layers
-        l+1..l+S into one batched device swap (`swap_in_many`)."""
-        return [tuple(w[e] for w in self._layers[li]) for li, e in keys]
+        order: the stored pinned arrays themselves, with no computation.
+        This is what lets the multi-layer prefetch horizon fan speculative
+        fills across layers l+1..l+S into one batched device swap
+        (`swap_in_many`)."""
+        return [self._layers[li][e] for li, e in keys]
 
 
 class SlotTable:

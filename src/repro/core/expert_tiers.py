@@ -71,14 +71,17 @@ class ShardError(ValueError):
 
 
 # ---------------------------------------------------------------- writer
-def _layer_map(params: Any) -> Mapping[int, Tuple[Any, Any, Any]]:
-    """Accept a `HostExpertStore` or a {layer: (wg, wu, wd)} mapping."""
-    layers = getattr(params, "_layers", params)
-    if not isinstance(layers, Mapping) or not layers:
+def _layer_map(params: Any) -> Tuple[List[int], Callable[[int], Any]]:
+    """Accept a `HostExpertStore` or a {layer: (wg, wu, wd)} mapping:
+    (layer ids, one layer's (wg, wu, wd) by id). A store's layer is
+    stacked only when it is asked for."""
+    if hasattr(params, "layer_ids"):
+        return params.layer_ids(), params.layer
+    if not isinstance(params, Mapping) or not params:
         raise ValueError(
             "export_expert_shards wants a HostExpertStore or a non-empty "
             "{moe_layer_index: (w_gate, w_up, w_down)} mapping")
-    return layers
+    return sorted(params), params.__getitem__
 
 
 def export_expert_shards(params: Any, out_dir: str) -> str:
@@ -86,14 +89,15 @@ def export_expert_shards(params: Any, out_dir: str) -> str:
 
     Atomic: everything lands in a temp directory first, then one
     ``os.replace``. Returns the final directory path."""
-    layers = _layer_map(params)
+    layer_ids, layer_weights = _layer_map(params)
     out = pathlib.Path(out_dir)
     out.parent.mkdir(parents=True, exist_ok=True)
     tmp = pathlib.Path(tempfile.mkdtemp(dir=out.parent,
                                         prefix=".tmp_shards_"))
     manifest: Dict[str, Any] = {"version": SHARD_VERSION, "layers": []}
-    for layer in sorted(layers):
-        ws = [np.ascontiguousarray(np.asarray(w)) for w in layers[layer]]
+    for layer in layer_ids:
+        ws = [np.ascontiguousarray(np.asarray(w))
+              for w in layer_weights(layer)]
         if len(ws) != len(TENSOR_NAMES):
             raise ValueError(f"layer {layer}: expected {TENSOR_NAMES}")
         n_experts = ws[0].shape[0]

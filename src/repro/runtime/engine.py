@@ -38,8 +38,8 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.cache import TwoLevelLRU
 from repro.core.cache_aware import residency_logit_bias
-from repro.core.expert_buffer import (HostExpertStore, SlotTable, make_buffer,
-                                      swap_in, swap_in_many)
+from repro.core.expert_buffer import (HostExpertStore, SlotTable, is_pinned,
+                                      make_buffer, swap_in, swap_in_many)
 from repro.core.faults import FaultInjector, FaultPlan, StepWatchdog
 from repro.core.prefetcher import Prefetcher, TransferLink
 from repro.core.step_size import StepSizeController
@@ -114,7 +114,7 @@ def init_serving_params(model: Model, key) -> ServingParams:
     """`ServingParams` built layer by layer, equal to
     `split_params(model, model.init(key))` but with at most one layer's
     routed experts on the device at a time: each is generated there and
-    pulled into the host store before the next."""
+    copied into the pinned host store before the next."""
     cfg, dtype = model.cfg, model.dtype
     fns: Dict[Any, Any] = {}
 
@@ -341,6 +341,7 @@ class SlotPathStats:
     """Per-engine counters for the slot-path benchmark."""
     swap_calls: int = 0        # device swap dispatches (batched or per-expert)
     swap_experts: int = 0      # experts actually transferred
+    swap_pinned_experts: int = 0  # of those, written from pinned host memory
     prefetched: int = 0        # experts transferred ahead of demand
     prefetch_hits: int = 0     # prefetched experts later demanded
     late_hits: int = 0         # prefetch hits the link model says arrived late
@@ -450,8 +451,9 @@ class SlotBufferEngine:
       and one `ffn` dispatch per MoE layer;
     - routing stays on device; only a (2, E) bool needed/predicted mask is
       pulled to host per MoE layer;
-    - ALL missing experts of a layer swap in through ONE batched donated
-      write fed from pre-staged contiguous host views (`HostExpertStore`);
+    - ALL missing experts of a layer swap in through batched donated
+      writes (one per power of two in their count) that copy them from the
+      pinned host store (`HostExpertStore`) themselves;
     - predicted next-layer experts (pre-gating the next router on the
       current hidden state) are issued BEFORE the current layer's FFN is
       dispatched, so JAX async dispatch overlaps the transfer with compute;
@@ -516,7 +518,7 @@ class SlotBufferEngine:
         self._dispatch = Dispatcher(self.stats)
         # per-absolute-layer params (routed experts stripped)
         self._p = params.layers
-        # expert weight source: pre-staged contiguous host views by default,
+        # expert weight source: the pinned host store by default,
         # or a caller-supplied TieredExpertStore (core.expert_tiers) whose
         # host residency the demand/prefetch paths must guarantee first
         if store is None:
@@ -1249,20 +1251,27 @@ class SlotBufferEngine:
         SWAP_CHUNK experts each: that bounds the device transfer buffers (a
         whole prefetch window of olmoe-1b-7b experts would be gigabytes).
 
-        `device_put` returns before its transfer ends, so a window's host
-        time is an enqueue time, not a transfer rate. One window in
+        From the pinned host store each write is a program that copies its
+        experts to the device itself (`swap_in_many`), so a window's host
+        time is a dispatch time, not a transfer rate (an unpinned numpy
+        source, e.g. a `TieredExpertStore`'s, is still staged on this
+        thread first). One window in
         BANDWIDTH_SAMPLE_EVERY feeds the controller's C_s instead: it
         starts once earlier writes to the pool have landed (untimed) and is
         timed until its last write has landed. Compute still reading the
         pool delays that write too, so a sample errs slow, never fast.
-        The window is a `swap.write` span; a sampled one holds the
+        The window is a `swap.write` span (arg `pinned`: the experts
+        written from pinned host memory); a sampled one holds the
         `swap.drain` and `swap.sample_wait` spans in which the host waits
         and dispatches nothing."""
         sample = self._swap_windows % BANDWIDTH_SAMPLE_EVERY == 0
         self._swap_windows += 1
         nbytes = len(slots) * self._expert_nbytes
+        ws = self.store.experts(keys)
+        pinned = sum(all(map(is_pinned, w)) for w in ws)
+        self.stats.swap_pinned_experts += pinned
         with span("swap.write", experts=len(keys), bytes=int(nbytes),
-                  sampled=int(sample)):
+                  sampled=int(sample), pinned=pinned):
             if sample:
                 with span("swap.drain"):
                     jax.block_until_ready(self.buffer)
@@ -1270,7 +1279,7 @@ class SlotBufferEngine:
             for i in range(0, len(keys), SWAP_CHUNK):
                 self.buffer = swap_in_many(
                     self.buffer, slots[i:i + SWAP_CHUNK],
-                    *zip(*self.store.experts(keys[i:i + SWAP_CHUNK])))
+                    *zip(*ws[i:i + SWAP_CHUNK]))
                 self.stats.swap_calls += 1
             if sample:
                 with span("swap.sample_wait"):
@@ -1825,15 +1834,16 @@ class SlotBufferEngine:
             """ONE blocking pull of the window's accumulated needed masks
             (+ optional sync-layer rows), then verification. On success the
             window commits (pending/ckpt clear); returns (sync_rows, -1).
-            On mispredict returns (stale rows, fail index)."""
-            mats = []
-            if pending:
-                mats.append(jnp.stack([p[2] for p in pending]))
+            On mispredict returns (stale rows, fail index). The masks are
+            copied to the host together and joined there: joining them on
+            the device would compile once per pair of row counts."""
+            mats = [p[2] for p in pending]
             if extra is not None:
                 mats.append(extra)
-            stacked = mats[0] if len(mats) == 1 else jnp.concatenate(mats, 0)
-            with span("engine.mask_pull", layer=li, rows=stacked.shape[0]):
-                masks_h = np.asarray(stacked)
+            rows = len(pending) + (0 if extra is None else extra.shape[0])
+            with span("engine.mask_pull", layer=li, rows=rows):
+                masks_h = np.concatenate(
+                    [np.atleast_2d(m) for m in jax.device_get(mats)], 0)
             self.stats.host_syncs += 1
             npend = len(pending)
             fail = verify(masks_h[:npend])
@@ -2345,8 +2355,10 @@ class SlotBufferEngine:
             if victim is not None:
                 self.table.release(*victim)
             slot = self.table.assign(li, int(e))
-            wg, wu, wd = (w[int(e)] for w in self.experts.layer(li))
-            self.buffer = swap_in(self.buffer, slot, wg, wu, wd)
+            ws = self.experts.experts([key])[0]
+            self.stats.swap_pinned_experts += all(map(is_pinned, ws))
+            self.buffer = swap_in(self.buffer, slot, *jax.device_put(
+                ws, self.buffer["w_gate"].sharding))
             self.stats.swap_calls += 1
             self.stats.swap_experts += 1
             swaps += 1

@@ -16,6 +16,7 @@ import pytest
 from repro.configs.base import reduce_config
 from repro.configs.registry import get_config, get_smoke_config
 from repro.core.expert_buffer import HostExpertStore
+from repro.core.expert_tiers import TieredExpertStore, export_expert_shards
 from repro.core.prefetcher import Prefetcher, TransferLink
 from repro.core.step_size import StepSizeConfig, StepSizeController
 from repro.runtime.engine import Engine, SlotBufferEngine
@@ -144,9 +145,11 @@ def test_decode_replay_path_exercised_and_exact(decode_setup):
 
 
 @pytest.mark.slow
-def test_generate_greedy_tokens_match_engine(decode_setup):
+def test_generate_greedy_tokens_match_engine(decode_setup, tmp_path):
     """SlotBufferEngine.generate greedy continuation == Engine.generate on
-    the same params, across slot-buffer sizes that force eviction churn."""
+    the same params, across slot-buffer sizes that force eviction churn;
+    every swap is written from pinned host memory, and none is from a
+    disk-backed store's numpy arrays, which serve the same tokens."""
     cfg, eng, prompt = decode_setup
     ref, _, _ = eng.generate(prompt, n_steps=6)
     E = cfg.moe.num_experts
@@ -154,11 +157,17 @@ def test_generate_greedy_tokens_match_engine(decode_setup):
         sb = _slot_engine(cfg, eng, n_slots_per_layer=spl)
         got = sb.generate(prompt, 6)
         np.testing.assert_array_equal(got, ref)
+        assert sb.stats.swap_pinned_experts == sb.stats.swap_experts > 0
         if spl < E:
             assert sb.cache.stats.evictions > 0
         # and the slot path agrees with its own fully-resident oracle
         np.testing.assert_array_equal(sb.generate(prompt, 6, reference=True),
                                       ref)
+    tiered = TieredExpertStore(
+        export_expert_shards(sb.experts, str(tmp_path / "shards")))
+    sb = _slot_engine(cfg, eng, n_slots_per_layer=E // 2, store=tiered)
+    np.testing.assert_array_equal(sb.generate(prompt, 6), ref)
+    assert sb.stats.swap_experts > 0 and sb.stats.swap_pinned_experts == 0
 
 
 @pytest.mark.slow

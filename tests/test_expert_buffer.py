@@ -1,11 +1,14 @@
-"""SlotTable invariants + batched swap_in_many vs sequential swap_in."""
+"""SlotTable invariants, the pinned host store, and batched swap_in_many
+vs sequential swap_in."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.registry import get_smoke_config
-from repro.core.expert_buffer import (HostExpertStore, SlotTable, make_buffer,
-                                      swap_in, swap_in_many)
+from repro.core.expert_buffer import (HostExpertStore, SlotTable,
+                                      _swap_in_many, make_buffer, swap_in,
+                                      swap_in_many)
 
 
 # ---------------------------------------------------------------------------
@@ -61,8 +64,22 @@ def test_slot_table_layer_isolation():
 # swap_in_many == sequential swap_in (bitwise)
 # ---------------------------------------------------------------------------
 
+def _sources(kind, wg, wu, wd):
+    """The experts of (N, d, f) / (N, f, d) stacks as `swap_in_many` gets
+    them: the device stacks themselves, numpy stacks, or the pinned host
+    store's per-expert arrays."""
+    if kind == "device":
+        return wg, wu, wd
+    if kind == "numpy":
+        return tuple(np.asarray(w) for w in (wg, wu, wd))
+    store = HostExpertStore()
+    store.add_layer(0, wg, wu, wd)
+    return tuple(zip(*store.experts([(0, e) for e in range(wg.shape[0])])))
+
+
+@pytest.mark.parametrize("source", ["device", "numpy", "pinned"])
 @pytest.mark.parametrize("n_moved", [1, 3, 4, 7])
-def test_swap_in_many_matches_sequential(n_moved):
+def test_swap_in_many_matches_sequential(n_moved, source):
     cfg = get_smoke_config("olmoe-1b-7b")
     d, f = cfg.d_model, cfg.moe.d_expert
     n_slots = 8
@@ -75,8 +92,11 @@ def test_swap_in_many_matches_sequential(n_moved):
     seq = make_buffer(cfg, n_slots)
     for i, s in enumerate(slots):
         seq = swap_in(seq, int(s), wg[i], wu[i], wd[i])
-    batched = swap_in_many(make_buffer(cfg, n_slots), slots, wg, wu, wd)
+    batched = swap_in_many(make_buffer(cfg, n_slots), slots,
+                           *_sources(source, wg, wu, wd))
     for k in ("w_gate", "w_up", "w_down"):
+        assert batched[k].dtype == jnp.bfloat16
+        assert batched[k].sharding.memory_kind == "device"
         np.testing.assert_array_equal(np.asarray(seq[k], np.float32),
                                       np.asarray(batched[k], np.float32))
 
@@ -96,17 +116,86 @@ def test_swap_in_many_overwrites_previous_occupant():
                                   np.asarray(new[0], np.float32))
 
 
+def _layer(rng, E=6, d=8, f=4):
+    return (jnp.asarray(rng.standard_normal((E, d, f)), jnp.bfloat16),
+            jnp.asarray(rng.standard_normal((E, d, f)), jnp.bfloat16),
+            jnp.asarray(rng.standard_normal((E, f, d)), jnp.bfloat16))
+
+
 def test_host_expert_store_gathers_contiguous_views():
-    rng = np.random.default_rng(3)
-    E, d, f = 6, 8, 4
-    wg = jnp.asarray(rng.standard_normal((E, d, f)), jnp.bfloat16)
-    wu = jnp.asarray(rng.standard_normal((E, d, f)), jnp.bfloat16)
-    wd = jnp.asarray(rng.standard_normal((E, f, d)), jnp.bfloat16)
+    wg, wu, wd = _layer(np.random.default_rng(3))
     store = HostExpertStore()
     store.add_layer(0, wg, wu, wd)
-    for e, (g_wg, g_wu, g_wd) in zip((4, 1), store.experts([(0, 4), (0, 1)])):
-        assert isinstance(g_wg, np.ndarray) and g_wg.flags["C_CONTIGUOUS"]
+    keys = [(0, 4), (0, 1)]
+    got = store.experts(keys)
+    for e, (g_wg, g_wu, g_wd), again in zip((4, 1), got, store.experts(keys)):
+        # handed out by reference: the same stored arrays on every call
+        assert all(a is b for a, b in zip((g_wg, g_wu, g_wd), again))
         np.testing.assert_array_equal(np.asarray(g_wg, np.float32),
                                       np.asarray(wg[e], np.float32))
         np.testing.assert_array_equal(np.asarray(g_wd, np.float32),
                                       np.asarray(wd[e], np.float32))
+
+
+@pytest.mark.parametrize("source", ["device", "numpy"])
+def test_host_store_holds_each_expert_in_pinned_host_memory(source):
+    ws = _layer(np.random.default_rng(4))
+    if source == "numpy":
+        ws = tuple(np.asarray(w) for w in ws)
+    store = HostExpertStore()
+    store.add_layer(0, *ws)
+    for e, got in enumerate(store.experts([(0, e) for e in range(6)])):
+        for g, w in zip(got, ws):
+            assert g.sharding.memory_kind == "pinned_host"
+            assert g.shape == w.shape[1:] and g.dtype == jnp.bfloat16
+    # the stacked layer (reference and export paths) is the source, bitwise
+    for g, w in zip(store.layer(0), ws):
+        assert isinstance(g, np.ndarray)
+        np.testing.assert_array_equal(g.view(np.uint16),
+                                      np.asarray(w).view(np.uint16))
+
+
+def test_host_store_expert_lookup_compiles_and_dispatches_nothing():
+    store = HostExpertStore()
+    for li in range(2):
+        store.add_layer(li, *_layer(np.random.default_rng(li)))
+    keys = [(1, 5), (0, 0), (1, 2), (0, 5)]
+    first = store.experts(keys)
+    compiles = []
+
+    def on_duration(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        with jax.transfer_guard("disallow"):
+            got = store.experts(keys)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert compiles == []
+    # nothing computed: every array handed out is the one stored
+    assert all(a is b for ga, fa in zip(got, first) for a, b in zip(ga, fa))
+
+
+def test_swap_sizes_warmed_as_powers_of_two_cover_every_write_size():
+    """The benchmark's warm-up writes 1, 2, 4 ... 32 experts once; after
+    that no write of 1..32 experts may add a compiled shape."""
+    rng = np.random.default_rng(5)
+    E = 32
+    buf = make_buffer(get_smoke_config("olmoe-1b-7b"), E)
+    d, f = buf["w_gate"].shape[1:]
+    store = HostExpertStore()
+    store.add_layer(0, *_layer(rng, E, d, f))
+
+    def write(n):
+        slots = rng.permutation(E)[:n]
+        return swap_in_many(buf, slots, *zip(*store.experts(
+            [(0, int(e)) for e in rng.permutation(E)[:n]])))
+    m = 1
+    while m <= 32:
+        buf = write(m)
+        m *= 2
+    warmed = _swap_in_many._cache_size()
+    for n in range(1, 33):
+        buf = write(n)
+        assert _swap_in_many._cache_size() == warmed, n

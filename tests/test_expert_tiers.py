@@ -224,7 +224,7 @@ def test_disk_prefetch_converts_misses_to_hits():
 
 
 # --------------------------------------------------------------------------
-# per-expert host views: what swap_in_many sends, with no host copy
+# per-expert pinned arrays: what swap_in_many sends, with no host copy
 # --------------------------------------------------------------------------
 
 def test_host_store_experts_are_uncopied_views_bit_exact():
@@ -233,9 +233,10 @@ def test_host_store_experts_are_uncopied_views_bit_exact():
         st = _store(rng, layers=3, experts=8, dtype=dtype)
         keys = [(0, 3), (0, 5), (1, 1), (2, 7), (2, 0)]
         for (li, e), ws in zip(keys, st.experts(keys)):
-            for w, whole in zip(ws, st.layer(li)):
+            again = st.experts([(li, e)])[0]
+            for w, w2, whole in zip(ws, again, st.layer(li)):
                 np.testing.assert_array_equal(_bits(w), _bits(whole[e]))
-                assert np.shares_memory(w, whole) and w.flags.c_contiguous
+                assert w is w2 and w.sharding.memory_kind == "pinned_host"
 
 
 # --------------------------------------------------------------------------

@@ -105,6 +105,7 @@ def test_serve_writes_every_span_nested_where_the_work_happens(model, tmp_path):
         "moe_ffn_decode", "moe_ffn_chunk", "pre_decode_batched"}
     writes = [s[3] for s in spans if s[0] == "swap.write"]
     assert sum(w["experts"] for w in writes) == sb.stats.swap_experts
+    assert sum(w["pinned"] for w in writes) == sb.stats.swap_experts
     assert sum(w["sampled"] for w in writes) == len(
         [s for s in spans if s[0] == "swap.sample_wait"])
 
