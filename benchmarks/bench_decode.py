@@ -60,11 +60,10 @@ def _max_seq(p):
     return p["prompt"] + p["warmup"] + p["repeats"] * p["steps"] + 8
 
 
-def _engine(cfg, model, params, p, step_size=None, use_superkernel=False):
+def _engine(cfg, model, params, p, step_size=None):
     return SlotBufferEngine(cfg, params, model,
                             n_slots_per_layer=p["n_slots_per_layer"],
-                            max_seq=_max_seq(p), step_size=step_size,
-                            use_superkernel=use_superkernel)
+                            max_seq=_max_seq(p), step_size=step_size)
 
 
 def bench_full_forward(cfg, model, params, p) -> dict:
@@ -104,12 +103,10 @@ def bench_full_forward(cfg, model, params, p) -> dict:
     }
 
 
-def bench_decode(cfg, model, params, p, step_size,
-                 use_superkernel=False) -> dict:
+def bench_decode(cfg, model, params, p, step_size) -> dict:
     """prefill() once, then `repeats` measured windows of KV-cached
     decode_step()s (best window reported; counters span all windows)."""
-    sb = _engine(cfg, model, params, p, step_size=step_size,
-                 use_superkernel=use_superkernel)
+    sb = _engine(cfg, model, params, p, step_size=step_size)
     logits, state = sb.prefill(_prompt(p))
     tok = jnp.argmax(logits, -1).astype(jnp.int32)
     for _ in range(p["warmup"]):
@@ -146,21 +143,6 @@ def bench_decode(cfg, model, params, p, step_size,
     return out
 
 
-def check_superkernel_token_parity(cfg, model, params, p) -> bool:
-    """Eviction-churn config: the segment-fused superkernel path must emit
-    greedy tokens IDENTICAL to the fully-resident einsum oracle (replays and
-    hinted re-dispatches included)."""
-    churn = dict(p, n_slots_per_layer=max(2, p["experts"] // 3))
-    prompt = _prompt(p)
-    oracle = _engine(cfg, model, params, churn, step_size=2)
-    want = np.asarray(oracle.generate(prompt, min(p["steps"], 8),
-                                      reference=True))
-    sk = _engine(cfg, model, params, churn, step_size=2,
-                 use_superkernel=True)
-    got = np.asarray(sk.generate(prompt, min(p["steps"], 8)))
-    return bool(np.array_equal(got, want))
-
-
 def check_oracle_bitexact(cfg, model, params, p) -> bool:
     """Eviction-churn config (slots << experts): per-step decode logits must
     match the fully-resident oracle bitwise, replays included."""
@@ -187,41 +169,24 @@ def bench(p) -> dict:
     params = model.init(jax.random.PRNGKey(0))
     full = bench_full_forward(cfg, model, params, p)
     decode = {}
-    superkernel = {}
     for s in p["horizons"]:
         decode[f"S={s}"] = bench_decode(cfg, model, params, p, step_size=s)
-        superkernel[f"S={s}"] = bench_decode(cfg, model, params, p,
-                                             step_size=s,
-                                             use_superkernel=True)
     decode["adaptive"] = bench_decode(cfg, model, params, p, step_size=None)
-    superkernel["adaptive"] = bench_decode(cfg, model, params, p,
-                                           step_size=None,
-                                           use_superkernel=True)
     best = max(v["tokens_per_s"] for v in decode.values())
     s_ref = f"S={p['horizons'][-1]}"
-    s2 = f"S={[s for s in p['horizons'] if s >= 2][0]}"
     report = {
         "config": {k: v for k, v in p.items() if k != "horizons"},
         "n_moe_layers": p["layers"],
         "full_forward": full,
         "decode": decode,
-        "superkernel": superkernel,
         "ratios": {
             "decode_speedup_vs_full_forward":
                 best / max(full["tokens_per_s"], 1e-9),
             "host_sync_reduction_vs_per_layer":
                 p["layers"] / max(decode[s_ref]["host_syncs_per_step"], 1e-9),
-            "superkernel_dispatch_reduction":
-                decode[s2]["jit_calls_per_step"]
-                / max(superkernel[s2]["jit_calls_per_step"], 1e-9),
-            "superkernel_tokens_vs_unfused":
-                superkernel[s2]["tokens_per_s"]
-                / max(decode[s2]["tokens_per_s"], 1e-9),
         },
         "oracle_bitexact_under_churn":
             check_oracle_bitexact(cfg, model, params, p),
-        "superkernel_token_parity_under_churn":
-            check_superkernel_token_parity(cfg, model, params, p),
     }
     return report
 
@@ -237,16 +202,10 @@ def run(csv) -> None:
                 f"{r['tokens_per_s']:.1f}tok/s,"
                 f"{r['host_syncs_per_step']:.2f}syncs,"
                 f"{r['replays_per_step']:.2f}replays")
-    for name, r in report["superkernel"].items():
-        csv.add(f"decode/superkernel/{name}/step", r["wall_s_per_step"] * 1e6,
-                f"{r['tokens_per_s']:.1f}tok/s,"
-                f"{r['jit_calls_per_step']:.2f}jit,"
-                f"{r['replays_per_step']:.2f}replays")
     rt = report["ratios"]
     csv.add("decode/ratios", 0.0,
             f"{rt['decode_speedup_vs_full_forward']:.2f}x_tokens_per_s,"
             f"{rt['host_sync_reduction_vs_per_layer']:.1f}x_fewer_syncs,"
-            f"{rt['superkernel_dispatch_reduction']:.2f}x_fewer_dispatches,"
             f"bitexact={report['oracle_bitexact_under_churn']}")
 
 
@@ -267,8 +226,6 @@ def main() -> None:
             f.write("\n")
     assert report["oracle_bitexact_under_churn"], \
         "slot-path decode diverged from the fully-resident oracle"
-    assert report["superkernel_token_parity_under_churn"], \
-        "superkernel decode tokens diverged from the einsum oracle"
     if args.smoke:
         n_moe = report["n_moe_layers"]
         s2 = report["decode"]["S=2"]
@@ -285,16 +242,9 @@ def main() -> None:
         assert s2["host_syncs_per_step"] < n_moe, (
             "speculative horizon no longer collapses host syncs: "
             f"{s2['host_syncs_per_step']:.2f}/step vs {n_moe} MoE layers")
-        # deterministic counter gate: the decode superkernel must keep
-        # halving warm jitted dispatches per step vs the unfused path
-        skr = report["ratios"]["superkernel_dispatch_reduction"]
-        assert skr >= 2.0, (
-            "decode superkernel no longer halves dispatches/step: "
-            f"only {skr:.2f}x vs the unfused slot path")
         print(f"# smoke OK: {speedup:.2f}x tokens/s over full forward, "
               f"{s2['host_syncs_per_step']:.2f} host syncs/step "
-              f"({n_moe} MoE layers), superkernel {skr:.2f}x fewer "
-              "dispatches")
+              f"({n_moe} MoE layers)")
 
 
 if __name__ == "__main__":

@@ -4,8 +4,6 @@ kernels' arithmetic intensity and VMEM working set per BlockSpec tile,
 the quantities that determine MXU utilization on the target."""
 from __future__ import annotations
 
-import numpy as np
-
 from benchmarks.common import Csv
 
 
@@ -13,31 +11,6 @@ def ffn_tile_stats(D: int, F: int, bc: int, bf: int, dtype_bytes: int = 2):
     flops = 2 * bc * D * bf * 3          # gate+up+down matmuls
     vmem = (bc * D + 2 * D * bf + bf * D + bc * D) * dtype_bytes
     hbm = (bc * D + 3 * D * bf) * dtype_bytes + bc * D * 4
-    return flops, vmem, flops / hbm
-
-
-def fused_moe_tile_stats(T: int, E: int, D: int, F: int,
-                         dtype_bytes: int = 2):
-    """Per grid step (one resident expert slot) of the decode-superkernel
-    MoE entry: router logits + top-k are recomputed each step (cheap, keeps
-    the kernel single-pass) and the expert FFN runs over all T decode rows
-    with gate-weighted accumulation into the fp32 output ref."""
-    flops = 2 * T * D * E + 2 * T * D * F * 3 + T * F
-    vmem = (T * D + D * E + 3 * D * F + 2 * T * D) * dtype_bytes \
-        + T * D * 4                                   # fp32 accumulator
-    hbm = (3 * D * F) * dtype_bytes + (T * D * 4) / E  # weights dominate
-    return flops, vmem, flops / hbm
-
-
-def decode_attn_row_stats(S: int, Hq: int, Hkv: int, D: int,
-                          block_s: int, dtype_bytes: int = 2):
-    """Per grid step (one batch row) of the fused single-token attention:
-    ring K/V insert + online-softmax over ceil(S/block_s) chunks, reading
-    only chunks below the row's cache_len."""
-    flops = 2 * Hq * D * S * 2 + 3 * Hq * S
-    vmem = (Hq * D + 2 * block_s * Hkv * D + Hq * D) * dtype_bytes \
-        + Hq * D * 4
-    hbm = (2 * S * Hkv * D + 2 * Hq * D) * dtype_bytes
     return flops, vmem, flops / hbm
 
 
@@ -59,36 +32,6 @@ def run(csv: Csv) -> dict:
         t_hbm = (vmem) / 819e9
         out[name] = ai
         csv.add(f"kernels/moe_gemm/{name}", t_mxu * 1e6,
-                f"ai={ai:.1f}flops/B;vmem_tile={vmem/2**20:.2f}MiB;"
-                f"fits_vmem={fits};mxu_bound={t_mxu > t_hbm}")
-    # decode superkernel: fused MoE entry at serving batch sizes (T = batch
-    # rows in single-token decode) and fused decode attention per row
-    moe_cases = [
-        ("fused_moe_b4_olmoe", 4, 64, 2048, 1024),
-        ("fused_moe_b32_olmoe", 32, 64, 2048, 1024),
-        ("fused_moe_b32_qwen2moe", 32, 60, 3584, 2560),
-    ]
-    for name, T, E, D, F in moe_cases:
-        flops, vmem, ai = fused_moe_tile_stats(T, E, D, F)
-        fits = vmem < 8 * 2**20
-        t_mxu = flops / 197e12
-        t_hbm = vmem / 819e9
-        out[name] = ai
-        csv.add(f"kernels/decode_superkernel/{name}", t_mxu * 1e6,
-                f"ai={ai:.1f}flops/B;vmem_tile={vmem/2**20:.2f}MiB;"
-                f"fits_vmem={fits};mxu_bound={t_mxu > t_hbm}")
-    attn_cases = [
-        ("fused_attn_s1k_gqa", 1024, 32, 8, 128, 128),
-        ("fused_attn_s4k_gqa", 4096, 32, 8, 128, 256),
-        ("fused_attn_s4k_mha", 4096, 32, 32, 128, 256),
-    ]
-    for name, S, Hq, Hkv, D, bs in attn_cases:
-        flops, vmem, ai = decode_attn_row_stats(S, Hq, Hkv, D, bs)
-        fits = vmem < 8 * 2**20
-        t_mxu = flops / 197e12
-        t_hbm = vmem / 819e9
-        out[name] = ai
-        csv.add(f"kernels/decode_superkernel/{name}", t_mxu * 1e6,
                 f"ai={ai:.1f}flops/B;vmem_tile={vmem/2**20:.2f}MiB;"
                 f"fits_vmem={fits};mxu_bound={t_mxu > t_hbm}")
     return out

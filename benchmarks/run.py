@@ -19,8 +19,7 @@ def main() -> None:
     only = sys.argv[1] if len(sys.argv) > 1 else None
     from benchmarks import (bench_cache_aware, bench_decode, bench_faults,
                             bench_integrity, bench_prefill,
-                            bench_serving_engine,
-                            bench_slotpath, bench_tiers,
+                            bench_serving_engine, bench_tiers,
                             fig2_step_size, fig3_batch_size,
                             fig4_diversity, fig7_overall_latency,
                             fig8_predictor_accuracy, fig9_cache_miss,
@@ -31,8 +30,7 @@ def main() -> None:
         "fig4": fig4_diversity, "fig7": fig7_overall_latency,
         "fig8": fig8_predictor_accuracy, "fig9": fig9_cache_miss,
         "fig10": fig10_lru, "fig11": fig11_cache_aware_routing,
-        "serving": fig_serving, "slotpath": bench_slotpath,
-        "decode": bench_decode, "serving_engine": bench_serving_engine,
+        "serving": fig_serving, "decode": bench_decode, "serving_engine": bench_serving_engine,
         "prefill": bench_prefill, "cache_aware": bench_cache_aware,
         "faults": bench_faults, "tiers": bench_tiers,
         "kernels": kernels_bench, "roofline": roofline,

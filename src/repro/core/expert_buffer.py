@@ -8,8 +8,8 @@ purely functional: `swap_in_many` writes ALL of a layer's missing experts in
 a few jitted donated scatters. `HostExpertStore` keeps every routed expert
 in pinned host memory as a device-addressable array, and the scatter
 program copies its experts from there itself, so the host->HBM copy is an
-asynchronous DMA inside the program, not a copy on the calling thread
-(`swap_in` is the per-expert legacy form). The MoE layer computes through
+asynchronous DMA inside the program, not a copy on the calling thread.
+The MoE layer computes through
 `repro.models.moe.moe_slotbuf` using the indirection.
 """
 from __future__ import annotations
@@ -40,21 +40,6 @@ def make_buffer(cfg: ModelConfig, n_slots: int, dtype=jnp.bfloat16):
         "w_down": jnp.zeros((n_slots, f, d), dtype, device=device),
     }
     return slots
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def swap_in(slots: Dict[str, jnp.ndarray], slot_idx: jnp.ndarray,
-            w_gate: jnp.ndarray, w_up: jnp.ndarray, w_down: jnp.ndarray):
-    """Write one expert's weights into `slot_idx` (donated: in-place)."""
-    i = jnp.asarray(slot_idx, jnp.int32)
-    return {
-        "w_gate": jax.lax.dynamic_update_slice_in_dim(
-            slots["w_gate"], w_gate[None], i, axis=0),
-        "w_up": jax.lax.dynamic_update_slice_in_dim(
-            slots["w_up"], w_up[None], i, axis=0),
-        "w_down": jax.lax.dynamic_update_slice_in_dim(
-            slots["w_down"], w_down[None], i, axis=0),
-    }
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -89,9 +74,8 @@ def swap_in_many(slots: Dict[str, jnp.ndarray], slot_idx,
                  w_gate, w_up, w_down) -> Dict[str, jnp.ndarray]:
     """Write N experts' weights in at most log2(N)+1 donated dispatches.
 
-    Replaces N sequential `swap_in` calls (N dispatches, N param-tree
-    re-slices) with batched scatters. slot_idx: (N,) distinct slots; w_*:
-    the N experts' weights, each a sequence of N arrays — the host store's
+    slot_idx: (N,) distinct slots; w_*: the N experts' weights, each a
+    sequence of N arrays — the host store's
     pinned per-expert arrays (see `HostExpertStore.experts`), numpy arrays,
     or a stacked (N, d, f) / (N, f, d) array. Pinned arrays go to the
     program as they are, and the program copies them to the device (see

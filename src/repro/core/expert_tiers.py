@@ -303,7 +303,7 @@ class HostTierModel:
         self.disk_bandwidth = float(disk_bandwidth)
         self.controller = controller
         self.disk_horizon_max = int(disk_horizon_max)
-        self.prefetch_enabled = bool(prefetch)
+        self.prefetch_on = bool(prefetch)
         self.link = TransferLink(bandwidth=self.disk_bandwidth)
         self.pf = Prefetcher(self.link, self.expert_nbytes,
                              cancel_on_forget=True)
@@ -545,7 +545,7 @@ class HostTierModel:
         device predictor's forward-looking signal, and a newly-hot expert
         has no popularity history yet — exactly the case the prefetch
         window exists for."""
-        if not self.prefetch_enabled:
+        if not self.prefetch_on:
             return False
         if self.guard.is_quarantined(key):
             return False
@@ -655,7 +655,7 @@ class HostTierModel:
     def auto_prefetch(self, now: float, current_layer: int) -> int:
         """Issue popularity-ranked disk->host promotions for the next
         `disk_horizon()` layers. Returns the number issued."""
-        if not self.prefetch_enabled or self.L == 0:
+        if not self.prefetch_on or self.L == 0:
             return 0
         # settle what already completed so the issue-slot accounting sees
         # the real in-flight set, not promotions that landed layers ago

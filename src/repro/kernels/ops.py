@@ -9,8 +9,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.kernels import (decode_superkernel, moe_gemm, slot_gather,
-                           topk_gating)
+from repro.kernels import moe_gemm, slot_gather, topk_gating
 from repro.kernels import ref as ref_ops
 
 
@@ -47,39 +46,7 @@ def slot_ffn(x, slot_of_group, s_gate, s_up, s_down, *, block_c: int = 128,
                                 interpret=interpret)
 
 
-def fused_moe_entry(x, router_w, logit_bias, slot_of_expert, s_gate, s_up,
-                    s_down, *, top_k: int, norm_topk: bool = True,
-                    interpret=None):
-    if interpret is None:
-        interpret = _default_interpret()
-    return decode_superkernel.fused_moe_entry(
-        x, router_w, logit_bias, slot_of_expert, s_gate, s_up, s_down,
-        top_k=top_k, norm_topk=norm_topk, interpret=interpret)
-
-
-def fused_decode_attention(q, k_new, v_new, k_cache, v_cache, cache_len, *,
-                           logit_softcap: float = 0.0, scale=None,
-                           block_s: int = 128, interpret=None):
-    if interpret is None:
-        interpret = _default_interpret()
-    return decode_superkernel.fused_decode_attention(
-        q, k_new, v_new, k_cache, v_cache, cache_len,
-        logit_softcap=logit_softcap, scale=scale, block_s=block_s,
-        interpret=interpret)
-
-
-def fused_mla_decode_attention(q_abs, q_pe, c_new, pe_new, latent, pe,
-                               cache_len, *, scale: float, block_s: int = 128,
-                               interpret=None):
-    if interpret is None:
-        interpret = _default_interpret()
-    return decode_superkernel.fused_mla_decode_attention(
-        q_abs, q_pe, c_new, pe_new, latent, pe, cache_len, scale=scale,
-        block_s=block_s, interpret=interpret)
-
-
 # re-export oracles for tests/benchmarks
 expert_ffn_ref = ref_ops.expert_ffn_ref
 topk_ref = ref_ops.topk_gating_ref
 slot_ffn_ref = ref_ops.slot_ffn_ref
-fused_moe_entry_ref = ref_ops.fused_moe_entry_ref

@@ -78,33 +78,3 @@ def moe_slotbuf_einsum_ref(params, slot_weights, slot_of_expert, x, moe,
         s = params["shared"]
         out = out + swiglu(x, s["w_gate"], s["w_up"], s["w_down"])
     return out, r
-
-
-def fused_moe_entry_ref(x: jnp.ndarray, router_w: jnp.ndarray,
-                        logit_bias: jnp.ndarray,
-                        slot_of_expert: jnp.ndarray, s_gate: jnp.ndarray,
-                        s_up: jnp.ndarray, s_down: jnp.ndarray, *,
-                        top_k: int, norm_topk: bool = True):
-    """Oracle for the decode superkernel's fused MoE entry: route + top-k +
-    slot indirection (dead-sentinel miss rule) + gate-weighted expert FFN.
-
-    x: (T, d); router_w: (d, E); logit_bias: (E,) fp32 additive;
-    slot_of_expert: (E,) int32, -1 = non-resident. Returns
-    (y (T, d) float32, gates (T, top_k) float32, ids (T, top_k) int32).
-    """
-    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
-                        router_w.astype(jnp.float32))
-    logits = logits + logit_bias.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, ids = jax.lax.top_k(probs, top_k)
-    if norm_topk:
-        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-    slot_raw = slot_of_expert[ids]                              # (T, k)
-    gates = gates * (slot_raw >= 0).astype(gates.dtype)
-    slot = jnp.maximum(slot_raw, 0)
-    g = jnp.einsum("td,tkdf->tkf", x, s_gate[slot])
-    u = jnp.einsum("td,tkdf->tkf", x, s_up[slot])
-    h = (jax.nn.silu(g) * u).astype(x.dtype)
-    yk = jnp.einsum("tkf,tkfd->tkd", h, s_down[slot])
-    y = jnp.sum(gates[..., None] * yk.astype(jnp.float32), axis=1)
-    return y, gates, ids.astype(jnp.int32)

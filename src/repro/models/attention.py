@@ -8,7 +8,6 @@ structure a Pallas flash kernel would use on real TPU.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -228,14 +227,11 @@ def gqa_project_kv(params, x: jnp.ndarray, positions: jnp.ndarray,
 def gqa_decode(params, x: jnp.ndarray, k_cache, v_cache, cache_len, *,
                rope_theta: float, window: int = 0, logit_softcap: float = 0.0,
                scale: Optional[float] = None, norm_eps: float = 1e-6,
-               cross: bool = False, use_kernel: bool = False):
+               cross: bool = False):
     """One-token attention. x: (B, 1, d). Returns (out, k_cache, v_cache).
 
     For self-attention the new token's K/V is inserted at `cache_len`.
     For cross-attention (`cross=True`) the caches are read-only.
-    `use_kernel=True` routes insert + online-softmax attention through the
-    fused Pallas decode kernel (self-attention, full window only — cross and
-    sliding-window fall back to the masked einsum oracle).
     """
     B = x.shape[0]
     positions = jnp.broadcast_to(jnp.asarray(cache_len).reshape(-1, 1), (B, 1))
@@ -244,16 +240,6 @@ def gqa_decode(params, x: jnp.ndarray, k_cache, v_cache, cache_len, *,
         q = rms_norm(q, params["q_norm"], norm_eps)
     if rope_theta > 0 and not cross:
         q = rope(q, positions, rope_theta)
-    if use_kernel and not cross and window == 0:
-        from repro.kernels import ops as kernel_ops
-        k, v = gqa_project_kv(params, x, positions, rope_theta, norm_eps)
-        clen_b = jnp.broadcast_to(
-            jnp.asarray(cache_len, jnp.int32).reshape(-1), (B,))
-        out, k_cache, v_cache = kernel_ops.fused_decode_attention(
-            q, k, v, k_cache, v_cache, clen_b,
-            logit_softcap=logit_softcap, scale=scale)
-        out = jnp.einsum("bthk,hkd->btd", out, params["wo"])
-        return out, k_cache, v_cache
     if not cross:
         k, v = gqa_project_kv(params, x, positions, rope_theta, norm_eps)
         idx = jnp.asarray(cache_len, jnp.int32)
@@ -444,8 +430,7 @@ def mla_prefill_chunk(params, h: jnp.ndarray, positions: jnp.ndarray,
 
 
 def mla_decode(params, x: jnp.ndarray, latent_cache, pe_cache, cache_len, *,
-               mla, rope_theta: float, norm_eps: float = 1e-6,
-               use_kernel: bool = False):
+               mla, rope_theta: float, norm_eps: float = 1e-6):
     """MLA decode with compressed cache, WEIGHT-ABSORBED (DeepSeek-V2 trick).
 
     latent_cache: (B, S, kv_lora_rank); pe_cache: (B, S, 1, rope_dim).
@@ -467,16 +452,15 @@ def mla_decode(params, x: jnp.ndarray, latent_cache, pe_cache, cache_len, *,
     c_new = rms_norm(kv_a[..., :R], params["kv_a_norm"], norm_eps)
     pe_new = rope(kv_a[..., R:][..., None, :], positions, rope_theta)
     idx = jnp.asarray(cache_len, jnp.int32)
-    if not use_kernel:
-        if idx.ndim:                          # (B,): per-row cache positions
-            rows = jnp.arange(B)
-            latent_cache = latent_cache.at[rows, idx].set(c_new[:, 0])
-            pe_cache = pe_cache.at[rows, idx].set(pe_new[:, 0])
-        else:
-            latent_cache = jax.lax.dynamic_update_slice_in_dim(
-                latent_cache, c_new, idx, axis=1)
-            pe_cache = jax.lax.dynamic_update_slice_in_dim(pe_cache, pe_new,
-                                                           idx, axis=1)
+    if idx.ndim:                              # (B,): per-row cache positions
+        rows = jnp.arange(B)
+        latent_cache = latent_cache.at[rows, idx].set(c_new[:, 0])
+        pe_cache = pe_cache.at[rows, idx].set(pe_new[:, 0])
+    else:
+        latent_cache = jax.lax.dynamic_update_slice_in_dim(
+            latent_cache, c_new, idx, axis=1)
+        pe_cache = jax.lax.dynamic_update_slice_in_dim(pe_cache, pe_new,
+                                                       idx, axis=1)
 
     # query
     if "wq_a" in params:
@@ -496,28 +480,17 @@ def mla_decode(params, x: jnp.ndarray, latent_cache, pe_cache, cache_len, *,
     # contraction order would otherwise add bf16 rounding vs the prefill path)
     q_abs = jnp.einsum("bthk,rhk->bhr", q_nope.astype(jnp.float32),
                        wk.astype(jnp.float32))               # (B, H, R)
-    if use_kernel:
-        # fused Pallas path: the kernel inserts the new latent/pe row and
-        # attends up to each row's length with online softmax in one launch
-        from repro.kernels import ops as kernel_ops
-        clen_b = jnp.broadcast_to(idx.reshape(-1), (B,))
-        ctx, latent_cache, pe_sq = kernel_ops.fused_mla_decode_attention(
-            q_abs, q_pe[:, 0].astype(jnp.float32), c_new[:, 0],
-            pe_new[:, 0, 0], latent_cache, pe_cache[:, :, 0], clen_b,
-            scale=scale)
-        pe_cache = pe_sq[:, :, None]
-    else:
-        s_nope = jnp.einsum("bhr,bsr->bhs", q_abs,
-                            latent_cache.astype(jnp.float32))
-        s_pe = jnp.einsum("bthk,bsxk->bhs", q_pe.astype(jnp.float32),
-                          pe_cache.astype(jnp.float32))
-        s = (s_nope + s_pe) * scale
-        S = latent_cache.shape[1]
-        n_valid = (idx + 1).reshape(-1, 1) if idx.ndim else (idx + 1)
-        valid = jnp.arange(S)[None, :] < n_valid
-        s = jnp.where(valid[:, None, :], s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        ctx = jnp.einsum("bhs,bsr->bhr", p, latent_cache.astype(jnp.float32))
+    s_nope = jnp.einsum("bhr,bsr->bhs", q_abs,
+                        latent_cache.astype(jnp.float32))
+    s_pe = jnp.einsum("bthk,bsxk->bhs", q_pe.astype(jnp.float32),
+                      pe_cache.astype(jnp.float32))
+    s = (s_nope + s_pe) * scale
+    S = latent_cache.shape[1]
+    n_valid = (idx + 1).reshape(-1, 1) if idx.ndim else (idx + 1)
+    valid = jnp.arange(S)[None, :] < n_valid
+    s = jnp.where(valid[:, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    ctx = jnp.einsum("bhs,bsr->bhr", p, latent_cache.astype(jnp.float32))
     out = jnp.einsum("bhr,rhv->bhv", ctx, wv.astype(jnp.float32))
     out = jnp.einsum("bhv,hvd->bd", out,
                      params["wo"].astype(jnp.float32))[:, None, :]

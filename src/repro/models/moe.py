@@ -414,7 +414,7 @@ def moe_slotbuf(params, slot_weights, slot_of_expert: jnp.ndarray,
     the runtime guarantees residency before dispatch, so in normal operation
     there are none.
 
-    `router_out` skips re-routing when the caller already routed (the fused
+    `router_out` skips re-routing when the caller already routed (the
     engine routes on device first to learn the needed-expert set).
     Router weights / shared experts stay permanently resident (small).
     """
@@ -444,31 +444,3 @@ def moe_slotbuf(params, slot_weights, slot_of_expert: jnp.ndarray,
         s = params["shared"]
         out = out + swiglu(x, s["w_gate"], s["w_up"], s["w_down"])
     return out, r
-
-
-def moe_slotbuf_fused(params, slot_weights, slot_of_expert: jnp.ndarray,
-                      x: jnp.ndarray, moe,
-                      logit_bias: Optional[jnp.ndarray] = None,
-                      interpret: Optional[bool] = None):
-    """Decode-superkernel MoE entry: route + top-k + slot indirection +
-    gate-weighted expert FFN in ONE Pallas launch (no dispatch/combine
-    scatter — decode token counts are tiny, so every expert block reads all
-    T rows and masks by assignment).
-
-    Returns (out (T, d) x.dtype, gates (T, k) f32 zeroed for non-resident
-    assignments, expert_ids (T, k) i32). Shared experts are added outside
-    the kernel (permanently resident, dense).
-    """
-    from repro.kernels import ops as kernel_ops
-    E = moe.num_experts
-    bias = (jnp.zeros((E,), jnp.float32) if logit_bias is None
-            else logit_bias.astype(jnp.float32))
-    y, gates, ids = kernel_ops.fused_moe_entry(
-        x, params["router"], bias, slot_of_expert.astype(jnp.int32),
-        slot_weights["w_gate"], slot_weights["w_up"], slot_weights["w_down"],
-        top_k=moe.top_k, norm_topk=moe.router_norm_topk, interpret=interpret)
-    out = y.astype(x.dtype)
-    if "shared" in params:
-        s = params["shared"]
-        out = out + swiglu(x, s["w_gate"], s["w_up"], s["w_down"])
-    return out, gates, ids

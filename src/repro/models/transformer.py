@@ -389,17 +389,13 @@ def layer_prefill_chunk(p, cfg: ModelConfig, spec: LayerSpec, x: jnp.ndarray,
 
 
 def layer_decode(p, cfg: ModelConfig, spec: LayerSpec, x: jnp.ndarray,
-                 cache, cache_len, *, src_len=None, use_kernel: bool = False):
+                 cache, cache_len, *, src_len=None):
     """One-token layer step. x: (B, 1, d). Returns (x, new_cache).
 
     `cache_len` may be a scalar (all rows at one position — the single-
     request decode path) or a (B,) int32 vector (continuous batching: each
     row sits at its own position; KV insertion and attention masking are
     then per-row).
-
-    `use_kernel=True` fuses KV-ring insert + online-softmax attention into
-    one Pallas launch (GQA and MLA self-attention; other mixer kinds and
-    cross-attention keep the einsum path).
     """
     p = gather_for_compute(p)
     B = x.shape[0]
@@ -409,28 +405,8 @@ def layer_decode(p, cfg: ModelConfig, spec: LayerSpec, x: jnp.ndarray,
         if cfg.attention == "mla":
             mix, lat, pe = attn_mod.mla_decode(
                 p["attn"], h, cache["latent"], cache["pe"], cache_len,
-                mla=cfg.mla, rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps,
-                use_kernel=use_kernel)
+                mla=cfg.mla, rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps)
             new_cache.update(latent=lat, pe=pe)
-        elif use_kernel:
-            size = cache["k"].shape[1]
-            positions = jnp.broadcast_to(
-                jnp.asarray(cache_len).reshape(-1, 1), (B, 1))
-            q = jnp.einsum("btd,dhk->bthk", h, p["attn"]["wq"])
-            if "q_norm" in p["attn"]:
-                q = rms_norm(q, p["attn"]["q_norm"], cfg.norm_eps)
-            if cfg.rope_theta > 0:
-                q = rope(q, positions, cfg.rope_theta)
-            k, v = attn_mod.gqa_project_kv(p["attn"], h, positions,
-                                           cfg.rope_theta, cfg.norm_eps)
-            from repro.kernels import ops as kernel_ops
-            clen_b = jnp.broadcast_to(
-                jnp.asarray(cache_len, jnp.int32).reshape(-1), (B,))
-            mix, kc, vc = kernel_ops.fused_decode_attention(
-                q, k, v, cache["k"], cache["v"], clen_b,
-                logit_softcap=cfg.attn_logit_softcap)
-            mix = jnp.einsum("bthk,hkd->btd", mix, p["attn"]["wo"])
-            new_cache.update(k=kc, v=vc)
         else:
             size = cache["k"].shape[1]
             slot = jnp.mod(cache_len, size)

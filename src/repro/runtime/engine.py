@@ -9,7 +9,7 @@ with platform timing constants.
 
 It also provides `SlotBufferEngine`: the MoE forward computed through the
 bounded device slot buffer (`core.expert_buffer` + `models.moe.moe_slotbuf`)
-with the host-side TwoLevelLRU controlling swaps. The fused hot path jits
+with the host-side TwoLevelLRU controlling swaps. Its one path jits
 per-layer compute once, routes on device (pulling only a small expert mask
 to host), batches every layer's swap-ins into one donated device write, and
 issues predicted next-layer swap-ins BEFORE dispatching the current layer's
@@ -39,13 +39,13 @@ from repro.configs.base import ModelConfig
 from repro.core.cache import TwoLevelLRU
 from repro.core.cache_aware import residency_logit_bias
 from repro.core.expert_buffer import (HostExpertStore, SlotTable, is_pinned,
-                                      make_buffer, swap_in, swap_in_many)
+                                      make_buffer, swap_in_many)
 from repro.core.faults import FaultInjector, FaultPlan, StepWatchdog
 from repro.core.prefetcher import Prefetcher, TransferLink
 from repro.core.step_size import StepSizeController
-from repro.core.trace import Sample, TraceLog
+from repro.core.trace import TraceLog
 from repro.models import moe as moe_mod
-from repro.models.layers import rms_norm, swiglu
+from repro.models.layers import rms_norm
 from repro.models.transformer import (LayerSpec, Model, init_layer,
                                       init_layer_cache, layer_decode,
                                       layer_forward, layer_prefill,
@@ -444,7 +444,8 @@ class SlotBufferEngine:
 
     Host side: TwoLevelLRU + SlotTable decide residency; device side: slots
     updated via batched donated scatters (`swap_in_many`), MoE computed with
-    `moe_slotbuf`. The fused hot path (default):
+    `moe_slotbuf`. One path serves every call — `forward`, the whole-prompt
+    and chunked prefills, and `decode_step`:
 
     - per-layer compute is jitted ONCE per layer shape (no per-layer
       retrace) — one `pre` dispatch (attention + norm + on-device routing)
@@ -462,21 +463,20 @@ class SlotBufferEngine:
       Issued transfers are also accounted through the paper's
       `core.prefetcher` link model (virtual time = MoE layer index).
 
-    Residency is guaranteed before each FFN dispatch, so outputs are
-    bit-exact versus the fully-resident model computed through the SAME
-    jitted functions (`reference_forward`). `fused=False` preserves the
-    pre-fused per-expert/per-op execution as the benchmark baseline.
+    Residency is guaranteed before each FFN dispatch (or, for a layer
+    dispatched speculatively in decode, verified after it and replayed), so
+    outputs are bit-exact versus the fully-resident model computed through
+    the SAME jitted functions (`reference_forward`, `reference_prefill`,
+    `reference_decode_step`).
     """
 
     def __init__(self, cfg: ModelConfig, params, model: Model,
-                 n_slots_per_layer: int, *, fused: bool = True,
-                 prefetch: bool = True,
+                 n_slots_per_layer: int, *,
                  link_bandwidth: float = 64e9, max_seq: int = 256,
                  step_size: Optional[int] = None,
                  controller: Optional[StepSizeController] = None,
                  pregate_margin: int = 2, route_bias: float = 0.0,
                  route_bias_adaptive: bool = False,
-                 use_superkernel: bool = False,
                  faults: Optional[FaultPlan] = None,
                  retry_max: int = 3, retry_backoff_s: float = 1e-3,
                  degraded_route_bias: float = 4.0,
@@ -502,16 +502,6 @@ class SlotBufferEngine:
         # the pool holds experts in the model's dtype: a bf16 pool under an
         # f32 model would round every expert it swaps in
         self.buffer = make_buffer(cfg, self.n_slots, model.dtype)
-        self.fused = fused
-        # decode superkernel: batched decode restructured into per-MoE-layer
-        # SEGMENTS (preceding dense layers + the MoE layer), each ONE jitted
-        # dispatch built on the fused Pallas kernels (attention insert +
-        # online softmax; route + top-k + slot FFN). Uniform speculation:
-        # every segment dispatches against current residency and is verified
-        # afterwards from the pulled masks (replay on mispredict).
-        self.use_superkernel = use_superkernel
-        self._sk_segs = None
-        self.prefetch_enabled = prefetch and fused
         self.stats = SlotPathStats()
         # every warm jitted dispatch funnels through this counter so
         # jit_calls accounting cannot drift from the calls actually made
@@ -528,7 +518,6 @@ class SlotBufferEngine:
             self.store = store
             self.tiers = store if hasattr(store, "demand_host") else None
             if self.tiers is not None:
-                assert fused, "tiered expert store requires the fused path"
                 tm = self.tiers.model
                 assert (tm.L, tm.E) == (L, E), (
                     f"shard store shape ({tm.L},{tm.E}) != model ({L},{E})")
@@ -964,8 +953,6 @@ class SlotBufferEngine:
 
     def _horizon(self, li: int) -> int:
         """Lookahead from MoE layer li, clamped to the remaining sweep."""
-        if not self.prefetch_enabled:
-            return 0
         if self.watchdog is not None and self.watchdog.tripped:
             # step deadline blown: collapse speculation to S=0 (sync every
             # MoE layer) until the watchdog's hysteresis re-expands it
@@ -1355,8 +1342,6 @@ class SlotBufferEngine:
     # -- forward ------------------------------------------------------------
     def forward(self, tokens: jnp.ndarray) -> jnp.ndarray:
         """Full forward with slot-buffer MoE. tokens: (B, T) -> (B, T, d)."""
-        if not self.fused:
-            return self._forward_legacy(tokens)
         self.stats.steps += 1
         tokens = jnp.asarray(tokens, jnp.int32)
         x, positions = self._dispatch(self._embed_fn(), self.params,
@@ -1369,10 +1354,9 @@ class SlotBufferEngine:
                                    positions)
                 continue
             nxt = self._next_router(li + 1)
-            want_pred = self.prefetch_enabled and nxt is not None
+            want_pred = nxt is not None
             x, flat, r, masks = self._dispatch(
-                self._pre_fn(spec, want_pred), p, x, positions,
-                nxt if want_pred else None)
+                self._pre_fn(spec, want_pred), p, x, positions, nxt)
             # ONE small host pull: (2, E) needed/predicted bool masks
             with span("engine.mask_pull", layer=li, rows=2):
                 masks_h = np.asarray(masks)
@@ -1427,9 +1411,8 @@ class SlotBufferEngine:
             # mirror forward()'s exact pre-fn variants so both paths run the
             # IDENTICAL compiled computations up to the slot indirection
             nxt = self._next_router(li + 1)
-            want_pred = self.prefetch_enabled and nxt is not None
-            x, flat, r, _ = self._pre_fn(spec, want_pred)(
-                p, x, positions, nxt if want_pred else None)
+            x, flat, r, _ = self._pre_fn(spec, nxt is not None)(
+                p, x, positions, nxt)
             x = self._ffn_fn(spec, "moe_ffn")(
                 p, self._staged_experts(li, x), self._ident_map, x, flat, r)
             li += 1
@@ -1509,7 +1492,6 @@ class SlotBufferEngine:
         + cache population + on-device routing; ffn = `moe_slotbuf`), plus
         the adaptive horizon: each sync pulls ONE (S+1, E) mask and fans
         speculative swap-ins across layers l+1..l+S in one batched write."""
-        assert self.fused, "incremental decode requires the fused runtime"
         tokens = jnp.asarray(tokens, jnp.int32)
         B, T = tokens.shape
         assert T <= self.max_seq, f"prompt {T} exceeds max_seq {self.max_seq}"
@@ -1552,7 +1534,6 @@ class SlotBufferEngine:
         (1, T) int32. Drive it with `prefill_chunk` (one fixed-shape chunk
         per call); consume the result via `finish_prefill_into` (batched
         serving) or let `prefill_chunked` run it to completion."""
-        assert self.fused, "chunked prefill requires the fused runtime"
         assert self.chunked_prefill_supported, (
             "chunked prefill needs global-attention layers throughout; use "
             "the monolithic `prefill` for this architecture")
@@ -1744,19 +1725,16 @@ class SlotBufferEngine:
         prompt, because every row's compute is independent of its
         neighbours and residency is guaranteed (or replayed) before each
         FFN dispatch."""
-        assert self.fused, "incremental decode requires the fused runtime"
         rows = (int(np.count_nonzero(state.active)) if state.batched
                 else int(np.shape(tok)[0]))
         with span("engine.decode_step", step=self.stats.steps, rows=rows,
                   S=self._s_eff()):
-            if self.use_superkernel:
-                return self._decode_step_superkernel(tok, state)
             return self._decode_step_layers(tok, state)
 
     def _decode_step_layers(self, tok, state: DecodeState
                             ) -> Tuple[jnp.ndarray, DecodeState]:
-        """`decode_step` through one `pre` and one FFN dispatch per MoE
-        layer (the path without the superkernel)."""
+        """The body of `decode_step`, inside its span: one `pre` and one
+        FFN dispatch per MoE layer."""
         # cache-aware routing is gated on the CEILING, not the live strength:
         # an adaptive engine at strength 0 keeps using the biased traces
         # (with a zero bias) so ramping costs no recompiles mid-serve.
@@ -1945,324 +1923,6 @@ class SlotBufferEngine:
                 active=act.copy())
         return logits, DecodeState(caches, clen + 1, pos=state.pos + 1)
 
-
-    # -- decode superkernel (segment-fused batched decode) -------------------
-    def _sk_segments(self):
-        """Partition the layer stack into decode SEGMENTS: each segment is
-        the run of dense layers up to and including the next MoE layer (so
-        segment index == MoE layer index li), plus a trailing run of dense
-        layers folded into the logits dispatch. One jitted dispatch per
-        segment is the whole point: the per-step dispatch count becomes
-        (#MoE layers + 1) instead of ~(2 * #MoE + #dense + 2)."""
-        if self._sk_segs is None:
-            segs, cur = [], []
-            for i, spec in enumerate(self.specs):
-                cur.append(i)
-                if spec.is_moe:
-                    segs.append(cur)
-                    cur = []
-            assert segs, "superkernel decode requires at least one MoE layer"
-            self._sk_segs = (segs, cur)
-        return self._sk_segs
-
-    def _specs_tag(self, cspecs) -> str:
-        """Name suffix of a run of layers: each one's shape, and its length
-        when it holds more than one."""
-        return ("".join(self._spec_tag(sp) for sp in cspecs)
-                + (f"_{len(cspecs)}l" if len(cspecs) > 1 else ""))
-
-    def _sk_seg_fn(self, specs_seg, s: int, batched: bool, first: bool,
-                   with_logits: bool = False):
-        """ONE jitted dispatch for a decode segment: (embed if first) ->
-        dense layers -> MoE attention -> fused route+top-k+slot-FFN Pallas
-        kernel -> residual, plus the (1+s, E) needed/pre-gate mask block.
-        Attention runs through the fused decode kernels (`use_kernel=True`);
-        the MoE entry always takes a logit-bias array (zeros when
-        cache-aware routing is off — adding fp32 zeros is bit-exact).
-        `with_logits`: all-MoE models have no trailing dense run, so the
-        LAST segment folds final-norm logits in too — no tail dispatch."""
-        key = ("sk_seg", tuple(self._spec_key(sp) for sp in specs_seg), s,
-               batched, first, with_logits)
-        if key not in self._fns:
-            cfg, model = self.cfg, self.model
-            cspecs = [self._spec_key(sp) for sp in specs_seg]
-            E = cfg.moe.num_experts
-            k_pred = min(E, cfg.moe.top_k + self.pregate_margin)
-            from repro.models.transformer import _zc
-
-            def fn(params, ps, seg_caches, x, clen, slot_weights, slot_map,
-                   routers_next, bias_this, bias_next, active=None):
-                if first:
-                    pos = jnp.broadcast_to(
-                        jnp.asarray(clen).reshape(-1, 1), (x.shape[0], 1))
-                    x = model.embed(params, x[:, None], positions=pos)
-                new_caches = []
-                for j, cspec in enumerate(cspecs[:-1]):
-                    x, c = layer_decode(ps[j], cfg, cspec, x, seg_caches[j],
-                                        clen, use_kernel=True)
-                    new_caches.append(c)
-                p = ps[-1]
-                stripped, spec_nf = split_ffn_params(p, cspecs[-1])
-                x, c = layer_decode(stripped, cfg, spec_nf, x,
-                                    seg_caches[-1], clen, use_kernel=True)
-                new_caches.append(c)
-                B, T, d = x.shape
-                h2 = rms_norm(x, p["ffn_norm"], cfg.norm_eps,
-                              zero_centered=_zc(cfg))
-                flat = h2.reshape(-1, d)
-                out, gates, ids = moe_mod.moe_slotbuf_fused(
-                    p["moe"], slot_weights, slot_map, flat, cfg.moe,
-                    logit_bias=bias_this)
-                ff = out.reshape(B, T, d)
-                if "post_ffn_norm" in p:
-                    ff = rms_norm(ff, p["post_ffn_norm"], cfg.norm_eps,
-                                  zero_centered=_zc(cfg))
-                x = x + ff
-                ids_m = ids
-                if active is not None:
-                    ids_m = jnp.where(active[:, None], ids_m, E)
-                rows = [jnp.zeros((E,), jnp.bool_)
-                        .at[ids_m.reshape(-1)].set(True, mode="drop")[None]]
-                for j in range(s):
-                    rn = moe_mod.route(routers_next[j], flat, k_pred,
-                                       cfg.moe.router_norm_topk,
-                                       logit_bias=bias_next[j])
-                    idn = rn.expert_ids
-                    if active is not None:
-                        idn = jnp.where(active[:, None], idn, E)
-                    rows.append(jnp.zeros((E,), jnp.bool_)
-                                .at[idn.reshape(-1)].set(True,
-                                                         mode="drop")[None])
-                logits = (model.logits(params, x[:, -1]) if with_logits
-                          else None)
-                return x, jnp.concatenate(rows, axis=0), new_caches, logits
-            self._fns[key] = named_jit(
-                "decode_segment" + self._specs_tag(cspecs) + f"_s{s}"
-                + ("_first" if first else "") + ("_logits" if with_logits else "")
-                + ("_batched" if batched else ""), fn)
-        return self._fns[key]
-
-    def _sk_tail_fn(self, specs_tail):
-        """Trailing dense layers + final-norm logits in ONE dispatch."""
-        key = ("sk_tail", tuple(self._spec_key(sp) for sp in specs_tail))
-        if key not in self._fns:
-            cfg, model = self.cfg, self.model
-            cspecs = [self._spec_key(sp) for sp in specs_tail]
-
-            def fn(params, ps, tail_caches, x, clen):
-                new_caches = []
-                for j, cspec in enumerate(cspecs):
-                    x, c = layer_decode(ps[j], cfg, cspec, x, tail_caches[j],
-                                        clen, use_kernel=True)
-                    new_caches.append(c)
-                return model.logits(params, x[:, -1]), new_caches
-            self._fns[key] = named_jit("decode_tail" + self._specs_tag(cspecs),
-                                       fn)
-        return self._fns[key]
-
-    def _decode_step_superkernel(self, tok, state: DecodeState
-                                 ) -> Tuple[jnp.ndarray, DecodeState]:
-        """One decode step through the segment-fused superkernel path.
-
-        Same contract as `decode_step` (bit-exact token stream vs the
-        einsum-oracle engine at route_bias 0), different dispatch shape:
-        each segment is ONE jitted launch that fuses attention (Pallas
-        decode kernel), routing + top-k + slot-indirect expert FFN (Pallas
-        MoE kernel) and the next-s pre-gate. Because routing happens INSIDE
-        the launch, every segment executes speculatively against current
-        residency; the accumulated needed masks are pulled at sync segments
-        and verified (needed subset of resident-at-dispatch), rolling back
-        and replaying from the first mis-speculated segment with its now-
-        known demand set on failure. Per-step dispatches: #segments + 1
-        (tail) + pulls — vs ~2 per MoE layer + dense + embed + logits on
-        the standard path."""
-        ca = self.route_bias > 0.0 or self._degraded
-        batched = state.batched
-        if batched:
-            act = np.asarray(state.active, bool)
-            if act.any():
-                assert int(np.asarray(state.pos)[act].max()) < self.max_seq, (
-                    f"decode past max_seq={self.max_seq} would silently wrap "
-                    "the KV ring buffer; raise max_seq at engine "
-                    "construction or retire the request")
-            active_dev = jnp.asarray(act)
-        else:
-            assert state.pos < self.max_seq, (
-                f"decode past max_seq={self.max_seq} would silently wrap the "
-                "KV ring buffer; raise max_seq at engine construction")
-            active_dev = None
-        t0 = time.perf_counter()
-        self.stats.steps += 1
-        tok = jnp.asarray(tok, jnp.int32)
-        caches, clen = list(state.caches), state.cache_len
-        segs, tail = self._sk_segments()
-        fold_logits = not tail
-        logits = None
-        E = self.cfg.moe.num_experts
-
-        predicted: Dict[int, set] = {}
-        demand_hint: Dict[int, set] = {}   # li -> known demand after replay
-        # pending: (li, seg_i, masks_dev, slot_snap, ready_snap, hint_set)
-        pending: List[tuple] = []
-        ckpt: Dict[int, tuple] = {}        # seg_i -> (x_in, [seg caches])
-        self._window_layers.clear()
-        self._evicted_spec.clear()
-
-        def replay_from(fail_idx: int, needed_h) -> Tuple[int, jnp.ndarray]:
-            plj, psi = pending[fail_idx][0], pending[fail_idx][1]
-            with span("engine.replay", layer=plj):
-                self.stats.replays += 1
-                for kk, (_, cs_old) in ckpt.items():
-                    if kk >= psi:
-                        for jj, aj in enumerate(segs[kk]):
-                            caches[aj] = cs_old[jj]
-                x_r = ckpt[psi][0]
-                for kk in [kk for kk in self._evicted_spec if kk[0] >= plj]:
-                    del self._evicted_spec[kk]
-                    self.prefetcher.note_unused(kk)
-                    self.controller.record_overfetch()
-                # the pulled mask IS the failed segment's demand: replay it
-                # with residency ensured up front (union with any earlier
-                # hint so the hint set grows monotonically -> the replay
-                # loop terminates)
-                demand_hint[plj] = demand_hint.get(plj, set()) | {
-                    int(e) for e in needed_h}
-                predicted.clear()
-                pending.clear()
-                ckpt.clear()
-                self._window_layers.clear()
-            return psi, x_r
-
-        def pull_and_verify():
-            """ONE blocking pull of every pending segment's mask block.
-            Returns (fail_idx, fail_needed, sync_rows): fail_idx < 0 on
-            success, where sync_rows is the LAST segment's full (1+s, E)
-            block (needed row + pre-gate rows) for `_decode_sync_rows`."""
-            stacked = (pending[0][2] if len(pending) == 1
-                       else jnp.concatenate([pp[2] for pp in pending], 0))
-            with span("engine.mask_pull", layer=si, rows=stacked.shape[0]):
-                masks_h = np.asarray(stacked)
-            self.stats.host_syncs += 1
-            row = 0
-            for idx, (plj, _, mdev, snap, rsnap, hint) in enumerate(pending):
-                needed = np.nonzero(masks_h[row])[0]
-                self._settle_prediction(plj, {int(e) for e in needed},
-                                        ready_at_dispatch=rsnap)
-                if any(snap[int(e)] < 0 for e in needed):
-                    # a hinted replay dispatched after best-effort
-                    # ensure_resident: a still-missing expert within the
-                    # hint is capacity overflow (its tokens dropped via the
-                    # dead sentinel, as on the standard path), not a
-                    # misprediction — don't replay forever
-                    if not (hint and {int(e) for e in needed} <= hint):
-                        return idx, needed, None
-                row += mdev.shape[0]
-            last_rows = masks_h[row - pending[-1][2].shape[0]: row]
-            return -1, None, last_rows
-
-        si = 0
-        n_segs = len(segs)
-        while True:
-            if si == n_segs:
-                if pending:
-                    fail, needed_h, _ = pull_and_verify()
-                    if fail >= 0:
-                        si, x = replay_from(fail, needed_h)
-                        continue
-                    pending.clear()
-                    ckpt.clear()
-                    self._window_layers.clear()
-                break
-            li = si
-            seg = segs[si]
-            first = si == 0
-            hint = demand_hint.pop(li, set())
-            with span("engine.residency", layer=li):
-                if hint:
-                    self.cache.retier([(li, int(e)) for e in sorted(hint)],
-                                      recent_layers=(), current_layer=li)
-                    self.ensure_resident(li, sorted(hint))
-                elif li in predicted:
-                    self.ensure_resident(li, sorted(predicted[li]),
-                                         speculative=True)
-            sync = li not in predicted or bool(hint)
-            s = self._horizon(li) if sync else 0
-            if ca:
-                bias_this = self._residency_bias(li)
-                bias_next = (self._pregate_bias(li, s) if s > 0
-                             else jnp.zeros((0, E), jnp.float32))
-            else:
-                bias_this = jnp.zeros((E,), jnp.float32)
-                bias_next = jnp.zeros((s, E), jnp.float32)
-            x_in = tok if first else x
-            wl = fold_logits and si == n_segs - 1
-            ckpt[si] = (x_in, [caches[j] for j in seg])
-            slot_map = jnp.asarray(self.table.layer_slot_map(li))
-            x, masks_dev, new_cs, lg = self._dispatch(
-                self._sk_seg_fn([self.specs[j] for j in seg], s, batched,
-                                first, wl),
-                self.params if first or wl else None,
-                [self._p[j] for j in seg],
-                [caches[j] for j in seg], x_in, clen, self.buffer, slot_map,
-                self._router_slice(li, s), bias_this, bias_next, active_dev)
-            if wl:
-                logits = lg
-            for jj, aj in enumerate(seg):
-                caches[aj] = new_cs[jj]
-            self._advance_clock()
-            snap = self.table.layer_slot_map(li)
-            ready_snap = {kk: self.prefetcher.is_ready(kk, self._clock)
-                          for kk in self._prefetch_pending if kk[0] == li}
-            pending.append((li, si, masks_dev, snap, ready_snap, hint))
-            self._window_layers.add(li)
-            if not sync:
-                self.stats.spec_layers += 1
-                si += 1
-                continue
-            fail, needed_h, sync_rows = pull_and_verify()
-            if fail >= 0:
-                si, x = replay_from(fail, needed_h)
-                continue
-            with span("engine.residency", layer=li):
-                needed, pred = self._decode_sync_rows(li, s, sync_rows)
-                predicted.clear()
-                predicted.update(pred)
-                self.cache.retier(
-                    [(li, int(e)) for e in needed]
-                    + [(lj, int(e)) for lj, es in pred.items() for e in es],
-                    recent_layers=(), current_layer=li)
-                # verified: pure LRU touches (all needed are resident),
-                # unless a hinted segment overflowed capacity — then this
-                # books the miss
-                self.ensure_resident(li, needed)
-                if pred:
-                    self.prefetch_window(
-                        [(lj, sorted(es)) for lj, es in sorted(pred.items())])
-            pending.clear()
-            ckpt.clear()
-            self._window_layers.clear()
-            si += 1
-
-        if not fold_logits:
-            logits, new_tc = self._dispatch(
-                self._sk_tail_fn([self.specs[j] for j in tail]),
-                self.params, [self._p[j] for j in tail],
-                [caches[j] for j in tail], x, clen)
-            for jj, aj in enumerate(tail):
-                caches[aj] = new_tc[jj]
-        self.cache.protect_early_layers(
-            max(1, min(self._s_eff(), len(self.moe_layer_ids))))
-        step_s = time.perf_counter() - t0
-        self.controller.update_layer_time(step_s / max(len(self.specs), 1))
-        self._fault_step_end(step_s)
-        if batched:
-            return logits, DecodeState(
-                caches, clen + active_dev.astype(jnp.int32),
-                pos=np.where(act, np.asarray(state.pos) + 1,
-                             np.asarray(state.pos)),
-                active=act.copy())
-        return logits, DecodeState(caches, clen + 1, pos=state.pos + 1)
-
     # -- fully-resident decode oracle ---------------------------------------
     def reference_prefill(self, tokens) -> Tuple[jnp.ndarray, DecodeState]:
         """Prefill through the SAME jitted functions with the identity slot
@@ -2340,67 +2000,3 @@ class SlotBufferEngine:
             tok = sample(logits, key, temperature)
             out.append(np.asarray(tok))
         return np.stack(out, axis=1)
-
-    # -- pre-fused execution (benchmark baseline) ---------------------------
-    def _ensure_resident_seq(self, li: int, experts) -> int:
-        """Pre-fused swap path: one jitted dispatch + param-tree re-slice
-        per missing expert."""
-        swaps = 0
-        for e in experts:
-            key = (li, int(e))
-            if self.cache.touch(key):
-                continue
-            self.stats.demand_misses += 1
-            victim = self.cache.insert(key)
-            if victim is not None:
-                self.table.release(*victim)
-            slot = self.table.assign(li, int(e))
-            ws = self.experts.experts([key])[0]
-            self.stats.swap_pinned_experts += all(map(is_pinned, ws))
-            self.buffer = swap_in(self.buffer, slot, *jax.device_put(
-                ws, self.buffer["w_gate"].sharding))
-            self.stats.swap_calls += 1
-            self.stats.swap_experts += 1
-            swaps += 1
-        return swaps
-
-    def _forward_legacy(self, tokens: jnp.ndarray) -> jnp.ndarray:
-        """The pre-fused hot path, kept verbatim as the benchmark baseline:
-        eager per-op layer compute, host routing that pulls the full (T, k)
-        assignment tensor, and per-expert sequential swap-ins."""
-        self.stats.steps += 1
-        cfg = self.cfg
-        model = self.model
-        x = model.embed(self.params, tokens)
-        B, T = tokens.shape
-        positions = jnp.broadcast_to(jnp.arange(T)[None, :], (B, T))
-        li = 0
-        from repro.models.transformer import _zc
-        for i, spec in enumerate(self.specs):
-            p = self._p[i]
-            if not spec.is_moe:
-                x = layer_forward(p, cfg, spec, x, positions)
-                continue
-            # attention part
-            stripped, spec_nf = split_ffn_params(p, spec)
-            x = layer_forward(stripped, cfg, spec_nf, x, positions)
-            # route on host to learn required experts, then ensure residency
-            h2 = rms_norm(x, p["ffn_norm"], cfg.norm_eps, zero_centered=_zc(cfg))
-            flat = h2.reshape(B * T, -1)
-            r = moe_mod.route(p["moe"]["router"], flat, cfg.moe.top_k,
-                              cfg.moe.router_norm_topk)
-            needed = sorted({int(e) for e in np.asarray(r.expert_ids).reshape(-1)})
-            self.stats.host_syncs += 1
-            self._ensure_resident_seq(li, needed)
-            slot_map = jnp.asarray(self.table.layer_slot_map(li))
-            self.stats.ffn_calls += 1
-            self.stats.ffn_experts += len(needed)
-            out, _ = moe_mod.moe_slotbuf(
-                p["moe"], self.buffer, slot_map, flat, cfg.moe)
-            ff = out.reshape(B, T, -1)
-            if "post_ffn_norm" in p:
-                ff = rms_norm(ff, p["post_ffn_norm"], cfg.norm_eps,
-                              zero_centered=_zc(cfg))
-            x = x + ff
-            li += 1
-        return x

@@ -122,8 +122,7 @@ class Dispatcher:
     Every warm jitted call the engine issues goes through ONE of these
     (`self._dispatch(fn, *args)`) instead of ~20 hand-sprinkled
     `stats.jit_calls += 1` sites, so dispatch accounting cannot drift from
-    the calls actually made — the superkernel's claimed dispatch reduction
-    is measured through this funnel. Each call is an `engine.dispatch` span
+    the calls actually made. Each call is an `engine.dispatch` span
     carrying the function's name.
     """
     stats: object

@@ -108,7 +108,6 @@ class ServingEngine:
     def __init__(self, engine: SlotBufferEngine,
                  cfg: Optional[EngineServingConfig] = None,
                  key: Optional[jax.Array] = None):
-        assert engine.fused, "serving requires the fused slot-path runtime"
         self.engine = engine
         self.cfg = cfg or EngineServingConfig()
         if self.cfg.route_bias is not None:

@@ -7,6 +7,7 @@ host-sync collapse as the horizon S grows, the StepSizeController feedback
 signals wired into the real engine, and the `Engine.generate` decoded-token
 trace fix."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -78,15 +79,20 @@ def test_controller_horizon_clamps_to_remaining_layers():
 # slow lane: real-engine decode
 # ---------------------------------------------------------------------------
 
-@pytest.fixture(scope="module")
-def decode_setup():
-    cfg = reduce_config(get_config("olmoe-1b-7b"), layers=4, d_model=64,
+@functools.lru_cache(maxsize=None)
+def _setup(arch):
+    cfg = reduce_config(get_config(arch), layers=4, d_model=64,
                         heads=4, kv_heads=4, d_ff=128, vocab=512, experts=8,
                         top_k=2, d_expert=32)
     eng = Engine(cfg, max_seq=64)
     prompt = np.random.default_rng(7).integers(
         0, cfg.vocab_size, (2, 12)).astype(np.int32)
     return cfg, eng, prompt
+
+
+@pytest.fixture(scope="module")
+def decode_setup():
+    return _setup("olmoe-1b-7b")
 
 
 def _slot_engine(cfg, eng, **kw):
@@ -104,11 +110,14 @@ def _drive(sb, prompt, n_steps=10):
 
 
 @pytest.mark.slow
-def test_decode_bit_exact_vs_oracle_under_eviction_churn(decode_setup):
+@pytest.mark.parametrize("arch", ["olmoe-1b-7b", "deepseek-v2-lite"],
+                         ids=["gqa", "mla"])
+def test_decode_bit_exact_vs_oracle_under_eviction_churn(arch):
     """Per-step decode logits must match the fully-resident oracle BITWISE
     with fewer slots than experts (forced churn) — speculative windows,
-    demand swaps, and mispredict replays are numerically invisible."""
-    cfg, eng, prompt = decode_setup
+    demand swaps, and mispredict replays are numerically invisible. GQA
+    attention and MLA (a dense first layer, shared experts) alike."""
+    cfg, eng, prompt = _setup(arch)
     for spl, s in ((3, 2), (4, 1)):
         sb = _slot_engine(cfg, eng, n_slots_per_layer=spl, step_size=s)
         lo, st = sb.prefill(prompt)
@@ -123,6 +132,45 @@ def test_decode_bit_exact_vs_oracle_under_eviction_churn(decode_setup):
             tok = jnp.argmax(lo, -1).astype(jnp.int32)
         assert sb.cache.stats.evictions > 0      # the buffer really churned
         assert int(st.cache_len) == prompt.shape[1] + 8
+
+
+def _oracle_logits(sb, prompt, n_steps):
+    """The fully-resident oracle's logits over its own greedy tokens."""
+    lo, st = sb.reference_prefill(prompt)
+    out = [np.asarray(lo)]
+    toks = []
+    for _ in range(n_steps):
+        toks.append(jnp.argmax(lo, -1).astype(jnp.int32))
+        lo, st = sb.reference_decode_step(toks[-1], st)
+        out.append(np.asarray(lo))
+    return out, toks
+
+
+@pytest.mark.parametrize("expert", [0, 3])
+def test_oracle_check_catches_a_dropped_expert(decode_setup, monkeypatch,
+                                               expert):
+    """Control for the bit-exact check above: an FFN whose slot map drops
+    one expert in every MoE layer (its group streams nothing, so its
+    outputs read zero) fails that check, fed the oracle's own tokens."""
+    from repro.kernels import ops
+    cfg, eng, prompt = decode_setup
+    want, toks = _oracle_logits(
+        _slot_engine(cfg, eng, n_slots_per_layer=3, step_size=2), prompt, 8)
+    for name in ("slot_ffn", "slot_ffn_ref"):
+        real = getattr(ops, name)
+
+        def dropping(x, slot_of_group, *w, _real=real, **kw):
+            return _real(x, slot_of_group.at[expert].set(-1), *w, **kw)
+        monkeypatch.setattr(ops, name, dropping)
+    sb = _slot_engine(cfg, eng, n_slots_per_layer=3, step_size=2)
+    lo, st = sb.prefill(prompt)
+    got = [np.asarray(lo)]
+    for tok in toks:
+        lo, st = sb.decode_step(tok, st)
+        got.append(np.asarray(lo))
+    off = [j for j, (g, w) in enumerate(zip(got, want))
+           if not np.array_equal(g, w)]
+    assert len(off) > 4, off
 
 
 @pytest.mark.slow

@@ -1,5 +1,5 @@
 """SlotTable invariants, the pinned host store, and batched swap_in_many
-vs sequential swap_in."""
+vs writing the experts in one at a time."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,7 +7,7 @@ import pytest
 
 from repro.configs.registry import get_smoke_config
 from repro.core.expert_buffer import (HostExpertStore, SlotTable,
-                                      _swap_in_many, make_buffer, swap_in,
+                                      _swap_in_many, make_buffer,
                                       swap_in_many)
 
 
@@ -91,7 +91,8 @@ def test_swap_in_many_matches_sequential(n_moved, source):
 
     seq = make_buffer(cfg, n_slots)
     for i, s in enumerate(slots):
-        seq = swap_in(seq, int(s), wg[i], wu[i], wd[i])
+        seq = {k: seq[k].at[int(s)].set(w[i])
+               for k, w in (("w_gate", wg), ("w_up", wu), ("w_down", wd))}
     batched = swap_in_many(make_buffer(cfg, n_slots), slots,
                            *_sources(source, wg, wu, wd))
     for k in ("w_gate", "w_up", "w_down"):

@@ -319,3 +319,114 @@ def test_capacity_of_t_rows_never_drops():
     dense, _ = moe_mod.moe_reference(p, x, moe)
     np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# router-driven entry: `route` on device, then `moe_slotbuf(router_out=...)`,
+# as the engine's `pre` and FFN dispatches run it
+# ---------------------------------------------------------------------------
+
+
+def _routed_case(rng, T, E, n_slots, k, norm, d=64, f=128,
+                 dtype=jnp.bfloat16):
+    """A random router over E experts, n_slots slots holding a random subset
+    of the experts, and at least one ROUTED expert left non-resident (when
+    more experts are routed than the pool holds, the rest miss too)."""
+    moe = MoEConfig(num_experts=E, top_k=k, d_expert=f, router_norm_topk=norm)
+    p = _mk_params(rng, d, E, f, dtype)
+    p["router"] = jnp.asarray(rng.standard_normal((d, E)), jnp.float32) * 0.3
+    x = jnp.asarray(rng.standard_normal((T, d)), dtype) * 0.5
+    routed = np.unique(np.asarray(
+        moe_mod.route(p["router"], x, k, norm).expert_ids))
+    dead = {int(rng.choice(routed))}
+    soe = np.full(E, -1)
+    live = [e for e in rng.permutation(E) if e not in dead][:n_slots]
+    soe[live] = rng.permutation(n_slots)[:len(live)]
+    return moe, p, x, soe, _pool(rng, p, soe, n_slots)
+
+
+def _softmax_topk(x, router, k, norm, bias=0.0):
+    """float64 routing written out: softmax over biased logits, top-k."""
+    lo = (np.asarray(x, np.float64) @ np.asarray(router, np.float64)
+          + np.asarray(bias, np.float64))
+    pr = np.exp(lo - lo.max(-1, keepdims=True))
+    pr /= pr.sum(-1, keepdims=True)
+    ids = np.argsort(-pr, axis=-1, kind="stable")[:, :k]
+    gates = np.take_along_axis(pr, ids, -1)
+    if norm:
+        gates = gates / gates.sum(-1, keepdims=True)
+    return ids, gates
+
+
+def _check_routed_entry(moe, p, x, soe, pool, bias=None):
+    """The served entry against the per-slot einsum oracle on the same
+    routing, and the routing against float64 softmax-top-k."""
+    soe_d = jnp.asarray(soe, jnp.int32)
+    r = moe_mod.route(p["router"], x, moe.top_k, moe.router_norm_topk,
+                      logit_bias=bias)
+    out, r_used = moe_mod.moe_slotbuf(p, pool, soe_d, x, moe, router_out=r,
+                                      interpret=True)
+    assert r_used is r
+    oracle, _ = ref.moe_slotbuf_einsum_ref(p, pool, soe_d, x, moe,
+                                           router_out=r)
+    # one bf16 rounding apart at most: the kernel's dots have another shape
+    # than the oracle's, and can round the last bit of an output either way
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(oracle, np.float32),
+                               rtol=2.0 ** -7, atol=1e-6)
+    ids, gates = _softmax_topk(x, p["router"], moe.top_k,
+                               moe.router_norm_topk,
+                               0.0 if bias is None else bias)
+    np.testing.assert_array_equal(np.sort(np.asarray(r.expert_ids), -1),
+                                  np.sort(ids, -1))
+    np.testing.assert_allclose(np.sort(np.asarray(r.gates), -1),
+                               np.sort(gates, -1), rtol=1e-5, atol=1e-6)
+    routed = np.asarray(r.expert_ids)
+    assert (soe[routed] < 0).any() and (soe[routed] >= 0).any()
+    return r, out
+
+
+@pytest.mark.parametrize("T,E,n_slots,k", [(8, 8, 8, 2), (16, 8, 6, 2),
+                                           (4, 16, 5, 4), (1, 8, 3, 8)])
+@pytest.mark.parametrize("norm", [True, False])
+def test_routed_entry_matches_einsum_oracle(T, E, n_slots, k, norm):
+    """Random router, some routed experts not resident: `route` then
+    `moe_slotbuf(router_out=...)` equals the per-slot einsum oracle on the
+    same routing, and the routing is softmax-top-k of the router logits,
+    renormalised or not."""
+    rng = np.random.default_rng(T * E + k)
+    _check_routed_entry(*_routed_case(rng, T, E, n_slots, k, norm))
+
+
+@pytest.mark.parametrize("delta", [0.0, 1.5])
+def test_routed_entry_with_residency_logit_bias(delta):
+    """Cache-aware routing rides `route`'s `logit_bias`, built from the slot
+    table as the engine's `pre` dispatch gets it: at delta 0 the bias is a
+    bit-exact no-op; at delta 1.5 routing follows the biased logits and the
+    entry still equals the oracle on that routing."""
+    from repro.core.cache_aware import residency_logit_bias
+    rng = np.random.default_rng(3)
+    moe, p, x, soe, pool = _routed_case(rng, 8, 8, 5, 2, True)
+    bias = jnp.asarray(residency_logit_bias(soe >= 0, delta))
+    r, out = _check_routed_entry(moe, p, x, soe, pool, bias)
+    if delta == 0.0:
+        r0, out0 = _check_routed_entry(moe, p, x, soe, pool)
+        np.testing.assert_array_equal(np.asarray(r.expert_ids),
+                                      np.asarray(r0.expert_ids))
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(out0))
+
+
+def test_routed_entry_off_the_tile():
+    """d_model 48 and d_expert 72, off the 128-lane tile, 5 tokens: the
+    kernel path needs no padding from its caller."""
+    rng = np.random.default_rng(9)
+    moe, p, x, soe, pool = _routed_case(rng, 5, 4, 3, 2, True, d=48, f=72,
+                                        dtype=jnp.float32)
+    soe_d = jnp.asarray(soe, jnp.int32)
+    r = moe_mod.route(p["router"], x, 2, True)
+    out, _ = moe_mod.moe_slotbuf(p, pool, soe_d, x, moe, router_out=r,
+                                 interpret=True)
+    oracle, _ = ref.moe_slotbuf_einsum_ref(p, pool, soe_d, x, moe,
+                                           router_out=r)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
+                               rtol=1e-5, atol=1e-6)

@@ -86,22 +86,6 @@ def test_slot_buffer_engine_exact_vs_reference():
 
 
 @pytest.mark.slow
-def test_slot_buffer_legacy_exact_vs_unrolled():
-    """The pre-fused path keeps the original guarantee verbatim: eager
-    slot-buffer execution is bit-exact versus the eager unrolled model."""
-    cfg = get_smoke_config("olmoe-1b-7b")
-    eng = Engine(cfg, max_seq=64)
-    toks = jnp.asarray(np.random.default_rng(2).integers(
-        0, cfg.vocab_size, (2, 10)), jnp.int32)
-    sb = SlotBufferEngine(cfg, eng.params, eng.model,
-                          n_slots_per_layer=cfg.moe.num_experts, fused=False)
-    x_sb = sb.forward(toks)
-    x = _eager_unrolled(eng.model, eng.params, cfg, toks)
-    assert float(jnp.max(jnp.abs(x_sb - x))) == 0.0
-    assert sb.stats.swap_experts > 0
-
-
-@pytest.mark.slow
 def test_slot_buffer_bit_exact_across_evictions():
     """Regression: with fewer slots than experts (forced swap-in/release
     churn), repeated forwards must stay bit-exact versus the fully-resident

@@ -111,15 +111,13 @@ def test_serve_writes_every_span_nested_where_the_work_happens(model, tmp_path):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("arch,superkernel", [("olmoe-1b-7b", False),
-                                               ("olmoe-1b-7b", True),
-                                               ("deepseek-v2-lite", False)])
-def test_every_registered_function_has_a_name_of_its_own(arch, superkernel):
+@pytest.mark.parametrize("arch", ["olmoe-1b-7b", "deepseek-v2-lite"])
+def test_every_registered_function_has_a_name_of_its_own(arch):
     cfg = get_smoke_config(arch)
     eng = Engine(cfg, max_seq=48)
     sb = SlotBufferEngine(cfg, eng.params, eng.model, max_seq=48,
                           n_slots_per_layer=cfg.moe.num_experts // 2,
-                          step_size=1, use_superkernel=superkernel)
+                          step_size=1)
     rng = np.random.default_rng(3)
     reqs = [Request(prompt=rng.integers(0, cfg.vocab_size, L).astype(np.int32),
                     max_new_tokens=3, request_id=i)
@@ -130,9 +128,8 @@ def test_every_registered_function_has_a_name_of_its_own(arch, superkernel):
     names = [f.__name__ for f in sb._fns.values()]
     assert len(set(names)) == len(names), names
     assert not {"fn", "<lambda>"} & set(names)
-    want = {"predict_ws", "embed", "logits"}
-    want |= ({"decode_segment_s1_first_batched"} if superkernel
-             else {"moe_ffn_decode", "embed_decode"})
+    want = {"predict_ws", "embed", "logits", "moe_ffn_decode",
+            "embed_decode"}
     if arch == "olmoe-1b-7b":
         want |= {"moe_ffn_chunk", "moe_ffn", "embed_chunk", "logits_at"}
     assert want <= set(names), want - set(names)
