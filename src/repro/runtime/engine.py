@@ -19,8 +19,9 @@ functions whenever the runtime keeps the working set resident.
 
 `prefill`/`decode_step`/`generate` add KV-cached incremental decode: O(1)
 attention per step, an adaptive multi-layer prefetch horizon S (pre-gating
-the next S routers in one dispatch, ONE (S+1, E) mask pull per sync, and
-speculative execution of the S-layer window with verify-and-replay), with a
+the next S routers in the sync layer's own attention program, ONE mask pull
+per sync, and speculative execution of the S-layer window with
+verify-and-replay), with a
 `core.step_size.StepSizeController` closing the paper's stall/overfetch
 feedback loop from real runtime signals.
 """
@@ -556,10 +557,20 @@ class SlotBufferEngine:
         # swap windows dispatched so far (one in BANDWIDTH_SAMPLE_EVERY is
         # timed to completion; see `_dispatch_swap`)
         self._swap_windows = 0
-        # all MoE routers stacked (L, d, E) so the pre-gate fn can take any
-        # lookahead window as ONE device slice
+        # all MoE routers stacked (L, d, E)
         self._router_stack = jnp.stack(
             [self._p[i]["moe"]["router"] for i in self.moe_layer_ids])
+        # the pre-gate reads a fixed window of the controller's s_max
+        # routers (no deeper than the sweep) at a traced start layer, from
+        # the stack padded with s_max zero routers: one program serves
+        # every horizon S
+        self._s_max = max(1, min(controller.cfg.s_max, L - 1))
+        self._gate_routers = jnp.concatenate(
+            [self._router_stack,
+             jnp.zeros((self._s_max,) + self._router_stack.shape[1:],
+                       self._router_stack.dtype)])
+        self._gate_starts = [jnp.asarray(j, jnp.int32)
+                             for j in range(1, L + 1)]
         # §3.4 cache-aware routing: bounded residency perturbation of the
         # decode routers (see `set_route_bias`). 0 disables it entirely —
         # the jitted fns are then called exactly as without the feature, so
@@ -828,9 +839,11 @@ class SlotBufferEngine:
                 lambda params, x, idx: model.logits(params, x[:, idx]))
         return self._fns["logits_at"]
 
-    def _pre_decode_fn(self, spec: LayerSpec, batched: bool = False):
+    def _pre_decode_fn(self, spec: LayerSpec, batched: bool = False,
+                       pregate: bool = False):
         """Decode pre half: O(1) attention against the KV cache + cache
         update + norm + on-device routing. One dispatch; no host pulls.
+        Returns (x, flat, r, needed, cache).
 
         `batched` (continuous batching): the fn additionally takes an
         `active` (B,) bool mask — cache_len is then per-row and the needed
@@ -840,57 +853,78 @@ class SlotBufferEngine:
         `rbias` (cache-aware serving): optional (E,) residency logit bias
         for this layer's router. jit re-traces on argument structure, so
         calls with rbias=None compile the EXACT pre-bias graph — engines
-        with the perturbation off are bit-exact by construction."""
-        key = ("pre_decode", self._spec_key(spec), batched)
+        with the perturbation off are bit-exact by construction.
+
+        `pregate` (a sync layer with a horizon): the fn also takes `gate` =
+        (routers, start[, bias]) and returns, instead of `needed`, the
+        `_pregate_block` whose row 0 it is, so the sync layer's attention
+        and its pre-gate are one dispatch. The layer's outputs pass an
+        optimization barrier first: the pre-gate reading them cannot change
+        how they are computed, so they match the plain program's bits."""
+        key = ("pre_decode", self._spec_key(spec), batched, pregate)
         if key not in self._fns:
             cfg, cspec = self.cfg, self._spec_key(spec)
 
-            def fn(p, x, cache, cache_len, active=None, rbias=None):
+            def fn(p, x, cache, cache_len, active=None, rbias=None,
+                   gate=None):
                 stripped, spec_nf = split_ffn_params(p, cspec)
                 x, new_cache = layer_decode(stripped, cfg, spec_nf, x, cache,
                                             cache_len)
                 flat, r, needed = _route_ffn_entry(p, cfg, x, active, rbias)
-                return x, flat, r, needed, new_cache
+                out = (x, flat, r, needed, new_cache)
+                if not pregate:
+                    return out
+                out = jax.lax.optimization_barrier(out)
+                return out[:3] + (self._pregate_block(
+                    out[1], out[3], *gate[:2], active, *gate[2:]),) + out[4:]
             self._fns[key] = named_jit(
                 "pre_decode" + self._spec_tag(spec)
-                + ("_batched" if batched else ""), fn)
+                + ("_batched" if batched else "")
+                + ("_pregate" if pregate else ""), fn)
         return self._fns[key]
 
-    def _pregate_fn(self, n_next: int, batched: bool = False):
-        """Pre-gate the next `n_next` routers on the current hidden state in
-        ONE dispatch, returning a single (n_next + 1, E) bool mask: row 0 is
-        the layer's actual needed set, rows 1.. the speculative horizon.
+    def _pregate_block(self, flat, needed, routers, start, active=None,
+                       rbias=None):
+        """(s_max + 1, E) bool sync block, traced: row 0 the layer's actual
+        needed set, row 1 + j the experts the router of MoE layer start + j
+        picks (top-k + margin) on the current hidden state. `routers` is
+        the padded stack `_gate_routers`, read at the traced `start`, so one
+        program serves every horizon; the host reads rows 0..S.
 
-        `batched`: idle batch slots are masked out of the union (their rows
+        `active`: idle batch slots are masked out of the union (their rows
         scatter out of range, mode="drop"), so one host sync still covers
         the whole co-batched decode iteration without garbage rows inflating
         the predicted working set.
 
-        `rbias` (cache-aware serving): optional (n_next, E) per-target-layer
+        `rbias` (cache-aware serving): optional (s_max, E) per-target-layer
         residency bias so predictions agree with the biased routing those
         layers will run; None traces the exact pre-bias graph."""
-        key = ("pregate", n_next, batched)
-        if key not in self._fns:
-            cfg = self.cfg
-            E = cfg.moe.num_experts
-            k_pred = min(E, cfg.moe.top_k + self.pregate_margin)
+        cfg = self.cfg
+        E = cfg.moe.num_experts
+        k_pred = min(E, cfg.moe.top_k + self.pregate_margin)
+        window = jax.lax.dynamic_slice_in_dim(routers, start, self._s_max)
 
-            def fn(flat, needed, routers, active=None, rbias=None):
-                rows = [needed[None]]
-                for j in range(n_next):
-                    rn = moe_mod.route(routers[j], flat, k_pred,
-                                       cfg.moe.router_norm_topk,
-                                       logit_bias=None if rbias is None
-                                       else rbias[j])
-                    ids = rn.expert_ids
-                    if active is not None:
-                        ids = jnp.where(active[:, None], ids, E)
-                    m = jnp.zeros((E,), jnp.bool_)
-                    m = m.at[ids.reshape(-1)].set(True, mode="drop")
-                    rows.append(m[None])
-                return jnp.concatenate(rows, axis=0)
+        def row(w, b):
+            ids = moe_mod.route(w, flat, k_pred, cfg.moe.router_norm_topk,
+                                logit_bias=b).expert_ids
+            if active is not None:
+                ids = jnp.where(active[:, None], ids, E)
+            return jnp.zeros((E,), jnp.bool_).at[ids.reshape(-1)].set(
+                True, mode="drop")
+        rows = (jax.vmap(lambda w: row(w, None))(window) if rbias is None
+                else jax.vmap(row)(window, rbias))
+        return jnp.concatenate([needed[None], rows], axis=0)
+
+    def _pregate_fn(self, batched: bool = False):
+        """`_pregate_block` as its own program, for the prefill paths (a
+        decode step runs it inside the sync layer's `pre_decode`).
+        `batched`: the program takes the `active` row mask."""
+        key = ("pregate", batched)
+        if key not in self._fns:
             self._fns[key] = named_jit(
-                f"pregate_s{n_next}" + ("_batched" if batched else ""), fn)
+                "pregate" + ("_batched" if batched else ""),
+                lambda flat, needed, routers, start, active=None:
+                self._pregate_block(flat, needed, routers, start, active))
         return self._fns[key]
 
     # -- cache-aware routing (§3.4) ------------------------------------------
@@ -939,20 +973,23 @@ class SlotBufferEngine:
             residency_logit_bias(mask, self._route_bias_strength()))
 
     def _pregate_bias(self, li: int, s: int) -> jnp.ndarray:
-        """(s, E) bias stack for the pre-gated horizon (layers li+1..li+s),
-        each row from its own layer's residency, so speculative predictions
-        agree with the biased routing those layers will actually run."""
-        strength = self._route_bias_strength()
-        rows = np.stack([self.table.layer_slot_map(li + 1 + j) >= 0
-                         for j in range(s)])
-        return jnp.asarray(residency_logit_bias(rows, strength))
+        """(s_max, E) bias stack for the pre-gated horizon: row j < s from
+        layer li+1+j's own residency, so speculative predictions agree with
+        the biased routing those layers will actually run (rows past s are
+        never read)."""
+        rows = np.zeros((self._s_max, self.cfg.moe.num_experts), bool)
+        for j in range(s):
+            rows[j] = self.table.layer_slot_map(li + 1 + j) >= 0
+        return jnp.asarray(residency_logit_bias(rows,
+                                                self._route_bias_strength()))
 
     # -- adaptive horizon ----------------------------------------------------
     def _s_eff(self) -> int:
         return self.fixed_s if self.fixed_s is not None else self.controller.s
 
     def _horizon(self, li: int) -> int:
-        """Lookahead from MoE layer li, clamped to the remaining sweep."""
+        """Lookahead from MoE layer li, clamped to the remaining sweep and
+        to the pre-gate's window."""
         if self.watchdog is not None and self.watchdog.tripped:
             # step deadline blown: collapse speculation to S=0 (sync every
             # MoE layer) until the watchdog's hysteresis re-expands it
@@ -960,41 +997,15 @@ class SlotBufferEngine:
         if self.faults is not None \
                 and self.faults.predictor_blackout(self._clock):
             return 0       # predictor signal dark: nothing to speculate on
-        remaining = len(self.moe_layer_ids) - (li + 1)
+        remaining = min(len(self.moe_layer_ids) - (li + 1), self._s_max)
         if self.fixed_s is not None:
             return max(0, min(self.fixed_s, remaining))
         return self.controller.horizon(remaining)
 
-    def _router_slice(self, li: int, s: int) -> jnp.ndarray:
-        """(s, d, E) device slice of the routers for MoE layers li+1..li+s."""
-        return self._router_stack[li + 1: li + 1 + s]
-
-    def _sync_masks_dev(self, li: int, s: int, flat, needed_dev,
-                        active_dev=None, rbias=None):
-        """Device-side (s+1, E) sync mask block: row 0 the layer's actual
-        needed set, rows 1.. the pre-gated horizon. At s == 0 the pregate
-        dispatch is pure overhead — the needed mask alone suffices.
-        `active_dev`: (B,) bool for batched serving (idle rows masked).
-        `rbias`: optional (s, E) cache-aware bias for the horizon routers
-        (None keeps the exact pre-bias traces)."""
-        if s == 0:
-            return needed_dev[None]
-        if rbias is not None:
-            return self._dispatch(
-                self._pregate_fn(s, batched=active_dev is not None),
-                flat, needed_dev, self._router_slice(li, s), active_dev,
-                rbias)
-        if active_dev is not None:
-            return self._dispatch(self._pregate_fn(s, batched=True),
-                                  flat, needed_dev,
-                                  self._router_slice(li, s), active_dev)
-        return self._dispatch(self._pregate_fn(s), flat, needed_dev,
-                              self._router_slice(li, s))
-
     @staticmethod
     def _decode_sync_rows(li: int, s: int, rows: np.ndarray):
-        """Pulled (s+1, E) sync block -> (needed expert ids, predicted sets
-        keyed by MoE layer)."""
+        """Pulled sync block (row 0 and at least s rows after it) -> (needed
+        expert ids, predicted sets keyed by MoE layer)."""
         needed = np.nonzero(rows[0])[0]
         predicted = {li + 1 + j: {int(e) for e in np.nonzero(rows[1 + j])[0]}
                      for j in range(s)}
@@ -1474,9 +1485,17 @@ class SlotBufferEngine:
         Returns the layer's slot map for the FFN dispatch and the number of
         experts it needs."""
         s = self._horizon(li)
-        masks = self._sync_masks_dev(li, s, flat, needed_dev, active_dev)
+        if s == 0:                 # the needed mask alone suffices
+            masks = needed_dev[None]
+        elif active_dev is None:
+            masks = self._dispatch(self._pregate_fn(), flat, needed_dev,
+                                   self._gate_routers, self._gate_starts[li])
+        else:
+            masks = self._dispatch(self._pregate_fn(batched=True), flat,
+                                   needed_dev, self._gate_routers,
+                                   self._gate_starts[li], active_dev)
         with span("engine.mask_pull", layer=li, rows=s + 1):
-            masks_h = np.asarray(masks)      # ONE (S+1, E) blocking pull
+            masks_h = np.asarray(masks)      # ONE blocking pull
         self.stats.host_syncs += 1
         self._advance_clock()
         with span("engine.residency", layer=li):
@@ -1734,7 +1753,10 @@ class SlotBufferEngine:
     def _decode_step_layers(self, tok, state: DecodeState
                             ) -> Tuple[jnp.ndarray, DecodeState]:
         """The body of `decode_step`, inside its span: one `pre` and one
-        FFN dispatch per MoE layer."""
+        FFN dispatch per MoE layer. A sync layer's `pre` also pre-gates its
+        horizon (the host takes the layer's clock tick and reads the
+        horizon before dispatching it), so a sync costs no dispatch of its
+        own."""
         # cache-aware routing is gated on the CEILING, not the live strength:
         # an adaptive engine at strength 0 keeps using the biased traces
         # (with a zero bias) so ramping costs no recompiles mid-serve.
@@ -1818,7 +1840,8 @@ class SlotBufferEngine:
             mats = [p[2] for p in pending]
             if extra is not None:
                 mats.append(extra)
-            rows = len(pending) + (0 if extra is None else extra.shape[0])
+            rows = len(pending) + (0 if extra is None else
+                                   extra.shape[0] if extra.ndim == 2 else 1)
             with span("engine.mask_pull", layer=li, rows=rows):
                 masks_h = np.concatenate(
                     [np.atleast_2d(m) for m in jax.device_get(mats)], 0)
@@ -1851,21 +1874,18 @@ class SlotBufferEngine:
                 i += 1
                 continue
             x_in, old_c = x, caches[i]
-            if ca:
-                # cache-aware routing: this layer's residency bias rides the
-                # pre dispatch (host mask push only — no extra syncs)
-                x2, flat, r, needed_dev, c2 = self._dispatch(
-                    self._pre_decode_fn(spec, batched=batched),
-                    p, x_in, old_c, clen, active_dev,
-                    self._residency_bias(li))
-            elif batched:
-                x2, flat, r, needed_dev, c2 = self._dispatch(
-                    self._pre_decode_fn(spec, batched=True),
-                    p, x_in, old_c, clen, active_dev)
-            else:
-                x2, flat, r, needed_dev, c2 = self._dispatch(
-                    self._pre_decode_fn(spec), p, x_in, old_c, clen)
             self._advance_clock()
+            s = 0 if li in predicted else self._horizon(li)
+            gate = None
+            if s > 0:
+                gate = (self._gate_routers, self._gate_starts[li]) + (
+                    (self._pregate_bias(li, s),) if ca else ())
+            # cache-aware routing: this layer's residency bias rides the pre
+            # dispatch (host mask push only — no extra syncs)
+            x2, flat, r, needed_dev, c2 = self._dispatch(
+                self._pre_decode_fn(spec, batched=batched, pregate=s > 0),
+                p, x_in, old_c, clen, active_dev,
+                self._residency_bias(li) if ca else None, gate)
             if li in predicted:
                 # ---- speculative layer: no host pull ----------------------
                 ckpt[i] = (x_in, old_c)
@@ -1887,11 +1907,8 @@ class SlotBufferEngine:
                 li += 1
                 continue
             # ---- sync layer: ONE blocking pull for verify + routing + S ---
-            s = self._horizon(li)
-            masks = self._sync_masks_dev(
-                li, s, flat, needed_dev, active_dev,
-                self._pregate_bias(li, s) if ca and s > 0 else None)
-            sync, fail = pull_and_verify(masks)
+            # (needed_dev is the sync block when the layer pre-gated)
+            sync, fail = pull_and_verify(needed_dev)
             if fail >= 0:
                 i, li, x = replay_from(fail)
                 continue

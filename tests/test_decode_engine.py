@@ -193,6 +193,89 @@ def test_decode_replay_path_exercised_and_exact(decode_setup):
 
 
 @pytest.mark.slow
+@pytest.mark.parametrize("arch", ["olmoe-1b-7b", "deepseek-v2-lite"],
+                         ids=["gqa", "mla"])
+@pytest.mark.parametrize("batched", [False, True],
+                         ids=["single", "batched"])
+@pytest.mark.parametrize("s", [1, 3])
+def test_decode_step_pregates_inside_the_sync_layers_attention(arch,
+                                                               batched, s):
+    """With every expert resident nothing replays, and a decode step
+    dispatches the embedding, each dense layer, one attention program and
+    one FFN per MoE layer, and the logits: a sync layer's pre-gate rides
+    its attention program and costs no dispatch of its own."""
+    cfg, eng, prompt = _setup(arch)
+    E = cfg.moe.num_experts
+    sb = _slot_engine(cfg, eng, n_slots_per_layer=E, step_size=s)
+    n_moe = len(sb.moe_layer_ids)
+    n_dense = len(sb.specs) - n_moe
+    if batched:
+        state = sb.alloc_decode_state(prompt.shape[0])
+        logits = jnp.concatenate([sb.prefill_into(state, b, prompt[b:b + 1])
+                                  for b in range(prompt.shape[0])])
+    else:
+        logits, state = sb.prefill(prompt)
+    for li in range(n_moe):
+        sb.ensure_resident(li, range(E))
+    tok = jnp.argmax(logits, -1).astype(jnp.int32)
+    for _ in range(4):
+        before = sb.stats.snapshot()
+        logits, state = sb.decode_step(tok, state)
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        after = sb.stats.snapshot()
+        assert after["replays"] == before["replays"]
+        assert after["jit_calls"] - before["jit_calls"] == \
+            2 + n_dense + 2 * n_moe
+        assert after["ffn_calls"] - before["ffn_calls"] == n_moe
+    assert sb.stats.spec_layers > 0
+    names = {f.__name__ for f in sb._fns.values()}
+    assert any(n.startswith("pre_decode") and n.endswith("_pregate")
+               for n in names), names
+
+
+@pytest.mark.slow
+def test_warm_serve_compiles_nothing_as_the_horizon_moves():
+    """The compile contract behind a window with no compiles: once one
+    request has been served at each fixed S in 1..s_max (as the benchmark's
+    warm-up does), serving under the adaptive controller compiles nothing,
+    whatever S it takes and through replays."""
+    from repro.runtime.instrument import track_compiles
+    from repro.runtime.request import Request
+    from repro.runtime.serving import EngineServingConfig, ServingEngine
+    cfg, eng, _ = _setup("olmoe-1b-7b")
+    sb = _slot_engine(cfg, eng, n_slots_per_layer=3, pregate_margin=0)
+    srv = ServingEngine(sb, EngineServingConfig(max_batch=3, prefill_chunk=8,
+                                                admission_cap=False))
+    rng = np.random.default_rng(5)
+
+    def requests(n, first):
+        return [Request(prompt=rng.integers(0, cfg.vocab_size, L)
+                        .astype(np.int32), max_new_tokens=6,
+                        request_id=first + i)
+                for i, L in enumerate((12, 9, 12)[:n])]
+    s_max = sb.controller.cfg.s_max
+    assert s_max == len(sb.moe_layer_ids) - 1
+    srv.serve(requests(3, 0))
+    for s in range(1, s_max + 1):
+        sb.fixed_s = s
+        srv.serve(requests(1, 10 * s))
+    sb.fixed_s = None
+    real, seen = sb.decode_step, []
+
+    def forced(tok, state):
+        sb.controller.s = 1 + len(seen) % s_max
+        seen.append(sb.controller.s)
+        return real(tok, state)
+    sb.decode_step = forced
+    replays = sb.stats.replays
+    with track_compiles(sb) as probe:
+        srv.serve(requests(3, 100))
+    assert set(seen) == set(range(1, s_max + 1))
+    assert sb.stats.replays > replays
+    assert probe.new_entries == 0 and probe.new_compiles == 0
+
+
+@pytest.mark.slow
 def test_generate_greedy_tokens_match_engine(decode_setup, tmp_path):
     """SlotBufferEngine.generate greedy continuation == Engine.generate on
     the same params, across slot-buffer sizes that force eviction churn;

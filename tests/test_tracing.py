@@ -101,8 +101,10 @@ def test_serve_writes_every_span_nested_where_the_work_happens(model, tmp_path):
     ids = {s[3].get("request_id") for s in spans
            if s[0] in ("serve.admit", "engine.prefill_chunk")}
     assert ids == {0, 1, 2}
+    # a sync layer's attention pre-gates its horizon in the same program
     assert {s[3]["fn"] for s in spans if s[0] == "engine.dispatch"} >= {
-        "moe_ffn_decode", "moe_ffn_chunk", "pre_decode_batched"}
+        "moe_ffn_decode", "moe_ffn_chunk", "pre_decode_batched",
+        "pre_decode_batched_pregate"}
     writes = [s[3] for s in spans if s[0] == "swap.write"]
     assert sum(w["experts"] for w in writes) == sb.stats.swap_experts
     assert sum(w["pinned"] for w in writes) == sb.stats.swap_experts
@@ -136,13 +138,14 @@ def test_every_registered_function_has_a_name_of_its_own(arch):
 
 
 SHARED_ROLES = ["embed", "embed_chunk", "embed_decode", "logits", "logits_at",
-                "moe_ffn", "moe_ffn_chunk", "moe_ffn_decode", "pregate_s1",
-                "pregate_s1_batched"]
+                "moe_ffn", "moe_ffn_chunk", "moe_ffn_decode", "pregate",
+                "pregate_batched"]
 
 
 @pytest.mark.parametrize("arch,attention_roles", [
     # GQA layers keep the names the benchmark's trace readers match
-    ("olmoe-1b-7b", ["pre", "pre_decode_batched", "pre_prefill",
+    ("olmoe-1b-7b", ["pre", "pre_decode_batched",
+                     "pre_decode_batched_pregate", "pre_prefill",
                      "pre_prefill_chunk_kv16", "pre_prefill_chunk_kv8",
                      "pre_pregate"]),
     # latent attention says so in every role that runs it, the dense first
@@ -151,7 +154,8 @@ SHARED_ROLES = ["embed", "embed_chunk", "embed_decode", "logits", "logits_at",
     ("deepseek-v2-lite", ["dense_decode_mla", "dense_mla",
                           "dense_prefill_chunk_mla_kv16",
                           "dense_prefill_chunk_mla_kv8", "dense_prefill_mla",
-                          "pre_decode_mla_batched", "pre_mla",
+                          "pre_decode_mla_batched",
+                          "pre_decode_mla_batched_pregate", "pre_mla",
                           "pre_mla_pregate", "pre_prefill_chunk_mla_kv16",
                           "pre_prefill_chunk_mla_kv8", "pre_prefill_mla"])])
 def test_attention_roles_are_named_by_attention_kind(arch, attention_roles):
